@@ -132,14 +132,7 @@ def _apply_jax_config(cache_dir: str, min_secs: float) -> None:
         "jax_persistent_cache_min_entry_size_bytes": 0,
     }
     for key, value in updates.items():
-        try:
-            jax.config.update(key, value)
-        except AttributeError:
-            # Older jax without this knob: the dir + enable flags are the
-            # load-bearing ones and exist back to 0.4.x.
-            if key in ("jax_enable_compilation_cache",
-                       "jax_compilation_cache_dir"):
-                raise
+        jax.config.update(key, value)
 
 
 def status() -> Dict[str, object]:

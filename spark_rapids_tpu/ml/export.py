@@ -238,7 +238,7 @@ def train_logistic_regression_sharded(x, y, mask, steps: int = 100,
 
         @functools.partial(
             shard_map, mesh=mesh, in_specs=(spec, spec, spec),
-            out_specs=(rep, rep, rep, rep), check_rep=False)
+            out_specs=(rep, rep, rep, rep), check_vma=False)
         def fit(xs, ys, ms):
             def psum(a):
                 return jax.lax.psum(a, PART_AXIS)
@@ -453,7 +453,7 @@ def train_gbt_sharded(x, y, mask, *, mesh=None, n_trees: int = 20,
 
             @functools.partial(
                 shard_map, mesh=mesh, in_specs=(spec, spec, spec, rep),
-                out_specs=(rep, rep, rep), check_rep=False)
+                out_specs=(rep, rep, rep), check_vma=False)
             def boost_shards(xs, ys, ms, edges_):
                 def psum(a):
                     return jax.lax.psum(a, PART_AXIS)

@@ -19,15 +19,8 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401  (re-exported: the one import seam)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-# jax moved shard_map from jax.experimental to the top-level namespace;
-# this is the one sanctioned import seam, so the engine (and its tests)
-# run on both layouts instead of failing tier-1 on the older jax.
-try:
-    from jax import shard_map  # noqa: F401  (re-exported)
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 PART_AXIS = "part"
 
