@@ -20,18 +20,21 @@ import jax
 # array is created anywhere in the package.
 jax.config.update("jax_enable_x64", True)
 
-# Persistent XLA compilation cache — opt-IN via
-# SPARK_RAPIDS_TPU_COMPILE_CACHE=<dir>. Default is OFF: in this
-# environment compile requests can be served by a remote helper whose AOT
-# results target CPU features this machine lacks (+avx512*,
-# +prefer-no-gather); setting jax_compilation_cache_dir also activates
-# XLA-internal executable caches that replay those foreign binaries even
-# when jax_enable_compilation_cache is False — observed as mid-suite
-# SIGILL/segfaults under cpu_aot_loader.cc in rounds 3-4.
-_cache_dir = os.environ.get("SPARK_RAPIDS_TPU_COMPILE_CACHE", "off")
-if _cache_dir.lower() != "off":
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+# Persistent XLA compilation cache: on, and this is the one place that
+# decides where it lives. Where JAX_COMPILATION_CACHE_DIR is set, jax reads
+# it itself and no code here or in compile/persist.py sets another
+# directory. Otherwise it is one fixed directory inside the checkout,
+# computed from __file__ — never a temporary name, the home directory, a
+# pid or the time: a cache that moves between processes never hits.
+COMPILE_CACHE_DIR = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                 ".jax_cache")
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+# jax's own floor is 1 s; the filter + aggregate programs of the scan-bound
+# queries compile in ~3 s for the TPU and must be kept, the sub-half-second
+# eager ops are not worth a file each.
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 from .version import __version__  # noqa: E402,F401
 from . import types  # noqa: E402,F401

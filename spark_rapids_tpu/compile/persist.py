@@ -3,9 +3,11 @@
 Two cooperating pieces of cross-process memory:
 
 1. **JAX persistent compilation cache** — XLA executables keyed by HLO
-   hash, written under ``spark.rapids.tpu.compileCache.dir``. With it, a
+   hash. The package turns it on at import and decides its directory
+   (``spark_rapids_tpu.COMPILE_CACHE_DIR``: ``JAX_COMPILATION_CACHE_DIR``
+   where set, else a fixed directory in the checkout). With it, a
    restarted process pays deserialization (milliseconds) instead of
-   compilation (seconds per program on remote-compile backends) for every
+   compilation (seconds to minutes per program on a TPU) for every
    program any previous process built.
 
 2. **Compile manifest** (``tpu_compile_manifest.json`` in the same dir) —
@@ -16,11 +18,15 @@ Two cooperating pieces of cross-process memory:
    through AOT lowering (:mod:`.warmup`), each of which then hits the
    on-disk executable, so cold start collapses to tracing time.
 
-Safety: the environment kill-switch ``JAX_ENABLE_COMPILATION_CACHE=false``
-always wins (the CPU test tier sets it because replaying cross-machine AOT
-artifacts can SIGILL; some remote-compile helpers deadlock on the cache —
-see bench.py and tests/conftest.py). Configuration failures degrade to
-disabled, never to an error: a broken cache must not break queries.
+This module only *adds* to what the package set up: an explicit
+``spark.rapids.tpu.compileCache.dir`` moves the cache (and the manifest)
+there unless ``JAX_COMPILATION_CACHE_DIR`` placed it from outside, and
+disabling the conf key restores exactly what this module changed — it
+never turns off or moves a cache it did not itself configure. The
+environment kill-switch ``JAX_ENABLE_COMPILATION_CACHE=false`` (jax's own)
+always wins: no manifest is kept for a cache that cannot persist.
+Configuration failures degrade to disabled, never to an error: a broken
+cache must not break queries.
 """
 
 from __future__ import annotations
@@ -35,9 +41,11 @@ from ..utils import lockdep
 _LOCK = lockdep.lock("persist._LOCK", io_ok=True)
 _STATUS: Dict[str, object] = {"enabled": False, "reason": "not configured"}
 _MANIFEST: Optional["CompileManifest"] = None
-#: True while this process's jax config points at our cache dir — so a
-#: later disable actually reverts it instead of only updating _STATUS.
+#: True while this process's jax config carries this module's overrides,
+#: and what they replaced (key -> previous value): a later disable
+#: restores those instead of switching the cache off.
 _APPLIED = False
+_SAVED: Dict[str, object] = {}
 
 #: Bounds on the manifest so it stays a small index, not a log.
 _MAX_PLANS = 256
@@ -52,8 +60,10 @@ def _env_killed() -> bool:
 
 
 def default_cache_dir() -> str:
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "spark_rapids_tpu", "xla")
+    """The directory the package chose at import (the one place that
+    decides: ``spark_rapids_tpu/__init__.py``)."""
+    from .. import COMPILE_CACHE_DIR
+    return COMPILE_CACHE_DIR
 
 
 def configure(conf) -> Dict[str, object]:
@@ -88,19 +98,18 @@ def configure(conf) -> Dict[str, object]:
 
 
 def _deactivate_locked(reason: str) -> None:
-    """Turn the cache OFF for real: revert any jax config this module
-    applied earlier, not just the reported status (a session disabling the
-    key — or the env kill-switch appearing — must stop XLA persisting and
-    replaying executables)."""
+    """Drop the manifest and restore the jax config values an earlier
+    :func:`configure` overrode. The package-level cache stays as the
+    package (or ``JAX_COMPILATION_CACHE_DIR``) placed it."""
     global _MANIFEST, _APPLIED
     if _APPLIED:
         # The compile layer is process-global and follows the most
-        # recently constructed session's conf: flipping OFF a cache an
-        # earlier session enabled is allowed, but never silent.
+        # recently constructed session's conf: undoing what an earlier
+        # session configured is allowed, but never silent.
         import warnings
         warnings.warn(
-            f"persistent compile cache deactivated ({reason}); it was "
-            "enabled by an earlier session's conf — the compile layer is "
+            f"compile-cache conf overrides reverted ({reason}); they were "
+            "applied by an earlier session's conf — the compile layer is "
             "process-global (docs/compile-cache.md)", stacklevel=4)
         try:
             _revert_jax_config()
@@ -114,24 +123,23 @@ def _deactivate_locked(reason: str) -> None:
 
 def _revert_jax_config() -> None:
     import jax
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:  # noqa: BLE001 - enable=False is the load-bearing one
-        pass
+    while _SAVED:
+        key, value = _SAVED.popitem()
+        jax.config.update(key, value)
 
 
 def _apply_jax_config(cache_dir: str, min_secs: float) -> None:
     import jax
     updates = {
-        "jax_enable_compilation_cache": True,
-        "jax_compilation_cache_dir": cache_dir,
         "jax_persistent_cache_min_compile_time_secs": float(min_secs),
         # Entry size floor of 0: tiny shrink/transition kernels recompile
-        # per rung too, and on remote-compile links they are not cheap.
+        # per rung too.
         "jax_persistent_cache_min_entry_size_bytes": 0,
     }
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        updates["jax_compilation_cache_dir"] = cache_dir
     for key, value in updates.items():
+        _SAVED.setdefault(key, getattr(jax.config, key))
         jax.config.update(key, value)
 
 
@@ -277,5 +285,6 @@ def reset_for_tests() -> None:
     with _LOCK:
         _MANIFEST = None
         _APPLIED = False
+        _SAVED.clear()
         _STATUS.clear()
         _STATUS.update(enabled=False, reason="not configured")

@@ -639,19 +639,21 @@ FUSION_COMPILE_BUDGET_SECS = conf_float(
 
 COMPILE_CACHE_ENABLED = conf_bool(
     "spark.rapids.tpu.compileCache.enabled", False,
-    "Persist XLA executables to disk (JAX persistent compilation cache) "
-    "plus a manifest of (plan, capacity-rung) shapes, so a restarted "
-    "process skips recompiling everything it served before. Off by "
-    "default: some remote-compile helpers deadlock on the cache and "
-    "cross-machine AOT artifacts can SIGILL on replay (see "
-    "docs/compile-cache.md before enabling). The "
+    "Keep a manifest of (plan, capacity-rung) shapes beside JAX's "
+    "persistent compilation cache, so a restarted process can warm up "
+    "what it served before, and apply compileCache.dir / "
+    "compileCache.minCompileSecs. The executable cache itself is on "
+    "whenever the package is imported, under JAX_COMPILATION_CACHE_DIR "
+    "or else <checkout>/.jax_cache (docs/compile-cache.md). The "
     "JAX_ENABLE_COMPILATION_CACHE=false environment kill-switch always "
     "wins.")
 
 COMPILE_CACHE_DIR = conf_str(
     "spark.rapids.tpu.compileCache.dir", None,
-    "Directory for the persistent executable cache + compile manifest. "
-    "Default: ~/.cache/spark_rapids_tpu/xla.")
+    "Directory for the compile manifest, and for the executable cache "
+    "unless JAX_COMPILATION_CACHE_DIR already placed that. Default: the "
+    "package's cache directory (JAX_COMPILATION_CACHE_DIR, else "
+    "<checkout>/.jax_cache).")
 
 COMPILE_CACHE_MIN_COMPILE_SECS = conf_float(
     "spark.rapids.tpu.compileCache.minCompileSecs", 0.0,
