@@ -690,8 +690,12 @@ class TpuParquetScanExec:
             # ANY decode failure (unsupported shape, decompression codec
             # mismatch, corrupt/truncated page metadata) degrades to the
             # host reader for just this row group — the host result is the
-            # correctness baseline, so falling back is always safe.
+            # correctness baseline, so falling back is always safe. Under
+            # spark.rapids.sql.test.enabled nothing may leave the device
+            # quietly (a compile refusal lands here too): re-raise.
             except Exception:  # noqa: BLE001 - graceful per-unit fallback
+                if ctx.conf.test_enabled:
+                    raise
                 with trace_range("parquet.host_fallback"), \
                         pq.ParquetFile(path) as pf:
                     tbl = pf.read_row_group(

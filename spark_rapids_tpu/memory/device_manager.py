@@ -106,6 +106,10 @@ class DeviceManager:
                 stats = self.device.memory_stats() or {}
                 total = stats.get("bytes_limit", _DEFAULT_HBM_BYTES)
             except Exception as e:  # noqa: BLE001 - classify-narrowed
+                # An assumed budget is fine on the CPU backend and wrong
+                # on a chip, whose HBM size must come from the device.
+                if self.device.platform == "tpu":
+                    raise
                 self._classify_probe_failure("memory_stats(bytes_limit)", e)
                 total = _DEFAULT_HBM_BYTES
             self._hbm_budget = int(total * self._frac)

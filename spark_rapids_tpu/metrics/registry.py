@@ -131,6 +131,14 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "Bytes written by the file writer."),
     _spec("numFiles", MetricKind.SUM, MODERATE,
           "Files produced by the file writer."),
+    _spec("deviceDecodedRowGroups", MetricKind.SUM, ESSENTIAL,
+          "Parquet row groups decoded on the device "
+          "(io/parquet_device.py)."),
+    _spec("hostFallbackRowGroups", MetricKind.SUM, ESSENTIAL,
+          "Parquet row groups the device decoder could not read and the "
+          "host reader served instead. Zero when the scan ran on the "
+          "device; under spark.rapids.sql.test.enabled such a row group "
+          "raises instead."),
     _spec("peakDeviceBytes", MetricKind.PEAK, MODERATE,
           "Peak device bytes observed (HBM watermark where the backend "
           "reports it)."),
