@@ -45,8 +45,15 @@ def ensure_parquet(rows: int, seed: int) -> dict:
         tables = tpch.gen_tables(rows, seed=seed)
         os.makedirs(out, exist_ok=True)
         for name, rb in tables.items():
+            # Strings dictionary-encoded, numbers PLAIN: what the device
+            # decoder reads today. pyarrow's default starts every column
+            # on a dictionary and falls back to PLAIN mid-chunk once it
+            # outgrows its page ("mixed PLAIN + dictionary pages"), which
+            # the decoder refuses at SF1 (ROADMAP S5).
+            strings = [f.name for f in rb.schema if pa.types.is_string(f.type)]
             pq.write_table(pa.Table.from_batches([rb]),
-                           os.path.join(out, f"{name}.parquet"))
+                           os.path.join(out, f"{name}.parquet"),
+                           use_dictionary=strings)
         open(done, "w").close()
         say(f"generated {rows} lineitem rows (seed {seed}) to {out} "
             f"in {time.perf_counter() - t0:.1f}s")
