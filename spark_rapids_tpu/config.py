@@ -593,8 +593,8 @@ TPU_LADDER_GROWTH = conf_float(
     "spark.rapids.tpu.bucketLadder.growth", 2.0,
     "Geometric spacing between capacity-ladder rungs. 2.0 is the classic "
     "power-of-two ladder; 4.0 quarters the number of programs XLA ever "
-    "compiles at the price of up to 4x padding (attractive on slow "
-    "remote-compile backends); values toward 1.5 trade more programs for "
+    "compiles at the price of up to 4x padding (attractive where "
+    "compiles are slow); values toward 1.5 trade more programs for "
     "less padded HBM. Rungs stay 128-lane aligned. See "
     "docs/compile-cache.md.")
 
@@ -622,7 +622,7 @@ POLYMORPHIC_TIER_GROWTH = conf_float(
     "the bucket-ladder base. 4.0 bounds padded HBM/compute waste at 4x "
     "while merging ~2 power-of-two rungs per executable; 16.0 merges 4 "
     "rungs per executable (one compile per 16x of data growth — right "
-    "for slow remote-compile backends where compile time dominates) at "
+    "where compile time dominates) at "
     "up to 16x padding. Tiers always land on bucket-ladder rungs. See "
     "docs/tuning-guide.md for the padding-waste vs compile-count "
     "tradeoff.")
@@ -701,8 +701,8 @@ TPU_FUSION_INLINE_JOINS = conf_bool(
     "spark.rapids.tpu.fusion.inlineJoins", True,
     "Inline hash joins into the fused whole-stage program instead of "
     "running each join as an eager boundary: removes per-join dispatches "
-    "and intermediate materialization. Disable when a slow remote compile "
-    "helper makes many-sort fused programs too expensive to build.")
+    "and intermediate materialization. Disable when many-sort fused programs are too "
+    "expensive to compile.")
 
 TPU_MESH_ENABLED = conf_bool(
     "spark.rapids.tpu.mesh.enabled", False,
