@@ -57,9 +57,8 @@ def ensure_parquet(rows: int, seed: int) -> dict:
         open(done, "w").close()
         say(f"generated {rows} lineitem rows (seed {seed}) to {out} "
             f"in {time.perf_counter() - t0:.1f}s")
-    return {name: os.path.join(out, f"{name}.parquet")
-            for name in ("lineitem", "orders", "customer", "supplier",
-                         "part", "partsupp", "nation", "region")}
+    return {f[:-len(".parquet")]: os.path.join(out, f)
+            for f in sorted(os.listdir(out)) if f.endswith(".parquet")}
 
 
 def metric_total(profile, name: str) -> int:

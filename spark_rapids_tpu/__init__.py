@@ -26,10 +26,11 @@ jax.config.update("jax_enable_x64", True)
 # directory. Otherwise it is one fixed directory inside the checkout,
 # computed from __file__ — never a temporary name, the home directory, a
 # pid or the time: a cache that moves between processes never hits.
-COMPILE_CACHE_DIR = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache")
-if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+COMPILE_CACHE_DIR = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+if not COMPILE_CACHE_DIR:
+    COMPILE_CACHE_DIR = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
 # jax's own floor is 1 s; the filter + aggregate programs of the scan-bound
 # queries compile in ~3 s for the TPU and must be kept, the sub-half-second
