@@ -164,15 +164,26 @@ def test_hive_partitioned_falls_back(tmp_path):
                   .column("v").to_pylist()) == [10, 20, 30]
 
 
-def test_plain_fallback_pages(tmp_path):
+@pytest.mark.parametrize("test_enabled", [False, True])
+def test_plain_fallback_pages(tmp_path, test_enabled):
     # use_dictionary=False forces PLAIN data pages: fixed-width columns
     # decode on device via the plain path; byte-array chunks fall back
-    # per row group inside the exec and stay correct.
+    # per row group inside the exec and stay correct — unless
+    # spark.rapids.sql.test.enabled is set, under which nothing may leave
+    # the device quietly and the decoder's refusal is raised.
     tbl = _table(n=300)
     path = str(tmp_path / "t.parquet")
     pq.write_table(tbl, path, use_dictionary=False)
-    assert_tpu_and_cpu_are_equal(
-        lambda s: s.read.parquet(path).select(col("i"), col("f"), col("s")))
+
+    def query(s):
+        return s.read.parquet(path).select(col("i"), col("f"), col("s"))
+    if test_enabled:
+        with pytest.raises(NotImplementedError,
+                           match="PLAIN byte-array pages"):
+            query(tpu_session()).collect()
+    else:
+        assert_tpu_and_cpu_are_equal(
+            query, conf={"spark.rapids.sql.test.enabled": False})
 
 
 class TestRebaseGuard:
