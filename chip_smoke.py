@@ -1,15 +1,16 @@
 """Quickest proof that the engine still starts on the chip.
 
 One process, one TPU: TPC-H SF1 tables made from ``--seed``, written to
-parquet once, and q6 / q1 / q3 run through ``TpuSession`` from those files
-— device parquet decode, upload, the fused executor and the download are
-all on the path — each twice (cold, then warm), each answer checked against
-the CPU oracle session outside the timed call. Any planned CPU operator,
+parquet once, and q6 and q1 (q3 through ``--queries``) run through
+``TpuSession`` from those files — device parquet decode, upload, the fused
+executor and the download are all on the path — each twice (cold, then
+warm), each answer checked against the CPU oracle session outside the
+timed call. Any planned CPU operator,
 any row group read on the host, any mismatch or any exception ends the run
 non-zero with no result line. There is no path that passes on a CPU.
 
     python chip_smoke.py                 # on the chip machine; see README
-    python chip_smoke.py --queries q6    # the cheapest call (seconds of compile)
+    python chip_smoke.py --queries q6,q1,q3   # q3: 748 s cold on a v5e
 
 Last line of stdout on success, and only then:
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
@@ -26,7 +27,10 @@ DATA_DIR = os.path.join(HERE, ".chip_smoke_data")   # in .gitignore
 SF1_LINEITEM_ROWS = 6_001_215   # TPC-H SF1: 1.5 M orders, 150 k customers
 TABLES_OF = {"q6": ("lineitem",), "q1": ("lineitem",),
              "q3": ("customer", "orders", "lineitem")}
-DEFAULT_QUERIES = "q6,q1,q3"
+# The longest prefix of q6,q1,q3 whose COLD run fits the 1200 s a sealed
+# machine with an empty compile cache gets: q3 alone compiled for 748 s on
+# the v5e (CHANGES.md, PR 25), q6 + q1 take ~455 s with everything.
+DEFAULT_QUERIES = "q6,q1"
 
 
 def say(msg: str) -> None:
@@ -67,6 +71,14 @@ def metric_total(profile, name: str) -> int:
         return node["metrics"].get(name, 0) + sum(map(walk, node["children"]))
     return walk(profile.tree) + sum(m.get(name, 0)
                                     for m in profile.extras.values())
+
+
+def device_peak_bytes() -> int:
+    """The process's peak HBM as the device reports it (0 on a backend
+    without memory stats). The profile's hbmPeakBytesInUse only moves when
+    the engine itself probes, which the fused path of q6/q1 never does."""
+    import jax
+    return (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use", 0)
 
 
 def run(queries, rows: int, seed: int) -> None:
@@ -110,7 +122,8 @@ def run(queries, rows: int, seed: int) -> None:
                 f"{comp['compileNs'] / 1e9 + comp['fusedCompileSeconds']:.3f} "
                 f"deviceDecodedRowGroups={decoded} scannedRowGroups={scanned} "
                 f"hostFallbackRowGroups={fallback} "
-                f"hbmPeakBytesInUse={prof.engine['hbmPeakBytesInUse']}")
+                f"hbmPeakBytesInUse={prof.engine['hbmPeakBytesInUse']} "
+                f"devicePeakBytesInUse={device_peak_bytes()}")
             # a re-run inside collect (join capacity learning) decodes the
             # files again, so decoded may be a multiple of scanned
             if fallback or decoded < scanned:
