@@ -65,22 +65,6 @@ def ensure_parquet(rows: int, seed: int) -> dict:
             for f in sorted(os.listdir(out)) if f.endswith(".parquet")}
 
 
-def metric_total(profile, name: str) -> int:
-    """One metric summed over every node of a QueryProfile."""
-    def walk(node):
-        return node["metrics"].get(name, 0) + sum(map(walk, node["children"]))
-    return walk(profile.tree) + sum(m.get(name, 0)
-                                    for m in profile.extras.values())
-
-
-def device_peak_bytes() -> int:
-    """The process's peak HBM as the device reports it (0 on a backend
-    without memory stats). The profile's hbmPeakBytesInUse only moves when
-    the engine itself probes, which the fused path of q6/q1 never does."""
-    import jax
-    return (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use", 0)
-
-
 def run(queries, rows: int, seed: int) -> None:
     """The smoke's body: raises (or exits non-zero) on any failure."""
     import pyarrow.parquet as pq
@@ -114,18 +98,19 @@ def run(queries, rows: int, seed: int) -> None:
             secs = time.perf_counter() - t0
             prof = tpu.last_query_profile()
             comp = prof.engine["compile"]
-            decoded = metric_total(prof, "deviceDecodedRowGroups")
-            fallback = metric_total(prof, "hostFallbackRowGroups")
+            totals = prof.totals()
+            decoded = totals.get("deviceDecodedRowGroups", 0)
+            fallback = totals.get("hostFallbackRowGroups", 0)
             say(f"{name} {phase} seconds={secs:.3f} rows={got.num_rows} "
-                f"compiles={comp['kernelCompiles'] + comp['fusedCompiles']} "
-                f"compile_seconds="
-                f"{comp['compileNs'] / 1e9 + comp['fusedCompileSeconds']:.3f} "
+                f"compiles={comp['xlaCompiles']} "
+                f"cache_misses={comp['persistentCacheMisses']} "
+                f"compile_seconds={comp['xlaCompileNs'] / 1e9:.3f} "
+                f"planRuns={totals.get('planRuns', 0)} "
                 f"deviceDecodedRowGroups={decoded} scannedRowGroups={scanned} "
                 f"hostFallbackRowGroups={fallback} "
-                f"hbmPeakBytesInUse={prof.engine['hbmPeakBytesInUse']} "
-                f"devicePeakBytesInUse={device_peak_bytes()}")
+                f"hbmPeakBytesInUse={prof.engine['hbmPeakBytesInUse']}")
             # a re-run inside collect (join capacity learning) decodes the
-            # files again, so decoded may be a multiple of scanned
+            # files again: decoded is scanned x planRuns
             if fallback or decoded < scanned:
                 sys.exit(f"chip_smoke: {name} {phase}: {fallback} row groups "
                          f"read on the host, {decoded} decoded on the device "

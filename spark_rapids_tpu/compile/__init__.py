@@ -7,7 +7,7 @@ Under XLA the first run of every (plan shape, capacity bucket) pays a full
 compile — seconds on a remote-compile TPU backend — which is the dominant
 cold-start cost for a serving system that sees the same query shapes from
 millions of users. This package is the analog of the reference's
-"kernels are already compiled" property, built from four pieces:
+"kernels are already compiled" property, built from five pieces:
 
 * :mod:`.ladder` — the bucket ladder: every dynamic size in the engine
   (row capacities, string byte capacities) is rounded onto one shared,
@@ -25,6 +25,9 @@ millions of users. This package is the analog of the reference's
   at neighbor ladder rungs and compiles them in the background, so a
   growing dataset never stalls at a rung boundary and a restarted process
   re-compiles everything it served yesterday before the first query.
+* :mod:`.xla_events` — the engine's one ``jax.monitoring`` listener:
+  what JAX compiled and what the persistent cache served, as process
+  totals the query profile takes deltas of.
 
 See docs/compile-cache.md for the user-facing story.
 """
@@ -52,6 +55,8 @@ def configure(conf) -> dict:
     from . import budget as _budget
     from . import persist as _persist
     from . import warmup as _warmup
+    from . import xla_events as _xla_events
+    _xla_events.install()
     ladder = _ladder_from_conf(conf)
     if ladder != get_ladder() and _programs_exist():
         # Capacities bake into compiled programs: changing the ladder

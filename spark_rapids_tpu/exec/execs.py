@@ -21,6 +21,7 @@ Operator parity map (reference locations in SURVEY.md §2.3):
 
 from __future__ import annotations
 
+import functools
 from typing import Iterator, List, Optional
 
 import jax
@@ -40,7 +41,7 @@ from ..ops.kernels import rowops as KR
 from ..plan.logical import SortOrder
 from ..plan.physical import ExecContext, PhysicalPlan
 from ..utils.kernel_cache import cached_kernel, kernel_key
-from ..utils.tracing import trace_range
+from ..metrics.trace import span
 
 
 def _bind_all(exprs: List[Expression], schema: T.Schema) -> List[Expression]:
@@ -69,6 +70,40 @@ def _counted_stream(ctx, name: str, batches):
         yield db
 
 
+def _scoped(name: str, part):
+    """``part`` with every pull under ``jax.named_scope(name)``. The scope
+    is entered around each ``next`` and never held across a ``yield``:
+    the name stack is thread-local, and a suspended generator must not
+    leave its scope on the consumer's stack."""
+    it = iter(part)
+    while True:
+        with jax.named_scope(name):
+            try:
+                batch = next(it)
+            except StopIteration:
+                return
+        yield batch
+
+
+def _scoped_in_fusion(execute):
+    """Inside a fused program's trace, run an operator's ``execute`` and
+    every pull of its partitions under a named scope of the operator's
+    node name: a parent pulls its child inside its own pull, so an HLO
+    op's metadata reads as its path in the plan
+    (``jit(fused_1a2b3c4d)/TpuHashAggregateExec/TpuFilterExec/...``).
+    Trace-time only; outside fusion the operator's own programs carry
+    their names (utils/kernel_cache.py:program_name)."""
+    @functools.wraps(execute)
+    def scoped(self, ctx):
+        if not getattr(ctx, "in_fusion", False):
+            return execute(self, ctx)
+        name = self.node_name()
+        with jax.named_scope(name):
+            parts = execute(self, ctx)
+        return [_scoped(name, p) for p in parts]
+    return scoped
+
+
 class TpuExec(PhysicalPlan):
     columnar = True
 
@@ -76,6 +111,11 @@ class TpuExec(PhysicalPlan):
     #: exec.coalesce.insert_coalesce (CoalesceGoal declaration analog,
     #: reference GpuExec.childrenCoalesceGoal).
     children_coalesce_goals = None
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        if "execute" in cls.__dict__:
+            cls.execute = _scoped_in_fusion(cls.__dict__["execute"])
 
     def describe(self):
         return self.node_name()
@@ -126,7 +166,7 @@ class HostToDeviceExec(TpuExec):
                 ctx=None) -> ColumnarBatch:
         import time as _time
         t0 = _time.perf_counter_ns()
-        with trace_range("HostToDevice.upload"):
+        with span(getattr(ctx, "trace", None), "HostToDevice.upload"):
             if len(rbs) == 1:
                 combined = rbs[0]
             else:
@@ -173,7 +213,7 @@ class DeviceToHostExec(PhysicalPlan):
             import time as _time
             for db in part:
                 t0 = _time.perf_counter_ns()
-                with trace_range("DeviceToHost.download"):
+                with span(ctx.trace, "DeviceToHost.download"):
                     hb = HostBatch.from_device(db)
                 yield emit(ctx, hb, t0)
 
@@ -190,7 +230,7 @@ class DeviceToHostExec(PhysicalPlan):
             pending = None  # (begin ns, batch, download handle)
             for db in part:
                 t0 = _time.perf_counter_ns()
-                with trace_range("DeviceToHost.download_begin"):
+                with span(ctx.trace, "DeviceToHost.download_begin"):
                     handle = db.to_arrow_begin()
                 begin_ns = _time.perf_counter_ns() - t0
                 if pending is not None:
@@ -214,7 +254,7 @@ class DeviceToHostExec(PhysicalPlan):
         import time as _time
         begin_ns, db, handle = pending
         t0 = _time.perf_counter_ns()
-        with trace_range("DeviceToHost.download"):
+        with span(ctx.trace, "DeviceToHost.download"):
             hb = HostBatch(db.to_arrow_finish(handle))
         # emit() computes opTime as now - t0; shift t0 back by the begin
         # span so both download phases (and nothing else) are counted.
@@ -861,7 +901,7 @@ def _accumulate_spillable(child: PhysicalPlan, ctx, label: str,
         if not ids:
             return None
 
-        with trace_range(f"{label}.assemble"):
+        with span(getattr(ctx, "trace", None), f"{label}.assemble"):
             out = R.with_retry(ctx, f"{node or label}.assemble", ids,
                                lambda id_list: _pinned_concat(catalog,
                                                               id_list),

@@ -42,7 +42,7 @@ from ..memory import spill as SP
 from ..ops.kernels import concat as KC
 from ..ops.kernels import rowops as KR
 from ..utils.kernel_cache import cached_kernel, kernel_key
-from ..utils.tracing import trace_range
+from ..metrics.trace import span
 
 
 def _head_key_values(batch: ColumnarBatch, key_exprs) -> tuple:
@@ -369,7 +369,7 @@ class ExternalSorter:
     def sorted_chunks(self):
         """Merge all runs; yield the final run's chunks in order (each
         acquired from the catalog, freed after the caller consumes it)."""
-        with trace_range("extsort.merge"):
+        with span(None, "extsort.merge"):
             runs = self._runs
             while len(runs) > 1:
                 nxt = []

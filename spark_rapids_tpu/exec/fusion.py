@@ -249,7 +249,7 @@ def _pad_inputs_to_tiers(inputs):
 
 
 def _build_fused(fused_plan, conf, join_growth: float, guess_rows: int,
-                 join_caps=None, dense_modes=None):
+                 join_caps=None, dense_modes=None, name: str = "fused"):
     caps = dict(join_caps or {})
     nd = dict(dense_modes or {})
 
@@ -286,6 +286,10 @@ def _build_fused(fused_plan, conf, join_growth: float, guess_rows: int,
         # The head tuple is the single downloaded transfer; the full batch
         # stays device-resident for the (rare) guess-miss second pass.
         return (batch.n_rows, flags, totals, dfails, shrunk), batch
+    # The XLA module's name (``jit_fused_<8 hex of the plan hash>``): a
+    # pure function of the program cache's key, as
+    # utils/kernel_cache.py:program_name requires.
+    run.__name__ = run.__qualname__ = name
     return jax.jit(run)
 
 
@@ -326,10 +330,11 @@ def fused_collect(root: DeviceToHostExec, ctx: ExecContext
         # FusedProgram: the jitted callable plus its AOT executable table,
         # so background warm-ups (compile/warmup.py) are visible to this
         # dispatch instead of rotting in jit's invisible lower() path.
-        fn = FusedProgram(_build_fused(fused_plan, ctx.conf,
-                                       ctx.join_growth, guess_rows,
-                                       ctx.join_caps, ctx.dense_modes),
-                          label=type(device_plan).__name__)
+        fn = FusedProgram(
+            _build_fused(fused_plan, ctx.conf, ctx.join_growth, guess_rows,
+                         ctx.join_caps, ctx.dense_modes,
+                         name="fused_" + _persist.plan_hash(sig)[:8]),
+            label=type(device_plan).__name__)
         # Last-wins under concurrent sessions: a GIL-atomic dict store
         # of an equivalent program (same sig); the loser only wasted a
         # build. No lock on the dispatch path.
@@ -366,7 +371,7 @@ def fused_collect(root: DeviceToHostExec, ctx: ExecContext
     with _trace.span(tr, "fusion.dispatch", cat="dispatch") as _sp, \
             _lockdep.blocking("fusion.dispatch"):
         head, full = fn(inputs)
-        if _sp is not None and not key_compiled_before \
+        if tr is not None and not key_compiled_before \
                 and fn.jit_compiled(inputs):
             _sp.annotate(compiled=True)
     if budget_secs > 0 and not key_compiled_before \

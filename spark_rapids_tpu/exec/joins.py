@@ -32,7 +32,7 @@ from ..ops.expression import Expression
 from ..ops.kernels import rowops as KR
 from ..plan.physical import PhysicalPlan
 from ..utils.kernel_cache import cached_kernel, kernel_key
-from ..utils.tracing import trace_range
+from ..metrics.trace import span
 from .execs import (TpuExec, TpuShuffledHashJoinExec, _bind_all,
                     _coalesce_device, _null_col, _null_extend_right)
 
@@ -71,7 +71,7 @@ class TpuBroadcastExchangeExec(TpuExec):
         if not batches:
             self._empty = True
             return None
-        with trace_range("broadcast.collect"):
+        with span(getattr(ctx, "trace", None), "broadcast.collect"):
             from ..memory import retry as R
             # The broadcast payload must be ONE batch (every consumer
             # builds from it): spill + retry only, no split.

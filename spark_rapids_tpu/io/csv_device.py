@@ -43,7 +43,7 @@ from ..data.batch import ColumnarBatch
 from ..data.column import (DeviceColumn, bucket_byte_capacity,
                            bucket_capacity)
 from ..utils.kernel_cache import cached_kernel
-from ..utils.tracing import trace_range
+from ..metrics.trace import span
 
 
 class NotCsvDecodable(Exception):
@@ -287,7 +287,7 @@ def _decode_slice(dev_buf, starts: np.ndarray, ends: np.ndarray,
     e_pad[:n] = ends
     kern = cached_kernel("csv_device.parse", (dtypes, widths, cap),
                          _build_parse_kernel(dtypes, widths, cap))
-    with trace_range("csv.device_parse"):
+    with span(None, "csv.device_parse"):
         outs, bad = kern(dev_buf, jnp.asarray(s_pad), jnp.asarray(e_pad),
                          jnp.asarray(n, jnp.int32))
     if bool(bad):   # one scalar sync per batch
@@ -346,7 +346,8 @@ class TpuCsvScanExec:
             try:
                 maybe_inject(ctx, "io.csv.file")
                 with ctx.registry.timer(name, "opTime",
-                                        trace="csv.decode_file"):
+                                        trace="csv.decode_file",
+                                        owner=getattr(ctx, "trace", None)):
                     return list(decode_file(path, self._schema,
                                             self.options))
             except Exception as e:  # noqa: BLE001 - classify-narrowed

@@ -44,7 +44,7 @@ from ..data.batch import ColumnarBatch
 from ..data.column import (DeviceColumn, bucket_byte_capacity,
                            bucket_capacity)
 from ..utils.kernel_cache import cached_kernel
-from ..utils.tracing import trace_range
+from ..metrics.trace import span
 
 MAGIC = b"ORC"
 
@@ -581,7 +581,7 @@ def decode_stripe(path: str, tail: OrcTail, si: StripeInfo,
         bits = parse_byte_rle_bits(present, n_rows) if present else None
         n_valid = n_rows if bits is None else int(
             np.unpackbits(bits)[:n_rows].sum())
-        with trace_range("orc.decode_column"):
+        with span(None, "orc.decode_column"):
             if kind in _INT_KINDS:
                 if enc not in (_E_DIRECT_V2,):
                     raise NotOrcDecodable(f"int encoding {enc}")
@@ -741,7 +741,8 @@ class TpuOrcScanExec:
             try:
                 maybe_inject(ctx, "io.orc.stripe")
                 with ctx.registry.timer(name, "opTime",
-                                        trace="orc.device_decode_stripe"):
+                                        trace="orc.device_decode_stripe",
+                                        owner=getattr(ctx, "trace", None)):
                     return decode_stripe(path, tail, si, self._schema)
             except Exception as e:  # noqa: BLE001 - classify-narrowed
                 # parsers translate malformed-input errors to

@@ -401,103 +401,108 @@ def _expand_hybrid(kinds, counts, values, bit_starts, widths, packed,
     position and shift/mask. Widths are per RUN (a dictionary's bit width
     grows across pages as it fills; <= 24, so shift <= 7 + width <= 24
     keeps every value inside the 4 gathered bytes)."""
-    ends = jnp.cumsum(counts)
-    starts = ends - counts
-    i = jnp.arange(capacity, dtype=jnp.int32)
-    r = jnp.searchsorted(ends, i, side="right")
-    r = jnp.clip(r, 0, kinds.shape[0] - 1)
-    within = i - starts[r]
-    w = widths[r]
-    bit0 = bit_starts[r] + within * w
-    byte0 = bit0 >> 3
-    shift = (bit0 & 7).astype(jnp.uint32)
-    nb = packed.shape[0]
-    b = [packed[jnp.clip(byte0 + k, 0, nb - 1)].astype(jnp.uint32)
-         for k in range(4)]
-    word = b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24)
-    mask = (jnp.uint32(1) << jnp.clip(w, 0, 31).astype(jnp.uint32)) \
-        - jnp.uint32(1)
-    packed_val = ((word >> shift) & mask).astype(jnp.int32)
-    return jnp.where(kinds[r] == 1, values[r], packed_val)
+    with jax.named_scope("expand_hybrid"):
+        ends = jnp.cumsum(counts)
+        starts = ends - counts
+        i = jnp.arange(capacity, dtype=jnp.int32)
+        r = jnp.searchsorted(ends, i, side="right")
+        r = jnp.clip(r, 0, kinds.shape[0] - 1)
+        within = i - starts[r]
+        w = widths[r]
+        bit0 = bit_starts[r] + within * w
+    with jax.named_scope("unpack"):
+        byte0 = bit0 >> 3
+        shift = (bit0 & 7).astype(jnp.uint32)
+        nb = packed.shape[0]
+        b = [packed[jnp.clip(byte0 + k, 0, nb - 1)].astype(jnp.uint32)
+             for k in range(4)]
+        word = b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24)
+        mask = (jnp.uint32(1) << jnp.clip(w, 0, 31).astype(jnp.uint32)) \
+            - jnp.uint32(1)
+        packed_val = ((word >> shift) & mask).astype(jnp.int32)
+        return jnp.where(kinds[r] == 1, values[r], packed_val)
 
 
 def _decode_chunk_device(def_table, idx_table, packed, plain, dict_table,
                          n_rows, capacity, idx_bw, dtype,
                          dict_string: bool):
-    """Traced device decode of one column chunk (see module doc)."""
-    live = jnp.arange(capacity, dtype=jnp.int32) < n_rows
-    dk, dc, dv, db, dw = def_table
-    levels = _expand_hybrid(dk, dc, dv, db, dw, packed, capacity)
-    validity = (levels == 1) & live
-    # Indices/values are stored for NON-NULL slots only, compacted: row ->
-    # slot via an exclusive cumsum of the validity mask.
-    slot = jnp.cumsum(validity.astype(jnp.int32)) - 1
-    slot = jnp.clip(slot, 0, capacity - 1)
+    """Traced device decode of one column chunk (see module doc). Each
+    phase runs under a ``jax.named_scope`` (trace-time only), so XProf
+    and the HLO metadata tell them apart inside the one program."""
+    with jax.named_scope("def_levels"):
+        live = jnp.arange(capacity, dtype=jnp.int32) < n_rows
+        dk, dc, dv, db, dw = def_table
+        levels = _expand_hybrid(dk, dc, dv, db, dw, packed, capacity)
+        validity = (levels == 1) & live
+        # Indices/values are stored for NON-NULL slots only, compacted:
+        # row -> slot via an exclusive cumsum of the validity mask.
+        slot = jnp.cumsum(validity.astype(jnp.int32)) - 1
+        slot = jnp.clip(slot, 0, capacity - 1)
     if idx_table is not None:
         ik, ic, iv, ib, iw = idx_table
         raw_idx = _expand_hybrid(ik, ic, iv, ib, iw, packed, capacity)
-        codes = jnp.where(validity, raw_idx[slot], 0)
-        if dict_string:
-            rank = dict_table
-            codes = jnp.where(validity,
-                              rank[jnp.clip(codes, 0, rank.shape[0] - 1)], 0)
-            return codes, validity
-        vals = dict_table[jnp.clip(codes, 0, dict_table.shape[0] - 1)]
-        data = jnp.where(validity, vals, jnp.zeros((), vals.dtype))
+        with jax.named_scope("dict_gather"):
+            codes = jnp.where(validity, raw_idx[slot], 0)
+            if dict_string:
+                rank = dict_table
+                codes = jnp.where(
+                    validity, rank[jnp.clip(codes, 0, rank.shape[0] - 1)], 0)
+                return codes, validity
+            vals = dict_table[jnp.clip(codes, 0, dict_table.shape[0] - 1)]
+            data = jnp.where(validity, vals, jnp.zeros((), vals.dtype))
+            return data, validity
+    with jax.named_scope("plain_scatter"):
+        data = jnp.where(validity, plain[slot], jnp.zeros((), plain.dtype))
         return data, validity
-    data = jnp.where(validity, plain[slot], jnp.zeros((), plain.dtype))
-    return data, validity
 
 
-def _pad_packed(packed: bytes) -> jnp.ndarray:
+def _pad_packed(packed: bytes) -> np.ndarray:
     raw = np.frombuffer(packed or b"\0\0\0\0", dtype=np.uint8)
     cap = bucket_byte_capacity(max(len(raw), 4), 8)
     buf = np.zeros(cap, np.uint8)
     buf[: len(raw)] = raw
-    return jnp.asarray(buf)
+    return buf
 
 
 def _runs_arrays(runs: _HybridRuns, pad_to: int):
     def arr(xs, fill):
         a = np.full(pad_to, fill, np.int32)
         a[: len(xs)] = xs
-        return jnp.asarray(a)
+        return a
     # Padding runs have count 0 -> they own no output positions.
     return (arr(runs.kinds, 1), arr(runs.counts, 0), arr(runs.values, 0),
             arr(runs.bit_starts, 0), arr(runs.widths, 1))
 
 
-def decode_chunk(plan: ColumnChunkPlan, capacity: int) -> DeviceColumn:
-    """Upload one chunk's page bytes + run tables and decode on device."""
-    pad = bucket_byte_capacity(max(len(plan.def_runs.kinds),
-                              len(plan.idx_runs.kinds)
-                              if plan.idx_runs else 1, 1), 8)
-    def_table = _runs_arrays(plan.def_runs, pad)
-    idx_table = _runs_arrays(plan.idx_runs, pad) if plan.idx_runs else None
-    packed_dev = _pad_packed(plan.packed)
-    def _bucketed(arr, dtype):
-        """Pad to a power-of-two length: unbucketed shapes would retrace
-        the jitted kernel per row group (kernel_cache discipline). Also
-        keeps (masked-out) gathers in range for empty dictionaries."""
-        cap = bucket_byte_capacity(max(len(arr), 1), 8)
-        buf = np.zeros(cap, dtype)
-        buf[: len(arr)] = arr
-        return jnp.asarray(buf)
+def _bucketed(arr, dtype) -> np.ndarray:
+    """Pad to a power-of-two length: unbucketed shapes would retrace
+    the jitted kernel per row group (kernel_cache discipline). Also
+    keeps (masked-out) gathers in range for empty dictionaries."""
+    cap = bucket_byte_capacity(max(len(arr), 1), 8)
+    buf = np.zeros(cap, dtype)
+    buf[: len(arr)] = arr
+    return buf
 
-    dict_string = plan.dict_rank is not None
-    if dict_string:
-        dict_table = _bucketed(plan.dict_rank, np.int32)
-    elif plan.dict_values is not None:
-        dict_table = _bucketed(plan.dict_values, plan.dict_values.dtype)
-    else:
-        dict_table = None
-    plain = None
-    if plan.plain_values is not None:
-        buf = np.zeros(capacity, plan.plain_values.dtype)
-        buf[: len(plan.plain_values)] = plan.plain_values
-        plain = jnp.asarray(buf)
 
+def _count(counters: Optional[dict], name: str, value: int) -> None:
+    if counters is not None:
+        counters[name] = counters.get(name, 0) + value
+
+
+def decode_chunk(plan: ColumnChunkPlan, capacity: int,
+                 counters: Optional[dict] = None) -> DeviceColumn:
+    """Upload one chunk's page bytes + run tables and decode on device.
+    ``counters`` (the scan's, one dict per row group) takes the upload's
+    and the launch's host nanoseconds, the bytes uploaded and the chunk
+    itself: three clock reads a chunk, nothing per row."""
+    import time
     idx_bw, dtype = plan.idx_bit_width, plan.dtype
+    dict_string = plan.dict_rank is not None
+    has_idx = plan.idx_runs is not None
+    has_plain = plan.plain_values is not None
+    pad = bucket_byte_capacity(max(len(plan.def_runs.kinds),
+                              len(plan.idx_runs.kinds) if has_idx else 1,
+                              1), 8)
 
     def build():
         def kern(dt, it, pk, pl, dtab, n):
@@ -506,32 +511,65 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int) -> DeviceColumn:
         return kern
     kern = cached_kernel(
         "parquet_decode",
-        (dtype.name, capacity, idx_bw, idx_table is not None, dict_string,
-         plain is not None, pad),
-        build)
-    data, validity = kern(def_table, idx_table, packed_dev, plain,
-                          dict_table, jnp.asarray(plan.n_rows, jnp.int32))
+        (dtype.name, capacity, idx_bw, has_idx, dict_string, has_plain,
+         pad),
+        build,
+        suffix=f"{dtype.name}_bw{idx_bw}_"
+               + ("dictstr" if dict_string else "dict" if has_idx
+                  else "plain"))
+
+    # Host staging (pad to the bucketed shapes), then every host->device
+    # copy of the chunk in one timed stretch.
+    host = {"def": _runs_arrays(plan.def_runs, pad),
+            "idx": _runs_arrays(plan.idx_runs, pad) if has_idx else None,
+            "packed": _pad_packed(plan.packed),
+            "n_rows": np.asarray(plan.n_rows, np.int32)}
+    if dict_string:
+        host["dict"] = _bucketed(plan.dict_rank, np.int32)
+        byte_cap = bucket_byte_capacity(max(int(plan.dict_offsets[-1]), 1))
+        payload = np.zeros(byte_cap, np.uint8)
+        payload[: len(plan.dict_payload)] = plan.dict_payload
+        host["payload"] = payload
+        host["offsets"] = plan.dict_offsets
+    elif plan.dict_values is not None:
+        host["dict"] = _bucketed(plan.dict_values, plan.dict_values.dtype)
+    if has_plain:
+        buf = np.zeros(capacity, plan.plain_values.dtype)
+        buf[: len(plan.plain_values)] = plan.plain_values
+        host["plain"] = buf
+    t0 = time.perf_counter_ns()
+    dev = jax.tree_util.tree_map(jnp.asarray, host)
+    t1 = time.perf_counter_ns()
+    data, validity = kern(dev["def"], dev["idx"], dev["packed"],
+                          dev.get("plain"), dev.get("dict"), dev["n_rows"])
+    t2 = time.perf_counter_ns()
+    _count(counters, "scanUploadNs", t1 - t0)
+    _count(counters, "uploadBytes",
+           sum(a.nbytes for a in jax.tree_util.tree_leaves(host)))
+    _count(counters, "scanLaunchNs", t2 - t1)
+    _count(counters, "scanColumnChunksDecoded", 1)
     if dict_string:
         max_bytes = 8
         if plan.dict_offsets is not None and len(plan.dict_offsets) > 1:
             max_bytes = bucket_byte_capacity(
                 int(np.diff(plan.dict_offsets).max() or 1), 8)
-        byte_cap = bucket_byte_capacity(max(int(plan.dict_offsets[-1]), 1))
-        payload = np.zeros(byte_cap, np.uint8)
-        payload[: len(plan.dict_payload)] = plan.dict_payload
         return DeviceColumn(
-            data=jnp.asarray(payload), validity=validity, dtype=T.STRING,
-            offsets=jnp.asarray(plan.dict_offsets), max_bytes=max_bytes,
+            data=dev["payload"], validity=validity, dtype=T.STRING,
+            offsets=dev["offsets"], max_bytes=max_bytes,
             codes=data, dict_sorted=True)
     return DeviceColumn(data=data, validity=validity, dtype=plan.dtype)
 
 
 def decode_row_group(path: str, row_group: int, schema: T.Schema,
-                     pf=None, meta=None, pq_schema=None) -> ColumnarBatch:
+                     pf=None, meta=None, pq_schema=None,
+                     counters: Optional[dict] = None) -> ColumnarBatch:
     """Decode one row group of a parquet file into a device batch.
     Pass either an open ``pyarrow.parquet.ParquetFile`` or its parsed
     ``(meta, pq_schema)`` to amortize the footer parse across a file's
-    row groups (metadata objects hold no file descriptor)."""
+    row groups (metadata objects hold no file descriptor). ``counters``
+    is added to in place: ``scanParseNs`` here (file read, page headers,
+    decompression, run tables), the rest in :func:`decode_chunk`."""
+    import time
     import pyarrow.parquet as pq
     if meta is None:
         if pf is None:
@@ -546,10 +584,12 @@ def decode_row_group(path: str, row_group: int, schema: T.Schema,
     with open(path, "rb") as f:
         for field in schema:
             ci = name_to_idx[field.name]
+            t0 = time.perf_counter_ns()
             plan = plan_column_chunk(
                 f, md.column(ci), field,
                 pq_schema.column(ci).max_definition_level)
-            cols.append(decode_chunk(plan, capacity))
+            _count(counters, "scanParseNs", time.perf_counter_ns() - t0)
+            cols.append(decode_chunk(plan, capacity, counters))
     return ColumnarBatch(tuple(cols), jnp.asarray(n_rows, jnp.int32),
                          schema)
 
@@ -677,15 +717,18 @@ class TpuParquetScanExec:
 
         def read_unit(unit):
             path, meta, pq_schema, rg = unit
+            from ..metrics.trace import span
             from ..utils.fault_injection import maybe_inject
-            from ..utils.tracing import trace_range
             n_rows = meta.row_group(rg).num_rows
+            io: Dict[str, int] = {}
             try:
                 maybe_inject(ctx, "io.parquet.rowGroup")
                 with ctx.registry.timer(name, "opTime",
-                                        trace="parquet.device_decode"):
+                                        trace="parquet.device_decode",
+                                        owner=ctx.trace):
                     batch = decode_row_group(path, rg, self._schema,
-                                             meta=meta, pq_schema=pq_schema)
+                                             meta=meta, pq_schema=pq_schema,
+                                             counters=io)
                 ctx.metric(name, "deviceDecodedRowGroups", 1)
             # ANY decode failure (unsupported shape, decompression codec
             # mismatch, corrupt/truncated page metadata) degrades to the
@@ -696,7 +739,7 @@ class TpuParquetScanExec:
             except Exception:  # noqa: BLE001 - graceful per-unit fallback
                 if ctx.conf.test_enabled:
                     raise
-                with trace_range("parquet.host_fallback"), \
+                with span(ctx.trace, "parquet.host_fallback"), \
                         pq.ParquetFile(path) as pf:
                     tbl = pf.read_row_group(
                         rg, columns=self._schema.names)
@@ -710,6 +753,10 @@ class TpuParquetScanExec:
                     batch = ColumnarBatch.from_arrow(
                         rb.cast(T.schema_to_arrow(self._schema)))
                 ctx.metric(name, "hostFallbackRowGroups", 1)
+            finally:
+                # what a failed device attempt spent is still the scan's
+                for key, value in io.items():
+                    ctx.metric(name, key, value)
             ctx.metric(name, "numOutputRows", n_rows)
             ctx.metric(name, "numOutputBatches", 1)
             return batch

@@ -31,7 +31,7 @@ import pyarrow.compute as pc
 from .. import types as T
 from ..data.batch import HostBatch
 from ..plan.physical import ExecContext, PhysicalPlan
-from ..utils.tracing import trace_range
+from ..metrics.trace import span
 
 #: Spark-compatible save modes.
 MODES = ("error", "overwrite", "append", "ignore")
@@ -67,7 +67,7 @@ def _write_one(data, fmt: str, path: str, options: Dict) -> int:
     table = data if isinstance(data, pa.Table) else pa.Table.from_batches(
         [data])
     compression = options.get("compression")
-    with trace_range(f"write.{fmt}"):
+    with span(None, f"write.{fmt}"):
         if fmt == "parquet":
             import pyarrow.parquet as pq
             pq.write_table(table, path,
@@ -300,7 +300,7 @@ class TpuWriteFilesExec(_WriteFilesBase):
             sort). File emission stays OUTSIDE the retry: a retried
             attempt must never re-write a committed file."""
             if part_ordinals:
-                with trace_range("write.device_partition_sort"):
+                with span(ctx.trace, "write.device_partition_sort"):
                     b = KR.sort_batch(b, part_ordinals,
                                       [True] * len(part_ordinals),
                                       [True] * len(part_ordinals))
@@ -341,7 +341,7 @@ class TpuWriteFilesExec(_WriteFilesBase):
         the encoder's scope (caller falls back to the host Arrow path)."""
         from .parquet_encode import NotDeviceEncodable, write_device_batch
         target = os.path.join(self.path, self._file_name(task_id, 0))
-        with trace_range("write.parquet_device_encode"):
+        with span(None, "write.parquet_device_encode"):
             try:
                 # `or "snappy"`: an explicit compression=None means snappy
                 # on the host path too (_write_one) — keep one codec per job.

@@ -52,7 +52,6 @@ class DeviceManager:
             conf.get(CONCURRENT_ACQUIRE_TIMEOUT))
         self._devices = None
         self._hbm_budget = None
-        self._peak_in_use = 0
         self._init_lock = lockdep.lock("DeviceManager._init_lock", io_ok=True)
         self._warned_probes: set = set()
         # Spill catalog: the GpuShuffleEnv.initStorage chain
@@ -139,26 +138,23 @@ class DeviceManager:
                 inst.catalog.close()
             cls._instances.clear()
 
-    def memory_in_use(self) -> int:
+    def hbm_watermarks(self, device_session: bool = False) -> dict:
+        """HBM usage snapshot for the query profile, as the device's
+        allocator reports it (``memory_stats()``: ``bytes_in_use`` now,
+        ``peak_bytes_in_use`` the process's high-water mark so far — not
+        this query's alone; 0 where the backend keeps no stats, as the
+        CPU's). Never initializes the backend on its own: a CPU-oracle
+        session (sql.enabled=false) querying its profile must not touch
+        the accelerator, so the watermarks report 0 until something has
+        resolved the devices (the lazy-init contract above). A device
+        session's query has run on the device whether or not it came
+        through this manager; ``device_session`` resolves them here."""
+        if self._devices is None and not device_session:
+            return {"hbmBytesInUse": 0, "hbmPeakBytesInUse": 0}
         try:
             stats = self.device.memory_stats() or {}
-            used = stats.get("bytes_in_use", 0)
         except Exception as e:  # noqa: BLE001 - classify-narrowed
-            self._classify_probe_failure("memory_stats(bytes_in_use)", e)
-            used = 0
-        # Under the init lock: concurrent queries race the read-compare-
-        # write otherwise and the watermark can go backwards.
-        with self._init_lock:
-            if used > self._peak_in_use:
-                self._peak_in_use = used
-        return used
-
-    def hbm_watermarks(self) -> dict:
-        """HBM usage snapshot for the query profile. NEVER initializes the
-        backend: a CPU-oracle session (sql.enabled=false) querying its
-        profile must not touch the accelerator — watermarks report 0 until
-        some device work has forced init (the lazy-init contract above)."""
-        if self._devices is None:
-            return {"hbmBytesInUse": 0, "hbmPeakBytesInUse": 0}
-        return {"hbmBytesInUse": self.memory_in_use(),
-                "hbmPeakBytesInUse": self._peak_in_use}
+            self._classify_probe_failure("memory_stats", e)
+            stats = {}
+        return {"hbmBytesInUse": int(stats.get("bytes_in_use", 0)),
+                "hbmPeakBytesInUse": int(stats.get("peak_bytes_in_use", 0))}

@@ -61,7 +61,7 @@ import pyarrow as pa
 from .. import types as T
 from ..data.batch import ColumnarBatch
 from ..utils import lockdep
-from ..utils.tracing import trace_range
+from ..metrics.trace import span
 
 
 # ---------------------------------------------------------------------------
@@ -605,7 +605,7 @@ class BufferCatalog:
             if src == StorageTier.DISK:
                 payload = self._read_disk_payload(entry)
                 host_rb = _ipc_deserialize(payload)
-            with trace_range("spill.reload_to_device"):
+            with span(None, "spill.reload_to_device"):
                 batch = ColumnarBatch.from_arrow(
                     host_rb, capacity=entry.meta.capacity)
         # Revert-and-re-raise: classification-neutral (the exception
@@ -1035,11 +1035,10 @@ class BufferCatalog:
                 self._spill_job(e, requester)
             else:
                 submitted.append((f, e))
-        from ..metrics import trace as _tracing
         err: Optional[BaseException] = None
         for f, e in submitted:
             try:
-                with _tracing.span(
+                with span(
                         requester.trace if requester is not None else None,
                         "spill.io_wait", cat="spill"), \
                         lockdep.blocking("spill.io_wait"):
@@ -1089,9 +1088,8 @@ class BufferCatalog:
         # it parents under the requesting query's trace root — concurrent
         # lane units show as overlapping spans, the proof the PR-11
         # off-lock engine actually overlaps.
-        from ..metrics import trace as _tracing
         try:
-            with _tracing.span(
+            with span(
                     requester.trace if requester is not None else None,
                     "spill.io", cat="spill",
                     tier=entry.moving_from or entry.tier,
@@ -1109,7 +1107,7 @@ class BufferCatalog:
         size = entry.meta.size_bytes
         t0 = time.perf_counter_ns()
         try:
-            with trace_range("spill.device_to_host"):
+            with span(None, "spill.device_to_host"):
                 rb = entry.device_batch.to_arrow()
         # Revert-and-re-raise: classification-neutral (the waiter's
         # retry site classifies the propagated exception).
@@ -1189,7 +1187,7 @@ class BufferCatalog:
                 return
             self._disk_appends += 1
         try:
-            with trace_range("spill.host_to_disk"):
+            with span(None, "spill.host_to_disk"):
                 payload = _ipc_serialize(entry.host_batch)
                 rng = self._disk().append(payload)
         except SpillFileClosedError:
@@ -1291,7 +1289,7 @@ class BufferCatalog:
             live = {bid: e.disk_range for bid, e in self._entries.items()
                     if e.disk_range is not None and not e.freed}
         try:
-            with trace_range("spill.compact_disk"):
+            with span(None, "spill.compact_disk"):
                 new_ranges = f.compact(live)
         except SpillFileClosedError:
             # close() landed between the snapshot and the rewrite (the

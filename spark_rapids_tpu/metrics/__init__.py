@@ -14,12 +14,13 @@ Three pieces (docs/monitoring.md):
 * :mod:`.eventlog` — crash-safe JSON-lines event log
   (``spark.rapids.tpu.metrics.eventLog.dir``), one line per query, with
   size-capped rotation for long-lived serving processes.
-* :mod:`.trace` — per-query distributed tracing (ISSUE 13,
-  ``spark.rapids.tpu.trace.enabled``): the span-tree engine, Chrome
-  trace-event export, wire-propagated trace context, and the
-  flight-recorder ring. Not re-exported here (call sites import the
-  module directly — its disabled path is one None check);
-  ``tools/trace_report.py`` is the analyzer.
+* :mod:`.trace` — the one span API (``span``: always a profiler
+  ``TraceAnnotation``, and under ``spark.rapids.tpu.trace.enabled`` the
+  per-query span tree too), Chrome trace-event export with the clock
+  pair that maps it onto the profiler's timeline, wire-propagated trace
+  context, and the flight-recorder ring. Not re-exported here (call
+  sites import the module directly); ``tools/trace_report.py`` is the
+  analyzer.
 """
 
 from .eventlog import EventLog

@@ -87,6 +87,32 @@ class QueryProfile:
         known = {f.name for f in dataclasses.fields(cls)}
         return cls(**{k: v for k, v in d.items() if k in known})
 
+    def totals(self) -> Dict[str, float]:
+        """Every numeric metric of the profile summed by name: THE sum of
+        a profile. Operators of one node name share one metrics entry
+        (the registry keys by ``node_name()``), so each node name of the
+        tree counts once, however many operators carry it; then every
+        extras node. The engine section is not in it."""
+        total: Dict[str, float] = {}
+        seen: set = set()
+
+        def add(metrics: dict) -> None:
+            for key, value in metrics.items():
+                if isinstance(value, (int, float)) \
+                        and not isinstance(value, bool):
+                    total[key] = total.get(key, 0) + value
+
+        def walk(node: dict) -> None:
+            if node["name"] not in seen:
+                seen.add(node["name"])
+                add(node["metrics"])
+            for child in node["children"]:
+                walk(child)
+        walk(self.tree)
+        for metrics in self.extras.values():
+            add(metrics)
+        return total
+
     # -- rendering ----------------------------------------------------------
     def render(self) -> str:
         """The metric-annotated EXPLAIN tree."""
@@ -177,10 +203,12 @@ class QueryProfiler:
         self._t0 = time.perf_counter_ns()
         from ..compile import executables as _exe
         from ..compile import warmup as _warmup
+        from ..compile import xla_events as _xla
         from ..ops.kernels import pallas as _pallas
         from ..utils import checksum as _ck
         from ..utils import kernel_cache as _kc
         self._kc0 = _kc.cache_stats()
+        self._xla0 = _xla.stats()
         self._exe0 = _exe.stats()
         self._warm0 = _warmup.stats()
         self._ck0 = _ck.stats()
@@ -202,11 +230,26 @@ class QueryProfiler:
 
         from ..compile import executables as _exe
         from ..compile import warmup as _warmup
+        from ..compile import xla_events as _xla
         from ..ops.kernels import pallas as _pallas
         from ..utils import checksum as _ck
         from ..utils import kernel_cache as _kc
         wall_ns = time.perf_counter_ns() - self._t0
         registry: MetricsRegistry = ctx.registry
+        # What JAX compiled over this query (process totals' delta, as
+        # the kernel cache's): on the TpuSession node too, because
+        # readers that sum the profile by node (QueryProfile.totals) do
+        # not read the engine section.
+        xla = _xla.stats()
+        xla_compile = {
+            "xlaCompileNs": _delta(xla, self._xla0, "compile_ns"),
+            "xlaCompiles": _delta(xla, self._xla0, "compiles"),
+            "persistentCacheHits": _delta(xla, self._xla0, "cache_hits"),
+            "persistentCacheMisses": _delta(xla, self._xla0,
+                                            "cache_misses"),
+        }
+        for cname, value in xla_compile.items():
+            registry.add("TpuSession", cname, value)
         tree = _tree_of(physical, registry)
         tree_names: set = set()
         _collect_names(tree, tree_names)
@@ -246,9 +289,11 @@ class QueryProfiler:
             "spillLockWaitNs": _delta(spill, self._spill0,
                                       "spill_lock_wait_ns"),
             "deviceStoreBytes": dm.catalog.device_bytes,
-            **dm.hbm_watermarks(),
+            **dm.hbm_watermarks(
+                device_session=self._session.conf.sql_enabled),
             "compile": {
-                "compileNs": _delta(kc, self._kc0, "build_ns"),
+                "kernelBuildNs": _delta(kc, self._kc0, "build_ns"),
+                **xla_compile,
                 "kernelCompiles": _delta(kc, self._kc0, "misses"),
                 "kernelHits": _delta(kc, self._kc0, "hits"),
                 "fusedPrograms": exe.get("programs", 0),

@@ -15,16 +15,20 @@ Level gating happens at record time: a metric above the configured level
 level NONE the registry is inert — ``ExecContext.metric`` becomes a no-op
 and no timing fences are ever inserted (asserted by tests/test_metrics.py).
 
-Timing metrics are NANO_TIMING kind, implemented on
-:class:`..utils.tracing.NanoTimer` so every timed span doubles as an
-XProf/TraceAnnotation range (the NvtxWithMetrics coupling).
+Timing metrics are NANO_TIMING kind; :meth:`MetricsRegistry.timer`
+couples each one with a :func:`..metrics.trace.span`, so every timed
+region doubles as an XProf/TraceAnnotation range (the NvtxWithMetrics
+coupling).
 """
 
 from __future__ import annotations
 
+import contextlib
+import time
 from typing import Dict, Optional
 
 from ..utils import lockdep
+from .trace import span
 
 # ---------------------------------------------------------------------------
 # Levels (GpuMetric.scala: ESSENTIAL/MODERATE/DEBUG) and kinds.
@@ -88,7 +92,9 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "serializes the pipeline, so it never runs on the default path."),
     _spec("uploadBytes", MetricKind.SUM, ESSENTIAL,
           "Host->device bytes transferred (Arrow buffer footprint at the "
-          "HostToDevice boundary)."),
+          "HostToDevice boundary; in a device parquet scan the padded "
+          "page bytes, run tables and dictionaries of every column "
+          "chunk)."),
     _spec("downloadBytes", MetricKind.SUM, ESSENTIAL,
           "Device->host bytes transferred (result downloads, including the "
           "fused head transfer)."),
@@ -107,10 +113,6 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
     _spec("semaphoreWaitNs", MetricKind.NANO_TIMING, MODERATE,
           "Time blocked acquiring the task-admission semaphore "
           "(spark.rapids.sql.concurrentTpuTasks)."),
-    _spec("compileNs", MetricKind.NANO_TIMING, MODERATE,
-          "Host time spent building/tracing kernels this query "
-          "(kernel-cache misses; XLA backend compile time is async and "
-          "shows up in deviceTime on first dispatch)."),
     _spec("shuffleBytesWritten", MetricKind.SUM, ESSENTIAL,
           "Serialized shuffle bytes written to the block catalog."),
     _spec("shuffleBytesRead", MetricKind.SUM, ESSENTIAL,
@@ -139,6 +141,41 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "host reader served instead. Zero when the scan ran on the "
           "device; under spark.rapids.sql.test.enabled such a row group "
           "raises instead."),
+    _spec("scanParseNs", MetricKind.NANO_TIMING, ESSENTIAL,
+          "Device parquet scan, host side of a column chunk: file read, "
+          "page headers, decompression, run tables "
+          "(io/parquet_device.py plan_column_chunk). Thread-seconds "
+          "summed over the decode producers."),
+    _spec("scanUploadNs", MetricKind.NANO_TIMING, ESSENTIAL,
+          "Device parquet scan: the host->device copies of a column "
+          "chunk's packed bytes, run tables and dictionaries (the "
+          "jnp.asarray calls of decode_chunk), summed over the "
+          "producers; the bytes are in uploadBytes."),
+    _spec("scanLaunchNs", MetricKind.NANO_TIMING, ESSENTIAL,
+          "Device parquet scan: the call that enqueues a column chunk's "
+          "decode program (trace and compile on its first visit). Grows "
+          "when a producer stands behind the device's queue."),
+    _spec("scanColumnChunksDecoded", MetricKind.SUM, ESSENTIAL,
+          "Column chunks the device parquet scan decoded: row groups x "
+          "columns of the scan's schema, per run of the plan."),
+    _spec("planRuns", MetricKind.SUM, ESSENTIAL,
+          "Runs of the plan behind one collect()/cache(): 1, plus "
+          "join-capacity re-runs and dispatch retries (TpuSession node; "
+          "session.py _run_with_retries)."),
+    _spec("xlaCompileNs", MetricKind.NANO_TIMING, ESSENTIAL,
+          "Seconds in JAX's backend_compile_duration events over the "
+          "query: XLA compiles, or the persistent cache's lookups and "
+          "loads (compile/xla_events.py; a delta of process totals, on "
+          "the TpuSession node and in engine.compile)."),
+    _spec("xlaCompiles", MetricKind.SUM, ESSENTIAL,
+          "Programs JAX handed to the backend over the query "
+          "(backend_compile_duration events), compiled or loaded."),
+    _spec("persistentCacheHits", MetricKind.SUM, ESSENTIAL,
+          "Programs loaded from JAX's persistent compilation cache over "
+          "the query."),
+    _spec("persistentCacheMisses", MetricKind.SUM, ESSENTIAL,
+          "Programs compiled over the query because the persistent "
+          "cache did not hold them, and written to it."),
     _spec("peakDeviceBytes", MetricKind.PEAK, MODERATE,
           "Peak device bytes observed (HBM watermark where the backend "
           "reports it)."),
@@ -259,6 +296,12 @@ class TpuMetric:
             self._sum += value
             self._count += 1
         else:
+            if not isinstance(self._sum, (int, float)) \
+                    or isinstance(self._sum, bool):
+                # set() takes whatever a legacy dict writer assigned; a
+                # non-numeric leftover counts as 0 rather than raising
+                # mid-metric.
+                self._sum = 0
             self._sum += value
 
     def set(self, value) -> None:
@@ -276,17 +319,6 @@ class TpuMetric:
         if self.spec.kind == MetricKind.AVERAGE:
             return self._sum / self._count if self._count else 0
         return self._sum
-
-
-class _NoopTimer:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_TIMER = _NoopTimer()
 
 
 class MetricsRegistry:
@@ -351,20 +383,29 @@ class MetricsRegistry:
             if m is not None:
                 m.set(value)
 
-    def timer(self, node: str, name: str, trace: Optional[str] = None):
-        """Exception-safe NANO_TIMING context manager, coupled with an
-        XProf trace range (the NvtxWithMetrics analog). The trace span is
-        emitted regardless of the metrics level — profiler visibility must
-        not depend on metric gating — but the clock reads and accumulation
-        are skipped when the metric is gated."""
+    def timer(self, node: str, name: str, trace: Optional[str] = None,
+              owner=None):
+        """Exception-safe NANO_TIMING context manager, coupled with a span
+        (the NvtxWithMetrics analog; ``owner`` is the query's tracer, as
+        for :func:`..metrics.trace.span`). The span is opened regardless
+        of the metrics level — profiler visibility must not depend on
+        metric gating — but the clock reads and accumulation are skipped
+        when the metric is gated."""
+        sp = span(owner, trace or f"{node}.{name}")
         if not self.records(name):
-            if trace is None:
-                return _NOOP_TIMER
-            from ..utils.tracing import trace_range
-            return trace_range(trace)
-        from ..utils.tracing import NanoTimer
-        return NanoTimer(trace or f"{node}.{name}",
-                         _NodeSink(self, node), name)()
+            return sp
+        return self._timed(node, name, sp)
+
+    @contextlib.contextmanager
+    def _timed(self, node: str, name: str, sp):
+        # Accumulates in a finally, so a body that raises still records
+        # the time it spent before the raise.
+        start = time.perf_counter_ns()
+        try:
+            with sp:
+                yield
+        finally:
+            self.add(node, name, time.perf_counter_ns() - start)
 
     # -- read side ----------------------------------------------------------
     def node_metrics(self, node: str) -> Dict[str, object]:
@@ -382,26 +423,6 @@ class MetricsRegistry:
 
     def legacy_view(self) -> "_LegacyMetricsView":
         return _LegacyMetricsView(self)
-
-
-class _NodeSink:
-    """Dict-shaped adapter binding NanoTimer (and other legacy dict
-    writers) to one node of a registry."""
-
-    __slots__ = ("_registry", "_node")
-
-    def __init__(self, registry: MetricsRegistry, node: str):
-        self._registry = registry
-        self._node = node
-
-    def get(self, key, default=0):
-        return self._registry.node_metrics(self._node).get(key, default)
-
-    def __setitem__(self, key, value):
-        self._registry.set_value(self._node, key, value)
-
-    def add(self, key, value):
-        self._registry.add(self._node, key, value)
 
 
 def _deprecated(what: str) -> None:

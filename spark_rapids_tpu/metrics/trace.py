@@ -10,11 +10,20 @@ map/fetch/recompute — and exports it as Chrome trace-event JSON
 
 Design rules, in the lockdep mold (utils/lockdep.py):
 
-* **Zero-cost default.** Tracing is off unless
-  ``spark.rapids.tpu.trace.enabled`` is set; disabled call sites pay one
-  ``None`` check and receive the shared :data:`NOOP_SPAN` context
-  manager — no allocation, no fences, bit-identical results (asserted by
-  tests/test_trace.py).
+* **One API, one clock.** :func:`span` is the engine's only way to open
+  a span. It always opens a ``jax.profiler.TraceAnnotation`` (the
+  NvtxRange analog: a named range on the host plane of the XProf
+  ``.xplane.pb``, beside the device operations; one TraceMe level check
+  while no profiler session runs) and, when the owner carries a tracer,
+  the tree span as well: one call, one name, both records. The tracer
+  samples ``time.time_ns()`` and ``time.perf_counter_ns()`` together at
+  its start and exports both (``otherData.clock``), so a tree span maps
+  onto the profiler's timeline, which runs on the Unix clock
+  (docs/monitoring.md#the-clock).
+* **Cheap default.** The span tree is off unless
+  ``spark.rapids.tpu.trace.enabled`` is set; disabled call sites get the
+  bare annotation: no tracer object, no fences, bit-identical results
+  (asserted by tests/test_trace.py).
 * **Named internals.** The tracer's own lock routes through the lockdep
   factories; span bookkeeping never blocks on I/O.
 * **Thread stitching.** Each tracer keeps a per-thread stack of open
@@ -55,6 +64,8 @@ import weakref
 import zlib
 from collections import deque
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from ..utils import lockdep
 
@@ -142,31 +153,18 @@ def wire_hash(trace_id: str) -> int:
 # ---------------------------------------------------------------------------
 
 
-class _NoopSpan:
-    """Shared do-nothing span: the disabled path's context manager.
-    One module-level instance, reused — entering it allocates nothing."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
-NOOP_SPAN = _NoopSpan()
-
-
-class _Span:
-    """One open span handle (context manager). Closed spans are stored as
-    plain dicts on the tracer; the handle itself is transient."""
+class _Span(TraceAnnotation):
+    """One open span handle (context manager): the tree span and its
+    ``TraceAnnotation`` twin in one object. Closed spans are stored as
+    plain dicts on the tracer; the handle itself is transient. The tree
+    span's clock reads sit inside the annotation's, microseconds apart."""
 
     __slots__ = ("tracer", "name", "cat", "span_id", "parent_id",
                  "t0_ns", "args")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  span_id: int, parent_id: int, args: Optional[dict]):
+        super().__init__(name)
         self.tracer = tracer
         self.name = name
         self.cat = cat
@@ -176,6 +174,7 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        super().__enter__()
         self.t0_ns = time.perf_counter_ns()
         self.tracer._push(self)
         return self
@@ -188,6 +187,7 @@ class _Span:
             a["error"] = type(exc).__name__
             self.args = a
         self.tracer._pop(self, time.perf_counter_ns())
+        super().__exit__(exc_type, exc, tb)
         return False
 
     def annotate(self, **kv) -> None:
@@ -222,6 +222,10 @@ class Tracer:
         self.tenant = tenant
         self.query_id: Optional[int] = None
         self.max_spans = max_spans
+        # Both clocks sampled together: spans run on perf_counter_ns, the
+        # profiler's host plane on the Unix clock; the pair maps one onto
+        # the other (to_chrome's otherData.clock).
+        self.unix_ns = time.time_ns()
         self.t0_ns = time.perf_counter_ns()
         self.spans: List[dict] = []
         self.dropped = 0
@@ -365,7 +369,9 @@ class Tracer:
             "displayTimeUnit": "ms",
             "otherData": {"trace_id": self.trace_id, "tenant": self.tenant,
                           "query_id": self.query_id, "version": VERSION,
-                          "dropped_spans": dropped},
+                          "dropped_spans": dropped,
+                          "clock": {"perf_counter_ns": self.t0_ns,
+                                    "unix_ns": self.unix_ns}},
         }
 
 
@@ -376,10 +382,10 @@ class Tracer:
 
 def span(owner, name: str, cat: str = "engine", **args):
     """THE instrumentation one-liner: ``with trace.span(ctx.trace,
-    "fusion.dispatch"):``. ``owner`` is None (disabled — returns the
-    shared no-op), a :class:`Tracer`, or a :class:`SpanCtx` fork."""
+    "fusion.dispatch"):``. ``owner`` is None (no span tree: the bare
+    ``TraceAnnotation``), a :class:`Tracer`, or a :class:`SpanCtx` fork."""
     if owner is None:
-        return NOOP_SPAN
+        return TraceAnnotation(name)
     if isinstance(owner, SpanCtx):
         return owner.tracer.span(name, cat,
                                  fallback_parent=owner.parent_id, **args)

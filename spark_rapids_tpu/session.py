@@ -77,6 +77,7 @@ class TpuSession:
         # (the serving pool) no longer clobber a single slot —
         # last_query_profile() stays as the last-slot shim.
         self._last_profile = None
+        self._last_load_profile = None
         self._query_seq = 0
         self._event_log = None
         self._profiles = {}
@@ -135,6 +136,7 @@ class TpuSession:
         from .exec import pipeline as pipeline_layer
         pipeline_layer.configure(s.conf)
         s._last_profile = None
+        s._last_load_profile = None
         s._query_seq = 0
         s._event_log = None
         s._profiles = {}
@@ -397,6 +399,10 @@ class TpuSession:
         # would vanish with its context, under-reporting recovery in the
         # profile and the bench `faults` section.
         durability_carry: Dict[str, int] = {}
+        # Runs of the plan behind this one call: 1, plus capacity re-runs
+        # and dispatch retries. Carried the same way onto the final
+        # context's TpuSession node.
+        plan_runs = 0
 
         def _harvest_durability(c) -> None:
             from .metrics.profile import (DURABILITY_COUNTERS,
@@ -442,6 +448,7 @@ class TpuSession:
                     with TR.span(trace, "session.dispatch", cat="session",
                                  attempt=attempt, retry=dispatch_try), \
                             self.device_manager.semaphore:
+                        plan_runs += 1
                         result, overflowed = fn(
                             ctx, "eager" if eager else "deferred")
                     if dispatch_retries:
@@ -486,6 +493,7 @@ class TpuSession:
                 # belongs to this query's profile.
                 for cname, v in durability_carry.items():
                     ctx.metric("TpuSession", cname, v)
+                ctx.metric("TpuSession", "planRuns", plan_runs)
                 if plan_sig is not None and (caps or dense_modes):
                     if len(self._JOIN_CAP_CACHE) > 512:
                         self._JOIN_CAP_CACHE.pop(
@@ -688,9 +696,7 @@ class TpuSession:
             n = sum(int(b.n_rows) for p in parts for b in p)
             return L.CachedRelation(logical.schema, device_parts=parts,
                                     n_rows=n), False
-        from .utils.kernel_cache import plan_signature
-        return self._run_with_retries(run,
-                                      plan_sig=plan_signature(device_root))
+        return self._run_load(run, device_root)
 
     def collect_device(self, logical: L.LogicalPlan) -> List:
         """Execute and return HBM-resident ColumnarBatches with NO host
@@ -713,9 +719,28 @@ class TpuSession:
             if fusion.any_overflow(ctx):
                 return None, True
             return [b for p in parts for b in p], False
+        return self._run_load(run, device_root)
+
+    def _run_load(self, run, device_root):
+        """Run a load (``materialize`` / ``collect_device``: the batches
+        stay on the device) under a QueryProfiler, as ``execute`` runs a
+        query. Its profile goes to the event log and to the load's own
+        slot, :meth:`last_load_profile` — not to
+        :meth:`last_query_profile`, whose readers sum queries."""
+        from .metrics.profile import QueryProfiler
         from .utils.kernel_cache import plan_signature
-        return self._run_with_retries(run,
-                                      plan_sig=plan_signature(device_root))
+        profiler = QueryProfiler.maybe(self)
+        final = {}
+
+        def noted(ctx, mode):
+            final["ctx"] = ctx  # concurrency: ignore - the query thread
+            return run(ctx, mode)
+        sig = plan_signature(device_root)
+        result = self._run_with_retries(noted, plan_sig=sig)
+        if profiler is not None and final.get("ctx") is not None:
+            self._note_profile(profiler, device_root, final["ctx"], sig,
+                               load=True)
+        return result
 
     def explain(self, logical: L.LogicalPlan) -> str:
         physical = self.plan(logical)
@@ -727,7 +752,8 @@ class TpuSession:
     _MAX_PROFILES = 256
 
     def _note_profile(self, profiler, physical, ctx, plan_sig,
-                      profile_sink=None, tracer=None) -> None:
+                      profile_sink=None, tracer=None,
+                      load: bool = False) -> None:
         """Snapshot the finished query into the session's per-query-id
         profile map, the last-slot shim, and the structured event log
         (best-effort: observability must never fail a query). Query ids
@@ -749,7 +775,10 @@ class TpuSession:
             self._profiles[qid] = prof
             while len(self._profiles) > self._MAX_PROFILES:
                 self._profiles.pop(next(iter(self._profiles)))
-            self._last_profile = prof
+            if load:
+                self._last_load_profile = prof
+            else:
+                self._last_profile = prof
             log_dir = self.conf.metrics_event_log_dir
             log = None
             if log_dir:
@@ -787,6 +816,14 @@ class TpuSession:
         (or ``execute``'s ``profile_sink``) for race-free attribution."""
         with self._profiles_lock:
             return self._last_profile
+
+    def last_load_profile(self):
+        """The QueryProfile of the most recent load this session ran —
+        ``DataFrame.cache()`` (``materialize``) or ``collect_device`` —
+        or None. What the load read, decoded, uploaded and compiled;
+        kept apart from :meth:`last_query_profile`."""
+        with self._profiles_lock:
+            return self._last_load_profile
 
     def last_trace(self):
         """The :class:`~spark_rapids_tpu.metrics.trace.Tracer` of the

@@ -104,15 +104,14 @@ def _serve_span(trace64: int, span64: int, name: str, **args):
     """Server-side span stitched under the requesting client's span —
     the live-trace registry resolves same-process peers to the ONE
     tracer; an unknown trace id (cross-process peer whose tracer lives
-    elsewhere) records a flight-recorder event instead."""
+    elsewhere) records a flight-recorder event instead and opens the
+    bare profiler annotation."""
     from ..metrics import trace as TR
-    if not trace64:
-        return TR.NOOP_SPAN
-    tracer = TR.live_tracer(trace64)
-    if tracer is None:
+    tracer = TR.live_tracer(trace64) if trace64 else None
+    if trace64 and tracer is None:
         TR.record_event(name, **args)
-        return TR.NOOP_SPAN
-    return TR.span(TR.SpanCtx(tracer, span64), name, cat="shuffle", **args)
+    owner = TR.SpanCtx(tracer, span64) if tracer is not None else None
+    return TR.span(owner, name, cat="shuffle", **args)
 
 
 class ShuffleFetchFailedError(Exception):

@@ -2,7 +2,7 @@
 
 * registry kinds/levels (GpuMetric analog): accumulation semantics, level
   gating, the NONE-is-inert contract;
-* NanoTimer exception safety (metric accumulates even when the body
+* registry.timer exception safety (metric accumulates even when the body
   raises) and non-numeric merge (the seed's overwrite bug);
 * the deprecated ExecContext.metrics dict shim (reads silent, writes warn);
 * thread-safety hammer (warm-up + transport threads report concurrently);
@@ -126,29 +126,32 @@ class TestRegistry:
         assert m["opTime"] == 2 * n_threads * n_iter
 
 
-class TestNanoTimer:
+class TestRegistryTimer:
+    """The NanoTimer cases, moved with its body into registry.timer."""
+
     def test_exception_still_accumulates(self):
-        from spark_rapids_tpu.utils.tracing import NanoTimer
-        metrics = {}
+        r = MetricsRegistry(DEBUG)
         with pytest.raises(RuntimeError):
-            with NanoTimer("t", metrics, "ns")():
+            with r.timer("N", "ns", trace="t"):
                 raise RuntimeError("body failed")
-        assert metrics["ns"] > 0
+        assert r.node_metrics("N")["ns"] > 0
 
     def test_non_numeric_existing_value_merges_not_raises(self):
-        from spark_rapids_tpu.utils.tracing import NanoTimer
-        metrics = {"ns": "corrupt"}
-        with NanoTimer("t", metrics, "ns")():
+        r = MetricsRegistry(DEBUG)
+        r.set_value("N", "ns", "corrupt")
+        with r.timer("N", "ns", trace="t"):
             pass
-        assert isinstance(metrics["ns"], int) and metrics["ns"] > 0
+        ns = r.node_metrics("N")["ns"]
+        assert isinstance(ns, int) and ns > 0
 
     def test_registry_sink(self):
-        from spark_rapids_tpu.metrics.registry import _NodeSink
+        from spark_rapids_tpu.metrics import trace as TR
         r = MetricsRegistry(DEBUG)
-        from spark_rapids_tpu.utils.tracing import NanoTimer
-        with NanoTimer("t", _NodeSink(r, "N"), "opTime")():
+        tracer = TR.Tracer("t-registry-timer")
+        with r.timer("N", "opTime", trace="t", owner=tracer):
             pass
         assert r.node_metrics("N")["opTime"] > 0
+        assert [s["name"] for s in tracer.spans] == ["t"]
 
 
 class TestLegacyDictShim:
