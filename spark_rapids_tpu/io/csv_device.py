@@ -249,9 +249,13 @@ def _build_parse_kernel(dtypes: Tuple[str, ...], widths: Tuple[int, ...],
 
 
 def decode_file(path: str, schema: T.Schema, options: dict,
-                max_rows: int = 1 << 20):
+                max_rows: int = 1 << 20,
+                file_schema: Optional[T.Schema] = None):
     """Yield ColumnarBatches parsed on device; NotCsvDecodable when the
-    file's DATA is out of scope (quotes, overlong numbers, ragged rows)."""
+    file's DATA is out of scope (quotes, overlong numbers, ragged rows).
+    A line holds every field of ``file_schema``; only the fields of
+    ``schema`` (a subset, by name) are parsed on the device."""
+    file_schema = file_schema if file_schema is not None else schema
     buf = np.fromfile(path, dtype=np.uint8)
     q_opt = options.get("quote", '"')
     if q_opt not in (False, None, ""):
@@ -261,7 +265,10 @@ def decode_file(path: str, schema: T.Schema, options: dict,
             raise NotCsvDecodable("quoted fields")
     delim = ord(str(options.get("delimiter", ",")))
     header = bool(options.get("header", True))
-    starts, ends = _boundaries(buf, delim, len(schema), header)
+    starts, ends = _boundaries(buf, delim, len(file_schema), header)
+    if len(schema) < len(file_schema):
+        at = [file_schema.names.index(n) for n in schema.names]
+        starts, ends = starts[:, at], ends[:, at]
     n = len(starts)
     dev_buf = jax.device_put(buf if len(buf) else np.zeros(1, np.uint8))
     if n == 0:
@@ -315,9 +322,14 @@ class TpuCsvScanExec:
     children = ()
     children_coalesce_goals = None
 
-    def __init__(self, files: List[str], schema: T.Schema, options: dict):
+    def __init__(self, files: List[str], schema: T.Schema,
+                 file_schema: T.Schema, options: dict):
         self.files = list(files)
+        #: the fields the plan references (plan/optimizer.py), parsed on
+        #: the device; ``_file_schema`` is every field of a line, which the
+        #: host-side boundary pass needs to find them.
         self._schema = schema
+        self._file_schema = file_schema
         self.options = dict(options)
 
     @property
@@ -328,7 +340,9 @@ class TpuCsvScanExec:
         return "TpuCsvScanExec"
 
     def describe(self):
-        return f"TpuCsvScan files={len(self.files)}"
+        from .files import columns_read
+        return (f"TpuCsvScan files={len(self.files)} "
+                f"{columns_read(self._schema, self._file_schema)}")
 
     def tree_string(self, indent: int = 0) -> str:
         return "  " * indent + self.describe() + "\n"
@@ -348,8 +362,12 @@ class TpuCsvScanExec:
                 with ctx.registry.timer(name, "opTime",
                                         trace="csv.decode_file",
                                         owner=getattr(ctx, "trace", None)):
-                    return list(decode_file(path, self._schema,
-                                            self.options))
+                    batches = list(decode_file(
+                        path, self._schema, self.options,
+                        file_schema=self._file_schema))
+                ctx.metric(name, "scanColumnChunksDecoded",
+                           len(batches) * len(self._schema))
+                return batches
             except Exception as e:  # noqa: BLE001 - classify-narrowed
                 # Out-of-scope files (NotCsvDecodable) and classified
                 # device faults fall back to the host reader per file;

@@ -131,16 +131,29 @@ def to_arrow_filter(expr: Expression) -> Optional[ds.Expression]:
     return None
 
 
+def columns_read(schema: T.Schema, file_schema: T.Schema) -> str:
+    """``columns=4/16`` for a scan's line of explain: how many of the
+    files' columns it reads (the hidden __input_file_* columns are
+    synthesized, so they count on neither side)."""
+    from ..plan.input_file import META_NAMES
+    read = sum(n not in META_NAMES for n in schema.names)
+    held = sum(n not in META_NAMES for n in file_schema.names)
+    return f"columns={read}/{held}"
+
+
 class CpuFileScanExec(PhysicalPlan):
     """Host file scan; one partition per input fragment (file/row-group
     cluster), chunked by reader batch-size limits."""
 
     def __init__(self, fmt: str, paths: List[str], schema: T.Schema,
                  options: dict, pushed_filters: List[Expression],
-                 emit_file_meta: bool = False):
+                 file_schema: T.Schema, emit_file_meta: bool = False):
         self.fmt = fmt
         self.paths = paths
         self._schema = schema
+        #: every column of the files; ``schema`` is the part of it the plan
+        #: references (plan/optimizer.py) and the only part read.
+        self._file_schema = file_schema
         self.options = options
         self.pushed_filters = pushed_filters
         #: emit the hidden __input_file_* metadata columns (set by the
@@ -153,7 +166,8 @@ class CpuFileScanExec(PhysicalPlan):
         return self._schema
 
     def describe(self):
-        return f"CpuFileScan {self.fmt} {self.paths}"
+        return (f"CpuFileScan {self.fmt} {self.paths} "
+                f"{columns_read(self._schema, self._file_schema)}")
 
     def execute(self, ctx):
         import pyarrow as pa_mod
@@ -200,6 +214,8 @@ class CpuFileScanExec(PhysicalPlan):
             for rb in scanner.to_batches():
                 if not rb.num_rows:
                     continue
+                ctx.metric(self.node_name(), "scanColumnChunksDecoded",
+                           len(names))
                 rb = rb.cast(data_schema)
                 if meta_present:
                     n = rb.num_rows

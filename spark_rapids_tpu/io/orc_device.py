@@ -704,9 +704,10 @@ class TpuOrcScanExec:
     children_coalesce_goals = None
 
     def __init__(self, files: List[str], schema: T.Schema,
-                 tails: Optional[dict] = None):
+                 file_schema: T.Schema, tails: Optional[dict] = None):
         self.files = list(files)
         self._schema = schema
+        self._file_schema = file_schema
         self._tails = dict(tails or {})
 
     @property
@@ -717,7 +718,9 @@ class TpuOrcScanExec:
         return "TpuOrcScanExec"
 
     def describe(self):
-        return f"TpuOrcScan files={len(self.files)}"
+        from .files import columns_read
+        return (f"TpuOrcScan files={len(self.files)} "
+                f"{columns_read(self._schema, self._file_schema)}")
 
     def tree_string(self, indent: int = 0) -> str:
         return "  " * indent + self.describe() + "\n"
@@ -743,7 +746,10 @@ class TpuOrcScanExec:
                 with ctx.registry.timer(name, "opTime",
                                         trace="orc.device_decode_stripe",
                                         owner=getattr(ctx, "trace", None)):
-                    return decode_stripe(path, tail, si, self._schema)
+                    batch = decode_stripe(path, tail, si, self._schema)
+                ctx.metric(name, "scanColumnChunksDecoded",
+                           len(self._schema))
+                return batch
             except Exception as e:  # noqa: BLE001 - classify-narrowed
                 # parsers translate malformed-input errors to
                 # NotOrcDecodable at their boundary (_parse_boundary), and

@@ -669,9 +669,14 @@ class TpuParquetScanExec:
     children = ()
     children_coalesce_goals = None
 
-    def __init__(self, files: List[str], schema: T.Schema, pf_cache=None):
+    def __init__(self, files: List[str], schema: T.Schema,
+                 file_schema: T.Schema, pf_cache=None):
         self.files = list(files)
+        #: the columns the plan references (plan/optimizer.py): the only
+        #: chunks parsed, uploaded and decoded. ``_file_schema`` is every
+        #: column of the files, for explain.
         self._schema = schema
+        self._file_schema = file_schema
         # Parsed footers carried from the planning-time gate so each one
         # parses ONCE: {path: (FileMetaData, ParquetSchema)} — metadata
         # objects only, NOT open file handles (a thousand-file scan must
@@ -687,7 +692,9 @@ class TpuParquetScanExec:
         return "TpuParquetScanExec"
 
     def describe(self):
-        return f"TpuParquetScan files={len(self.files)}"
+        from .files import columns_read
+        return (f"TpuParquetScan files={len(self.files)} "
+                f"{columns_read(self._schema, self._file_schema)}")
 
     def tree_string(self, indent: int = 0) -> str:
         return "  " * indent + self.describe() + "\n"

@@ -156,8 +156,10 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "decode program (trace and compile on its first visit). Grows "
           "when a producer stands behind the device's queue."),
     _spec("scanColumnChunksDecoded", MetricKind.SUM, ESSENTIAL,
-          "Column chunks the device parquet scan decoded: row groups x "
-          "columns of the scan's schema, per run of the plan."),
+          "Column chunks a file scan decoded, per run of the plan: row "
+          "groups (parquet), stripes (ORC), file slices (CSV) or record "
+          "batches (host scan) x columns of the scan's schema, which is "
+          "what the plan references (plan/optimizer.py)."),
     _spec("planRuns", MetricKind.SUM, ESSENTIAL,
           "Runs of the plan behind one collect()/cache(): 1, plus "
           "join-capacity re-runs and dispatch retries (TpuSession node; "

@@ -33,6 +33,8 @@ FILE_LENGTH_COL = "__input_file_block_length"
 META_FIELDS = [T.StructField(FILE_NAME_COL, T.STRING, False),
                T.StructField(FILE_START_COL, T.LONG, False),
                T.StructField(FILE_LENGTH_COL, T.LONG, False)]
+#: their names: synthesized per fragment, never read from a file
+META_NAMES = frozenset(f.name for f in META_FIELDS)
 
 _COL_OF = {InputFileName: FILE_NAME_COL,
            InputFileBlockStart: FILE_START_COL,

@@ -725,7 +725,8 @@ def _device_scan_or_none(node: P.PhysicalPlan, conf: Optional[TpuConf]):
         if any("=" in part for f in files
                for part in below_root(f).split(os.sep)):
             return None
-        return CD.TpuCsvScanExec(files, node.schema, node.options)
+        return CD.TpuCsvScanExec(files, node.schema, node._file_schema,
+                                 node.options)
     if node.fmt == "orc" and conf.get(ORC_DEVICE_DECODE):
         from ..io import orc_device as OD
         files = OD.scan_files(node.paths)
@@ -740,7 +741,7 @@ def _device_scan_or_none(node: P.PhysicalPlan, conf: Optional[TpuConf]):
             if not OD.device_decodable(f, node.schema, tail):
                 return None
             tails[f] = tail
-        return OD.TpuOrcScanExec(files, node.schema, tails)
+        return OD.TpuOrcScanExec(files, node.schema, node._file_schema, tails)
     if not conf.get(PARQUET_DEVICE_DECODE):
         return None
     if node.fmt != "parquet":
@@ -761,7 +762,8 @@ def _device_scan_or_none(node: P.PhysicalPlan, conf: Optional[TpuConf]):
             return None
         if not ok:
             return None
-    return PD.TpuParquetScanExec(files, node.schema, pf_cache)
+    return PD.TpuParquetScanExec(files, node.schema, node._file_schema,
+                                 pf_cache)
 
 
 def finalize_plan(plan: P.PhysicalPlan, conf: TpuConf) -> P.PhysicalPlan:

@@ -113,6 +113,7 @@ def plan_physical(plan: L.LogicalPlan,
         from ..io.files import CpuFileScanExec
         return CpuFileScanExec(plan.fmt, plan.paths, plan.schema,
                                plan.options, plan.pushed_filters,
+                               plan._schema,
                                emit_file_meta=getattr(
                                    plan, "emit_file_meta", False))
     if isinstance(plan, L.Project):

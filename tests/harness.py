@@ -115,3 +115,59 @@ def assert_tpu_and_cpu_are_equal(
         if not _rows_equal(c, t, approx):
             raise AssertionError(
                 f"row {i} differs:\nCPU: {c}\nTPU: {t}")
+
+
+# -- the projection reaches the scan (plan/optimizer.py) --------------------
+
+WIDE_COLUMNS = 16
+#: the four columns `wide_query` references, in file order
+WIDE_REFERENCED = ["c01", "c06", "c10", "c15"]
+
+
+def wide_table(rows: int = 3000, seed: int = 11) -> pa.Table:
+    """A table of 16 columns, c00..c15: bigint, double, double, string in
+    turn, so a query for four of them leaves every type unread too."""
+    rng = np.random.default_rng(seed)
+    words = np.array(["ash", "birch", "cedar", "elm", "fir", "oak"])
+    cols = {}
+    for i in range(WIDE_COLUMNS):
+        name = f"c{i:02d}"
+        if i % 4 == 0:
+            cols[name] = rng.integers(-10**9, 10**9, rows)
+        elif i % 4 == 3:
+            cols[name] = pa.array(words[rng.integers(0, len(words), rows)])
+        elif i % 4 == 1:
+            cols[name] = rng.integers(0, 1000, rows).astype(np.int64)
+        else:
+            cols[name] = np.round(rng.normal(scale=100.0, size=rows), 3)
+    return pa.table(cols)
+
+
+def wide_query(df):
+    """Whole rows of 4 of the 16 columns, in file order of the rows."""
+    from spark_rapids_tpu.ops.expression import col, lit
+    return df.where(col("c01") >= lit(250)).select(
+        *[col(n) for n in WIDE_REFERENCED])
+
+
+def assert_scan_reads_only_referenced(session, df, units: int, scan: str,
+                                      monkeypatch):
+    """``wide_query`` over ``df`` (a 16-column file of ``units`` row
+    groups, stripes, slices or batches) plans ``scan``, decodes 4 columns a
+    unit, and returns the very table the unpruned plan — a Project above a
+    scan of all 16, as before the projection reached the scan — returns."""
+    from spark_rapids_tpu.plan import optimizer
+    query = wide_query(df)
+    line = [ln for ln in session.explain(query._plan).splitlines()
+            if scan in ln]
+    assert line and "columns=4/16" in line[0], session.explain(query._plan)
+    got = query.collect()
+    totals = session.last_query_profile().totals()
+    assert totals["scanColumnChunksDecoded"] == units * 4
+    monkeypatch.setattr(optimizer, "_project_scan", lambda scan, req: scan)
+    assert "columns=16/16" in session.explain(query._plan)
+    want = query.collect()
+    totals = session.last_query_profile().totals()
+    assert totals["scanColumnChunksDecoded"] == units * WIDE_COLUMNS
+    assert got.num_rows and got.equals(want)
+    assert df._plan.projected is None

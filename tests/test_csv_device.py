@@ -184,3 +184,24 @@ class TestFallbacks:
                            T.StructField("b", T.LONG, True)])
         with pytest.raises(CD.NotCsvDecodable):
             self._decode_all(path, schema, {"header": True})
+
+
+def test_scan_parses_only_referenced_fields(tmp_path, monkeypatch):
+    """The projection reaches the scan (plan/optimizer.py): the boundary
+    pass still walks all 16 fields of a line, the device parses 4."""
+    from harness import assert_scan_reads_only_referenced, wide_table
+    path = str(tmp_path / "wide")
+    os.makedirs(path)
+    table = wide_table()
+    rows = list(zip(*[c.to_pylist() for c in table.columns]))
+    for i in range(3):   # unquoted: quoted fields go to the host reader
+        with open(os.path.join(path, f"part-{i}.csv"), "w") as f:
+            f.write(",".join(table.column_names) + "\n")
+            f.writelines(",".join(map(str, r)) + "\n"
+                         for r in rows[i * 1000:(i + 1) * 1000])
+    files = CD.scan_files([path])
+    assert len(files) == 3
+    s = tpu_session()
+    assert_scan_reads_only_referenced(s, s.read.csv(path), len(files),
+                                      "TpuCsvScan", monkeypatch)
+    assert s.last_query_profile().totals().get("fileHostFallback", 0) == 0

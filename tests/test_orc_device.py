@@ -180,3 +180,17 @@ class TestOrcDeviceDecode:
         tpu = TpuSession({"spark.rapids.sql.enabled": True})
         cpu = TpuSession({"spark.rapids.sql.enabled": False})
         assert q(tpu).collect().equals(q(cpu).collect())
+
+
+def test_scan_decodes_only_referenced_columns(tmp_path, monkeypatch):
+    """The projection reaches the scan (plan/optimizer.py)."""
+    from harness import (assert_scan_reads_only_referenced, tpu_session,
+                         wide_table)
+    path = _write(tmp_path, wide_table(rows=30_000), "wide.orc",
+                  stripe_size=64 << 10)
+    stripes = len(OD.read_tail(path).stripes)
+    assert stripes > 1
+    s = tpu_session()
+    assert_scan_reads_only_referenced(s, s.read.orc(path), stripes,
+                                      "TpuOrcScan", monkeypatch)
+    assert s.last_query_profile().totals().get("stripeHostFallback", 0) == 0

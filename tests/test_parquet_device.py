@@ -260,3 +260,68 @@ class TestRebaseGuard:
             "spark.sql.legacy.parquet.datetimeRebaseModeInRead": "LEGACY"})
         with pytest.raises(SparkUpgradeError, match="LEGACY"):
             self._scan(s, path).collect()
+
+
+# -- the projection reaches the scan (plan/optimizer.py) --------------------
+
+def test_scan_decodes_only_referenced_columns(tmp_path, monkeypatch):
+    from harness import assert_scan_reads_only_referenced, wide_table
+    path = str(tmp_path / "wide.parquet")
+    pq.write_table(wide_table(), path, row_group_size=1000)
+    s = tpu_session()
+    assert_scan_reads_only_referenced(s, s.read.parquet(path), 3,
+                                      "TpuParquetScan", monkeypatch)
+
+
+def test_unreferenced_plain_string_column_stays_on_device(tmp_path):
+    """A PLAIN byte-array column (as Spark writes a near-unique comment)
+    is outside the device decoder; unreferenced, it is not the scan's
+    business: test.enabled would raise if a row group went to the host."""
+    from harness import wide_query, wide_table
+    tbl = wide_table()
+    tbl = tbl.append_column("comment", pa.array(
+        [f"text {i}" for i in range(tbl.num_rows)]))
+    path = str(tmp_path / "plain.parquet")
+    pq.write_table(tbl, path, row_group_size=1000,
+                   use_dictionary=[n for n in tbl.column_names
+                                   if n != "comment"])
+    s = tpu_session()
+    df = s.read.parquet(path)
+    got = wide_query(df).collect()
+    totals = s.last_query_profile().totals()
+    assert totals["deviceDecodedRowGroups"] == 3
+    assert totals.get("hostFallbackRowGroups", 0) == 0
+    want = wide_query(cpu_session().read.parquet(path)).collect()
+    assert got.equals(want)
+    # referenced, the same column is refused: the device scan raises
+    with pytest.raises(Exception):
+        df.where(col("c01") >= 250).select(col("comment")).collect()
+
+
+def test_two_projections_of_one_file_hash_apart(tmp_path):
+    """The served path's result cache and breaker key on the plan hash:
+    the scan's schema is in the signature, so the projection is."""
+    from harness import wide_table
+    from spark_rapids_tpu.metrics.profile import plan_profile_hash
+    from spark_rapids_tpu.utils.kernel_cache import plan_signature
+    path = str(tmp_path / "wide.parquet")
+    pq.write_table(wide_table(), path, row_group_size=1000)
+    s = tpu_session()
+    df = s.read.parquet(path)
+
+    def hashes(name):
+        """(the plan's hash, its scan's) for a sum over one column."""
+        from spark_rapids_tpu.ops import aggregates as A
+        plan = s.plan(df.group_by().agg(
+            A.AggregateExpression(A.Sum(col(name)), "total"))._plan)
+        scan = plan
+        while scan.children:
+            scan = scan.children[0]
+        assert scan.schema.names == [name], plan.tree_string()
+        return tuple(plan_profile_hash(plan_signature(p))
+                     for p in (plan, scan))
+    assert hashes("c01") == hashes("c01")
+    # both bigint, both columns=1/16: only the name under the scan differs
+    plan_a, scan_a = hashes("c01")
+    plan_b, scan_b = hashes("c05")
+    assert plan_a != plan_b and scan_a != scan_b

@@ -167,6 +167,10 @@ def test_decode_and_fused_programs_carry_their_names(tmp_path):
     from spark_rapids_tpu.utils import kernel_cache
     path, _ = _lineitem(tmp_path)
     session = TpuSession(DEVICE)
+    # a filter that returns whole rows decodes every column of the file,
+    # Q6 only the ones it references (plan/optimizer.py): one program a
+    # (type, encoding)
+    session.read.parquet(path).where(col("l_orderkey") >= lit(0)).collect()
     _q6(session.read.parquet(path)).collect()
     names = {fn.__name__ for fn in kernel_cache._CACHE.values()}
     assert {"parquet_decode_bigint_bw0_plain",
@@ -235,10 +239,12 @@ def test_scan_counters_of_a_small_q6(tmp_path):
     profile = session.last_query_profile()
     totals = profile.totals()
     assert totals["deviceDecodedRowGroups"] == 3
-    # no projection reaches the scan yet: every column of the file
-    assert totals["scanColumnChunksDecoded"] == 3 * table.num_columns
+    # the projection reaches the scan: the 4 columns Q6 references of 6
+    assert totals["scanColumnChunksDecoded"] == 3 * 4
     assert totals["planRuns"] == 1
-    assert totals["uploadBytes"] > table.nbytes // 2
+    referenced = table.select(["l_quantity", "l_extendedprice",
+                               "l_discount", "l_shipdate"])
+    assert totals["uploadBytes"] > referenced.nbytes // 2
     for name in ("scanParseNs", "scanUploadNs", "scanLaunchNs"):
         assert totals[name] > 0, name
     assert totals["scanParseNs"] + totals["scanUploadNs"] \

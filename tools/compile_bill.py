@@ -9,6 +9,11 @@ and compiles each recorded program for one described v5e chip
 this sandbox's compiler, the shapes and programs are the engine's own.
 
     JAX_PLATFORMS=cpu python tools/compile_bill.py --queries q6,q1,q3
+    JAX_PLATFORMS=cpu python tools/compile_bill.py --cell tpch_sf1_parquet.q3
+
+``--cell`` records one cell of the benchmark instead (its set-up and one
+query of the window, from the specification's files; a cell that waits in
+``benchmarks/selfcheck.py`` too), to size a cell's first run.
 
 Eager ``jnp`` ops outside any ``jax.jit`` (tiny programs) are not counted.
 Programs compiled here cannot be read back on a chip, so the persistent
@@ -73,15 +78,27 @@ def main() -> int:
     ap.add_argument("--min-elements", type=int, default=1 << 16,
                     help="skip programs whose largest argument is smaller")
     ap.add_argument("--out", default=None, help="also write JSON lines here")
+    ap.add_argument("--cell", default=None,
+                    help="a cell of BENCHMARK.json (or one that waits in "
+                         "benchmarks/selfcheck.py) instead of --queries")
+    ap.add_argument("--seed", type=int, default=41, help="of --cell's data")
     args = ap.parse_args()
 
     jax.jit = _recording_jit          # before the engine is imported
-    import chip_smoke
     marks = []
-    for q in args.queries.split(","):
-        n0 = len(RECORDS)
-        chip_smoke.run([q], args.rows, 1)
-        marks.append((q, n0, len(RECORDS)))
+    if args.cell:
+        sys.path.insert(0, os.path.join(sys.path[0], "benchmarks"))
+        import selfcheck
+        run = selfcheck.run.run_cell(selfcheck.load_cell(args.cell),
+                                     args.seed, 0.0, False)
+        assert run["correct"], run["compared"]
+        marks.append((args.cell, 0, len(RECORDS)))
+    else:
+        import chip_smoke
+        for q in args.queries.split(","):
+            n0 = len(RECORDS)
+            chip_smoke.run([q], args.rows, 1)
+            marks.append((q, n0, len(RECORDS)))
     jax.jit = _real_jit
 
     from jax.experimental import topologies
