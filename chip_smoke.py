@@ -49,11 +49,11 @@ def ensure_parquet(rows: int, seed: int) -> dict:
         tables = tpch.gen_tables(rows, seed=seed)
         os.makedirs(out, exist_ok=True)
         for name, rb in tables.items():
-            # Strings dictionary-encoded, numbers PLAIN: what the device
-            # decoder reads today. pyarrow's default starts every column
-            # on a dictionary and falls back to PLAIN mid-chunk once it
-            # outgrows its page ("mixed PLAIN + dictionary pages"), which
-            # the decoder refuses at SF1 (ROADMAP S5).
+            # Strings dictionary-encoded, numbers PLAIN: the files the
+            # smoke's recorded timings were taken on. The decoder reads
+            # pyarrow's default too since PR 30 (numbers that start on a
+            # dictionary and fall back to PLAIN mid-chunk); the benchmark's
+            # tpch_sf1_parquet_writer_defaults cell holds that path.
             strings = [f.name for f in rb.schema if pa.types.is_string(f.type)]
             pq.write_table(pa.Table.from_batches([rb]),
                            os.path.join(out, f"{name}.parquet"),

@@ -25,7 +25,10 @@ group-by / join uses the fast code paths.
 
 Scope (falls back to the host scan otherwise, reference-style graceful
 degradation): v1 data pages, PLAIN + PLAIN_DICTIONARY/RLE_DICTIONARY
-encodings, flat schemas, dictionary bit widths <= 24.
+encodings, flat schemas, dictionary bit widths <= 24. A fixed-width chunk
+may start on its dictionary and finish in PLAIN pages, as parquet-mr and
+parquet-cpp write one whose dictionary page passes its size limit (1 MiB
+by default); PLAIN byte-array and boolean pages stay outside.
 """
 
 from __future__ import annotations
@@ -238,12 +241,15 @@ class ColumnChunkPlan:
     nullable: bool
     # definition-level hybrid (bw=1): validity
     def_runs: Optional[_HybridRuns]
-    # value source: either dictionary indices (hybrid) + dictionary, or
-    # PLAIN values uploaded directly
+    # value source: dictionary indices (hybrid) + dictionary, or PLAIN
+    # values uploaded directly, or (a chunk whose writer fell back) the
+    # first ``dict_count`` non-null values from the one, the rest from the
+    # other
     idx_runs: Optional[_HybridRuns]
     idx_bit_width: int
     packed: bytes             # shared packed buffer (def + idx bitpacks)
     plain_values: Optional[np.ndarray]
+    dict_count: int           # non-null values of the dictionary pages
     # dictionary: fixed-width values, or sorted string dict + rank remap
     dict_values: Optional[np.ndarray]
     dict_rank: Optional[np.ndarray]
@@ -289,6 +295,7 @@ def plan_column_chunk(f, col_md, field: T.StructField,
     plain_parts: List[bytes] = []
     idx_bw = 0
     n_rows = 0
+    dict_count = 0
     uses_dict = False
     uses_plain = False
     while pos < len(chunk):
@@ -321,7 +328,13 @@ def plan_column_chunk(f, col_md, field: T.StructField,
             def_runs.widths.append(1)
             non_null = ph.num_values
         if ph.encoding in (PLAIN_DICTIONARY, RLE_DICTIONARY):
+            if uses_plain:
+                # the writers' fallback is one-way: a slot's source is
+                # told by its position alone only in that order
+                raise NotImplementedError(
+                    "dictionary pages after PLAIN pages")
             uses_dict = True
+            dict_count += non_null
             bw = payload[p]
             p += 1
             if bw > 24:
@@ -340,17 +353,15 @@ def plan_column_chunk(f, col_md, field: T.StructField,
         else:
             raise NotImplementedError(f"encoding {ph.encoding}")
         n_rows += ph.num_values
-    if uses_dict and uses_plain:
-        raise NotImplementedError("mixed PLAIN + dictionary pages")
-    if phys == "BYTE_ARRAY" and not uses_dict:
+    if phys == "BYTE_ARRAY" and (uses_plain or not uses_dict):
         raise NotImplementedError("PLAIN byte-array pages")
 
     plan = ColumnChunkPlan(
         dtype=field.data_type, n_rows=n_rows, nullable=field.nullable,
         def_runs=def_runs, idx_runs=idx_runs if uses_dict else None,
         idx_bit_width=idx_bw, packed=bytes(packed),
-        plain_values=None, dict_values=None, dict_rank=None,
-        dict_offsets=None, dict_payload=None)
+        plain_values=None, dict_count=dict_count, dict_values=None,
+        dict_rank=None, dict_offsets=None, dict_payload=None)
 
     if uses_plain:
         raw = b"".join(plain_parts)
@@ -425,10 +436,13 @@ def _expand_hybrid(kinds, counts, values, bit_starts, widths, packed,
 
 def _decode_chunk_device(def_table, idx_table, packed, plain, dict_table,
                          n_rows, capacity, idx_bw, dtype,
-                         dict_string: bool):
+                         dict_string: bool, dict_count=None):
     """Traced device decode of one column chunk (see module doc). Each
     phase runs under a ``jax.named_scope`` (trace-time only), so XProf
-    and the HLO metadata tell them apart inside the one program."""
+    and the HLO metadata tell them apart inside the one program.
+    ``dict_count`` (a traced scalar, never a shape) comes with a chunk
+    that holds both sources: non-null slot ``s`` reads the dictionary
+    below it and ``plain[s - dict_count]`` from it on."""
     with jax.named_scope("def_levels"):
         live = jnp.arange(capacity, dtype=jnp.int32) < n_rows
         dk, dc, dv, db, dw = def_table
@@ -441,6 +455,18 @@ def _decode_chunk_device(def_table, idx_table, packed, plain, dict_table,
     if idx_table is not None:
         ik, ic, iv, ib, iw = idx_table
         raw_idx = _expand_hybrid(ik, ic, iv, ib, iw, packed, capacity)
+        if plain is not None:
+            with jax.named_scope("dict_or_plain"):
+                # one gather over [dictionary | PLAIN values]: a slot's
+                # source is told by its position among the non-null values
+                n_dict = dict_table.shape[0]
+                source = jnp.where(
+                    slot < dict_count,
+                    jnp.clip(raw_idx[slot], 0, n_dict - 1),
+                    n_dict + jnp.clip(slot - dict_count, 0, capacity - 1))
+                vals = jnp.concatenate([dict_table, plain])[source]
+                data = jnp.where(validity, vals, jnp.zeros((), vals.dtype))
+                return data, validity
         with jax.named_scope("dict_gather"):
             codes = jnp.where(validity, raw_idx[slot], 0)
             if dict_string:
@@ -504,19 +530,26 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int,
                               len(plan.idx_runs.kinds) if has_idx else 1,
                               1), 8)
 
+    # what the chunk's pages hold, read from them: the program's kind and
+    # the counter it is counted under
+    kind, counter = (
+        ("dictstr", "scanChunksDictionary") if dict_string
+        else ("dictplain", "scanChunksDictionaryThenPlain")
+        if has_idx and has_plain
+        else ("dict", "scanChunksDictionary") if has_idx
+        else ("plain", "scanChunksPlain"))
+
     def build():
-        def kern(dt, it, pk, pl, dtab, n):
+        def kern(dt, it, pk, pl, dtab, n, dict_count=None):
             return _decode_chunk_device(dt, it, pk, pl, dtab, n, capacity,
-                                        idx_bw, dtype, dict_string)
+                                        idx_bw, dtype, dict_string,
+                                        dict_count)
         return kern
     kern = cached_kernel(
         "parquet_decode",
         (dtype.name, capacity, idx_bw, has_idx, dict_string, has_plain,
          pad),
-        build,
-        suffix=f"{dtype.name}_bw{idx_bw}_"
-               + ("dictstr" if dict_string else "dict" if has_idx
-                  else "plain"))
+        build, suffix=f"{dtype.name}_bw{idx_bw}_{kind}")
 
     # Host staging (pad to the bucketed shapes), then every host->device
     # copy of the chunk in one timed stretch.
@@ -537,17 +570,24 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int,
         buf = np.zeros(capacity, plan.plain_values.dtype)
         buf[: len(plan.plain_values)] = plan.plain_values
         host["plain"] = buf
+    if kind == "dictplain":
+        # where the chunk fell back is data: an operand, in no shape or key
+        host["dict_count"] = np.asarray(plan.dict_count, np.int32)
     t0 = time.perf_counter_ns()
     dev = jax.tree_util.tree_map(jnp.asarray, host)
     t1 = time.perf_counter_ns()
-    data, validity = kern(dev["def"], dev["idx"], dev["packed"],
-                          dev.get("plain"), dev.get("dict"), dev["n_rows"])
+    operands = [dev["def"], dev["idx"], dev["packed"], dev.get("plain"),
+                dev.get("dict"), dev["n_rows"]]
+    if kind == "dictplain":
+        operands.append(dev["dict_count"])
+    data, validity = kern(*operands)
     t2 = time.perf_counter_ns()
     _count(counters, "scanUploadNs", t1 - t0)
     _count(counters, "uploadBytes",
            sum(a.nbytes for a in jax.tree_util.tree_leaves(host)))
     _count(counters, "scanLaunchNs", t2 - t1)
     _count(counters, "scanColumnChunksDecoded", 1)
+    _count(counters, counter, 1)
     if dict_string:
         max_bytes = 8
         if plan.dict_offsets is not None and len(plan.dict_offsets) > 1:
@@ -823,8 +863,9 @@ def device_decodable(path: str, schema: T.Schema, pf=None) -> bool:
                 return False
             encs = set(cm.encodings)
             # NOTE: "PLAIN" always appears (the dictionary page itself is
-            # PLAIN-encoded), so a byte-array chunk that actually fell back
-            # to PLAIN data pages is indistinguishable here — the
+            # PLAIN-encoded), so a chunk that actually fell back to PLAIN
+            # data pages is indistinguishable here. A fixed-width one
+            # decodes on the device either way; for a byte-array one the
             # authoritative gate is plan_column_chunk raising at scan time,
             # which the scan catches to fall back to the host path.
             if not encs <= {"PLAIN", "PLAIN_DICTIONARY", "RLE_DICTIONARY",

@@ -160,6 +160,18 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "groups (parquet), stripes (ORC), file slices (CSV) or record "
           "batches (host scan) x columns of the scan's schema, which is "
           "what the plan references (plan/optimizer.py)."),
+    _spec("scanChunksPlain", MetricKind.SUM, ESSENTIAL,
+          "Device parquet scan: column chunks decoded from PLAIN data "
+          "pages alone (io/parquet_device.py decode_chunk; with the two "
+          "below it adds up to the scan's scanColumnChunksDecoded)."),
+    _spec("scanChunksDictionary", MetricKind.SUM, ESSENTIAL,
+          "Device parquet scan: column chunks decoded from "
+          "dictionary-encoded data pages alone, fixed-width or string."),
+    _spec("scanChunksDictionaryThenPlain", MetricKind.SUM, ESSENTIAL,
+          "Device parquet scan: fixed-width column chunks whose writer "
+          "fell back mid-chunk (the dictionary page passed its size "
+          "limit, 1 MiB by default): dictionary-encoded pages, then PLAIN "
+          "pages, decoded by one parquet_decode_*_dictplain program."),
     _spec("planRuns", MetricKind.SUM, ESSENTIAL,
           "Runs of the plan behind one collect()/cache(): 1, plus "
           "join-capacity re-runs and dispatch retries (TpuSession node; "

@@ -335,6 +335,10 @@ def from_arrow_type(at) -> DataType:
     """Map a pyarrow DataType to ours (host interchange is Arrow throughout)."""
     import pyarrow as pa
 
+    if pa.types.is_dictionary(at):
+        # an encoding of the writer's table (a stored Arrow schema keeps
+        # it), not a type of the engine: the column reads as its values
+        return from_arrow_type(at.value_type)
     if pa.types.is_boolean(at):
         return BOOLEAN
     if pa.types.is_int8(at):

@@ -95,6 +95,26 @@ def test_fused_program_compiles_for_v5e(one_chip, monkeypatch, name):
     assert compiled.memory_analysis().temp_size_in_bytes >= 0
 
 
+def test_dictionary_then_plain_decode_compiles_for_v5e(one_chip):
+    """The decode program of a column chunk that falls back from its
+    dictionary to PLAIN pages, at the shapes of a default-written SF1
+    l_extendedprice chunk (1,048,576 rows, a 131 k-entry dictionary
+    bucketed to 262,144, 287 index runs, 294 KB packed)."""
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.io import parquet_device as PD
+    s = jax.ShapeDtypeStruct
+    runs = tuple(s((512,), jnp.int32) for _ in range(5))
+
+    def kern(dt, it, pk, pl, dtab, n, dict_count):
+        return PD._decode_chunk_device(dt, it, pk, pl, dtab, n, ROWS, 18,
+                                       T.DOUBLE, False, dict_count)
+    abstract = _placed((runs, runs, s((1 << 19,), jnp.uint8),
+                        s((ROWS,), jnp.float64), s((1 << 18,), jnp.float64),
+                        s((), jnp.int32), s((), jnp.int32)), one_chip)
+    compiled = jax.jit(kern).lower(*abstract).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes >= 0
+
+
 # -- the Pallas families, compiled (not interpreted) --------------------------
 
 def _pallas_cases():

@@ -325,3 +325,219 @@ def test_two_projections_of_one_file_hash_apart(tmp_path):
     plan_a, scan_a = hashes("c01")
     plan_b, scan_b = hashes("c05")
     assert plan_a != plan_b and scan_a != scan_b
+
+
+# -- chunks that fall back from their dictionary to PLAIN pages -------------
+
+_FALLBACK_ROWS = 6000      # a row group
+_FALLBACK_GROUPS = 3
+
+
+def _fallback_column(dtype, nulls, every_group, seed=0):
+    """Three row groups of a fixed-width column. Distinct values overflow
+    a 4 KiB dictionary page in the first row group (``every_group``: in
+    all three); the others hold 50 values and stay on their dictionary."""
+    rng = np.random.default_rng(seed)
+    n = _FALLBACK_ROWS * _FALLBACK_GROUPS
+    many = rng.permutation(n * 4)[:n]
+    few = rng.integers(0, 50, n)
+    values = many if every_group else np.where(
+        np.arange(n) < _FALLBACK_ROWS, many, few)
+    if dtype == "float64":
+        arr = pa.array(values / 100.0, pa.float64())
+    elif dtype == "date32":
+        arr = pa.array(values.astype(np.int32), pa.date32())
+    else:
+        arr = pa.array(values, getattr(pa, dtype)())
+    if nulls:
+        arr = pa.array(arr.to_pylist(), arr.type,
+                       mask=rng.integers(0, 5, n) == 0)
+    return arr
+
+
+def _scan_all(path, keep):
+    """(answer, profile totals) of every row through the session's device
+    scan (a filter that keeps all: a bare scan stays on the host), under
+    ``test.enabled``: a row group the device refused would raise."""
+    s = tpu_session()
+    got = s.read.parquet(path).where(keep).collect()
+    return got, s.last_query_profile().totals()
+
+
+def _assert_decoded_as_pyarrow_reads(path, got, totals, fell_back):
+    want = pq.read_table(path)
+    for name in want.column_names:
+        assert got.column(name).combine_chunks().equals(
+            want.column(name).combine_chunks()), name
+    assert totals.get("hostFallbackRowGroups", 0) == 0
+    assert totals["scanChunksDictionaryThenPlain"] == fell_back
+    assert sum(totals.get(k, 0) for k in (
+        "scanChunksPlain", "scanChunksDictionary",
+        "scanChunksDictionaryThenPlain")) == totals["scanColumnChunksDecoded"]
+
+
+@pytest.mark.parametrize("every_group", [False, True],
+                         ids=["first_group", "every_group"])
+@pytest.mark.parametrize("nulls", [False, True], ids=["no_nulls", "nulls"])
+@pytest.mark.parametrize("dtype", ["int32", "int64", "float64", "date32"])
+def test_dictionary_then_plain_chunk_decodes_on_device(tmp_path, dtype,
+                                                       nulls, every_group):
+    """What parquet-mr and parquet-cpp write once a chunk's dictionary
+    page passes its limit: dictionary-encoded pages, then PLAIN pages.
+    Bit for bit what pyarrow's own reader gives, every row group on the
+    device, each chunk counted by what its pages hold."""
+    tbl = pa.table({"v": _fallback_column(dtype, nulls, every_group),
+                    "few": pa.array(np.arange(
+                        _FALLBACK_ROWS * _FALLBACK_GROUPS) % 7, pa.int64())})
+    path = str(tmp_path / "fallback.parquet")
+    pq.write_table(tbl, path, row_group_size=_FALLBACK_ROWS,
+                   dictionary_pagesize_limit=4096, data_page_size=2048,
+                   write_batch_size=256)
+    # the file is what the case says: the dictionary, then PLAIN pages
+    md = pq.ParquetFile(path).metadata
+    assert md.num_row_groups == _FALLBACK_GROUPS
+    with open(path, "rb") as f:
+        plans = [PD.plan_column_chunk(
+            f, md.row_group(rg).column(0),
+            T.schema_from_arrow(tbl.schema).fields[0], 1)
+            for rg in range(md.num_row_groups)]
+    mixed = [p.idx_runs is not None and p.plain_values is not None
+             for p in plans]
+    assert mixed == [True, every_group, every_group]
+    assert all(0 < p.dict_count < p.n_rows for p, m in zip(plans, mixed)
+               if m)
+    got, totals = _scan_all(path, col("few") >= 0)
+    _assert_decoded_as_pyarrow_reads(path, got, totals, sum(mixed))
+    assert totals["deviceDecodedRowGroups"] == _FALLBACK_GROUPS
+
+
+def test_default_written_file_crosses_the_real_dictionary_limit(tmp_path):
+    """``pq.write_table(t, path)`` with no option: 300,000 distinct doubles
+    pass the writer's own 1 MiB dictionary page (131,072 values)."""
+    rng = np.random.default_rng(3)
+    n = 300_000
+    tbl = pa.table({
+        "price": pa.array(rng.permutation(n * 8)[:n] / 100.0, pa.float64()),
+        "qty": pa.array(rng.integers(1, 51, n).astype(np.float64)),
+        "day": pa.array(rng.integers(8035, 10591, n).astype(np.int32),
+                        pa.date32())})
+    path = str(tmp_path / "default.parquet")
+    pq.write_table(tbl, path)
+    got, totals = _scan_all(path, col("qty") > 0)
+    _assert_decoded_as_pyarrow_reads(path, got, totals, 1)
+    assert totals["scanChunksDictionary"] == 2
+    from spark_rapids_tpu.utils import kernel_cache
+    assert "parquet_decode_double_bw18_dictplain" in {
+        fn.__name__ for fn in kernel_cache._CACHE.values()}
+
+
+def test_dictionary_page_after_plain_pages_is_refused(tmp_path,
+                                                      monkeypatch):
+    """The writers' fallback is one-way; a chunk in any other order is
+    outside the decoder (a slot's source is told by its position)."""
+    tbl = pa.table({"v": _fallback_column("int64", False, True)})
+    path = str(tmp_path / "fallback.parquet")
+    pq.write_table(tbl, path, dictionary_pagesize_limit=4096,
+                   data_page_size=2048, write_batch_size=256)
+    real = PD._parse_page_header
+    seen = []
+
+    def reversed_encodings(buf, pos):
+        ph = real(buf, pos)
+        if ph.page_type == 0:       # PLAIN first, then the dictionary's
+            seen.append(ph.encoding)
+            ph.encoding = PD.PLAIN if len(seen) == 1 else PD.RLE_DICTIONARY
+        return ph
+    monkeypatch.setattr(PD, "_parse_page_header", reversed_encodings)
+    with pytest.raises(NotImplementedError,
+                       match="dictionary pages after PLAIN"):
+        PD.decode_row_group(path, 0, T.schema_from_arrow(tbl.schema))
+
+
+def test_pure_chunks_keep_their_programs(tmp_path, monkeypatch):
+    """A pure PLAIN and a pure dictionary chunk ask ``cached_kernel`` for
+    the key and the name they asked for before chunks could fall back
+    (PERF.md section 5 and the ledger's ``breakdown`` hold the names): the
+    accepted cells' compile caches cannot move unseen. A chunk that falls
+    back has a key, a name and one operand more of its own."""
+    from spark_rapids_tpu.utils.kernel_cache import program_name
+    asked = []
+    real = PD.cached_kernel
+
+    def spy(kind, key, builder, static_argnums=None, suffix=""):
+        fn = real(kind, key, builder, static_argnums, suffix)
+
+        def call(*operands):
+            asked.append((program_name(kind, suffix), key, len(operands)))
+            return fn(*operands)
+        return call
+    monkeypatch.setattr(PD, "cached_kernel", spy)
+    n = 5000
+    rng = np.random.default_rng(1)
+    tbl = pa.table({
+        "x": pa.array(rng.integers(0, 10 ** 9, n) / 100.0, pa.float64()),
+        "d": pa.array(rng.integers(0, 2500, n).astype(np.int32),
+                      pa.date32()),
+        "k": pa.array(rng.integers(0, 10 ** 9, n), pa.int64()),
+        "s": pa.array([f"s{i % 13}" for i in range(n)])})
+    schema = T.schema_from_arrow(tbl.schema)
+    path = str(tmp_path / "t.parquet")
+    pq.write_table(tbl, path, use_dictionary=["s"])
+    PD.decode_row_group(path, 0, schema)
+    pq.write_table(tbl, path)
+    PD.decode_row_group(path, 0, schema)
+    pq.write_table(tbl.select(["x"]), path, dictionary_pagesize_limit=4096)
+    PD.decode_row_group(path, 0, T.Schema(schema.fields[:1]))
+    assert asked == [
+        ("parquet_decode_double_bw0_plain",
+         ("double", 8192, 0, False, False, True, 128), 6),
+        ("parquet_decode_date_bw0_plain",
+         ("date", 8192, 0, False, False, True, 128), 6),
+        ("parquet_decode_bigint_bw0_plain",
+         ("bigint", 8192, 0, False, False, True, 128), 6),
+        ("parquet_decode_string_bw4_dictstr",
+         ("string", 8192, 4, True, True, False, 128), 6),
+        ("parquet_decode_double_bw13_dict",
+         ("double", 8192, 13, True, False, False, 128), 6),
+        ("parquet_decode_date_bw12_dict",
+         ("date", 8192, 12, True, False, False, 128), 6),
+        ("parquet_decode_bigint_bw13_dict",
+         ("bigint", 8192, 13, True, False, False, 128), 6),
+        ("parquet_decode_string_bw4_dictstr",
+         ("string", 8192, 4, True, True, False, 128), 6),
+        ("parquet_decode_double_bw10_dictplain",
+         ("double", 8192, 10, True, False, True, 128), 7),
+    ]
+
+
+def test_dictionary_typed_arrow_field_reads_as_its_values(tmp_path):
+    """A table written from dictionary-typed columns with the writer's
+    defaults stores its Arrow schema: ``dictionary<values=V>`` opens as
+    ``V``, on the device scan and on the host scan."""
+    n = 2000
+    words = pa.array(["AIR", "MAIL", "RAIL", "SHIP", "TRUCK"])
+    tbl = pa.table({
+        "mode": pa.DictionaryArray.from_arrays(
+            pa.array(np.arange(n) % 5, pa.int32()), words),
+        "bucket": pa.DictionaryArray.from_arrays(
+            pa.array(np.arange(n) % 3, pa.int8()),
+            pa.array([10, 20, 30], pa.int64())),
+        "v": pa.array(np.arange(n), pa.int64())})
+    path = str(tmp_path / "dict_typed.parquet")
+    pq.write_table(tbl, path)
+    assert pa.types.is_dictionary(pq.read_schema(path).field("mode").type)
+    assert T.from_arrow_type(tbl.schema.field("mode").type) == T.STRING
+    assert T.from_arrow_type(tbl.schema.field("bucket").type) == T.LONG
+    want = tbl.cast(pa.schema([("mode", pa.string()), ("bucket", pa.int64()),
+                               ("v", pa.int64())]))
+    s = tpu_session()
+    df = s.read.parquet(path)
+    assert [f.data_type for f in df.schema] == [T.STRING, T.LONG, T.LONG]
+    got = df.where(col("v") >= 0).collect()
+    assert s.last_query_profile().totals()["deviceDecodedRowGroups"] == 1
+    host = cpu_session().read.parquet(path).collect()
+    for name in want.column_names:
+        assert got.column(name).to_pylist() == \
+            want.column(name).to_pylist(), name
+        assert host.column(name).to_pylist() == \
+            want.column(name).to_pylist(), name
