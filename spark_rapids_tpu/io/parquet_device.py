@@ -15,7 +15,10 @@ TPU-native split here:
   broadcast their value, bit-packed runs gather+shift+mask straight from
   the uploaded page bytes — then definition levels become the validity
   mask and dictionary indices scatter into row order. Everything is
-  vectorized; no per-value host loop anywhere.
+  vectorized; no per-value host loop anywhere. A chunk in which no page
+  holds a null (the host sees that in the pages) skips the definition
+  levels, the row -> slot prefix sum and the slot gather: row i is value
+  i, and a PLAIN chunk is its uploaded buffer.
 
 Parquet dictionaries pair perfectly with this engine's dict-encoded string
 columns: the page dictionary IS the column dictionary. The host sorts the
@@ -239,6 +242,10 @@ class ColumnChunkPlan:
     dtype: T.DataType
     n_rows: int
     nullable: bool
+    # whether any page held a null, read from the pages' definition levels
+    # (never for a REQUIRED column): a chunk without one decodes without
+    # the definition-level table and without slots
+    has_nulls: bool
     # definition-level hybrid (bw=1): validity
     def_runs: Optional[_HybridRuns]
     # value source: dictionary indices (hybrid) + dictionary, or PLAIN
@@ -296,6 +303,7 @@ def plan_column_chunk(f, col_md, field: T.StructField,
     idx_bw = 0
     n_rows = 0
     dict_count = 0
+    has_nulls = False
     uses_dict = False
     uses_plain = False
     while pos < len(chunk):
@@ -327,6 +335,7 @@ def plan_column_chunk(f, col_md, field: T.StructField,
             def_runs.bit_starts.append(0)
             def_runs.widths.append(1)
             non_null = ph.num_values
+        has_nulls = has_nulls or non_null < ph.num_values
         if ph.encoding in (PLAIN_DICTIONARY, RLE_DICTIONARY):
             if uses_plain:
                 # the writers' fallback is one-way: a slot's source is
@@ -358,7 +367,8 @@ def plan_column_chunk(f, col_md, field: T.StructField,
 
     plan = ColumnChunkPlan(
         dtype=field.data_type, n_rows=n_rows, nullable=field.nullable,
-        def_runs=def_runs, idx_runs=idx_runs if uses_dict else None,
+        has_nulls=has_nulls, def_runs=def_runs,
+        idx_runs=idx_runs if uses_dict else None,
         idx_bit_width=idx_bw, packed=bytes(packed),
         plain_values=None, dict_count=dict_count, dict_values=None,
         dict_rank=None, dict_offsets=None, dict_payload=None)
@@ -515,29 +525,54 @@ def _count(counters: Optional[dict], name: str, value: int) -> None:
         counters[name] = counters.get(name, 0) + value
 
 
-def decode_chunk(plan: ColumnChunkPlan, capacity: int,
-                 counters: Optional[dict] = None) -> DeviceColumn:
-    """Upload one chunk's page bytes + run tables and decode on device.
-    ``counters`` (the scan's, one dict per row group) takes the upload's
-    and the launch's host nanoseconds, the bytes uploaded and the chunk
-    itself: three clock reads a chunk, nothing per row."""
-    import time
+def _live_rows(n_rows, capacity):
+    """(row numbers, validity) of a chunk without nulls: all there is to
+    compute for a PLAIN one, whose uploaded, zero-padded buffer is the
+    column."""
+    with jax.named_scope("live_rows"):
+        row = jnp.arange(capacity, dtype=jnp.int32)
+        return row, row < n_rows
+
+
+def _decode_chunk_no_nulls(idx_table, packed, plain, dict_table, n_rows,
+                           capacity, dict_count=None):
+    """Traced device decode of a dictionary-encoded chunk whose pages hold
+    no null: row ``i`` is non-null value ``i``, so there is no
+    definition-level table, no prefix sum and no gather through slots. A
+    dictionary-string chunk gathers from its rank table as a number does
+    from its dictionary; ``plain`` and ``dict_count`` come with a chunk
+    that fell back."""
+    row, validity = _live_rows(n_rows, capacity)
+    ik, ic, iv, ib, iw = idx_table
+    idx = _expand_hybrid(ik, ic, iv, ib, iw, packed, capacity)
+    n_dict = dict_table.shape[0]
+    if plain is not None:
+        with jax.named_scope("dict_or_plain"):
+            source = jnp.where(
+                row < dict_count,
+                jnp.clip(idx, 0, n_dict - 1),
+                n_dict + jnp.clip(row - dict_count, 0, capacity - 1))
+            vals = jnp.concatenate([dict_table, plain])[source]
+    else:
+        with jax.named_scope("dict_gather"):
+            vals = dict_table[jnp.clip(idx, 0, n_dict - 1)]
+    return jnp.where(validity, vals, jnp.zeros((), vals.dtype)), validity
+
+
+def _run_table_bucket(*tables: Optional[_HybridRuns]) -> int:
+    return bucket_byte_capacity(
+        max([len(t.kinds) for t in tables if t is not None] + [1]), 8)
+
+
+def _nullable_program(plan: ColumnChunkPlan, capacity: int, kind: str):
+    """(program, host operands, their order) of a chunk with nulls: the
+    definition levels expand to the validity, its prefix sum gives each
+    row its slot among the non-null values."""
     idx_bw, dtype = plan.idx_bit_width, plan.dtype
     dict_string = plan.dict_rank is not None
     has_idx = plan.idx_runs is not None
     has_plain = plan.plain_values is not None
-    pad = bucket_byte_capacity(max(len(plan.def_runs.kinds),
-                              len(plan.idx_runs.kinds) if has_idx else 1,
-                              1), 8)
-
-    # what the chunk's pages hold, read from them: the program's kind and
-    # the counter it is counted under
-    kind, counter = (
-        ("dictstr", "scanChunksDictionary") if dict_string
-        else ("dictplain", "scanChunksDictionaryThenPlain")
-        if has_idx and has_plain
-        else ("dict", "scanChunksDictionary") if has_idx
-        else ("plain", "scanChunksPlain"))
+    pad = _run_table_bucket(plan.def_runs, plan.idx_runs)
 
     def build():
         def kern(dt, it, pk, pl, dtab, n, dict_count=None):
@@ -550,13 +585,70 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int,
         (dtype.name, capacity, idx_bw, has_idx, dict_string, has_plain,
          pad),
         build, suffix=f"{dtype.name}_bw{idx_bw}_{kind}")
+    host = {"def": _runs_arrays(plan.def_runs, pad),
+            "idx": _runs_arrays(plan.idx_runs, pad) if has_idx else None,
+            "packed": _pad_packed(plan.packed)}
+    return kern, host, (["def", "idx", "packed", "plain", "dict", "n_rows"]
+                        + ["dict_count"] * (has_idx and has_plain))
+
+
+def _no_nulls_program(plan: ColumnChunkPlan, capacity: int, kind: str):
+    """(program, host operands, their order) of a chunk without nulls,
+    keyed by what :func:`_decode_chunk_no_nulls` reads: the definition
+    levels are neither staged nor uploaded, the run-table bucket is the
+    index stream's alone, and the index bit width (per run, in the table)
+    is in no key — one program serves every width."""
+    if plan.idx_runs is None:
+        def build():
+            return lambda n: _live_rows(n, capacity)[1]
+        kern = cached_kernel("parquet_decode", ("plain_nn", capacity),
+                             build, suffix="plain_nn")
+        return kern, {}, ["n_rows"]
+    has_plain = plan.plain_values is not None
+    pad = _run_table_bucket(plan.idx_runs)
+
+    def build():
+        if has_plain:
+            return lambda it, pk, pl, dtab, n, dict_count: \
+                _decode_chunk_no_nulls(it, pk, pl, dtab, n, capacity,
+                                       dict_count)
+        return lambda it, pk, dtab, n: _decode_chunk_no_nulls(
+            it, pk, None, dtab, n, capacity)
+    kern = cached_kernel(
+        "parquet_decode", (plan.dtype.name, capacity, f"{kind}_nn", pad),
+        build, suffix=f"{plan.dtype.name}_{kind}_nn")
+    host = {"idx": _runs_arrays(plan.idx_runs, pad),
+            "packed": _pad_packed(plan.packed)}
+    return kern, host, ["idx", "packed"] + (
+        ["plain", "dict", "n_rows", "dict_count"] if has_plain
+        else ["dict", "n_rows"])
+
+
+def decode_chunk(plan: ColumnChunkPlan, capacity: int,
+                 counters: Optional[dict] = None) -> DeviceColumn:
+    """Upload one chunk's page bytes + run tables and decode on device.
+    ``counters`` (the scan's, one dict per row group) takes the upload's
+    and the launch's host nanoseconds, the bytes uploaded and the chunk
+    itself: three clock reads a chunk, nothing per row."""
+    import time
+    dict_string = plan.dict_rank is not None
+    has_idx = plan.idx_runs is not None
+    has_plain = plan.plain_values is not None
+
+    # what the chunk's pages hold, read from them: the program's kind and
+    # the counter it is counted under
+    kind, counter = (
+        ("dictstr", "scanChunksDictionary") if dict_string
+        else ("dictplain", "scanChunksDictionaryThenPlain")
+        if has_idx and has_plain
+        else ("dict", "scanChunksDictionary") if has_idx
+        else ("plain", "scanChunksPlain"))
+    kern, host, order = (_nullable_program if plan.has_nulls
+                         else _no_nulls_program)(plan, capacity, kind)
 
     # Host staging (pad to the bucketed shapes), then every host->device
     # copy of the chunk in one timed stretch.
-    host = {"def": _runs_arrays(plan.def_runs, pad),
-            "idx": _runs_arrays(plan.idx_runs, pad) if has_idx else None,
-            "packed": _pad_packed(plan.packed),
-            "n_rows": np.asarray(plan.n_rows, np.int32)}
+    host["n_rows"] = np.asarray(plan.n_rows, np.int32)
     if dict_string:
         host["dict"] = _bucketed(plan.dict_rank, np.int32)
         byte_cap = bucket_byte_capacity(max(int(plan.dict_offsets[-1]), 1))
@@ -576,11 +668,10 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int,
     t0 = time.perf_counter_ns()
     dev = jax.tree_util.tree_map(jnp.asarray, host)
     t1 = time.perf_counter_ns()
-    operands = [dev["def"], dev["idx"], dev["packed"], dev.get("plain"),
-                dev.get("dict"), dev["n_rows"]]
-    if kind == "dictplain":
-        operands.append(dev["dict_count"])
-    data, validity = kern(*operands)
+    out = kern(*[dev.get(name) for name in order])
+    # a PLAIN chunk without nulls is the uploaded buffer as it is
+    data, validity = out if has_idx or plan.has_nulls \
+        else (dev["plain"], out)
     t2 = time.perf_counter_ns()
     _count(counters, "scanUploadNs", t1 - t0)
     _count(counters, "uploadBytes",
@@ -588,6 +679,7 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int,
     _count(counters, "scanLaunchNs", t2 - t1)
     _count(counters, "scanColumnChunksDecoded", 1)
     _count(counters, counter, 1)
+    _count(counters, "scanChunksNoNulls", int(not plan.has_nulls))
     if dict_string:
         max_bytes = 8
         if plan.dict_offsets is not None and len(plan.dict_offsets) > 1:

@@ -171,7 +171,16 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "Device parquet scan: fixed-width column chunks whose writer "
           "fell back mid-chunk (the dictionary page passed its size "
           "limit, 1 MiB by default): dictionary-encoded pages, then PLAIN "
-          "pages, decoded by one parquet_decode_*_dictplain program."),
+          "pages, decoded by one parquet_decode_*_dictplain[_nn] program."),
+    _spec("scanChunksNoNulls", MetricKind.SUM, ESSENTIAL,
+          "Device parquet scan: column chunks, of whichever kind "
+          "(scanChunksPlain, scanChunksDictionary and "
+          "scanChunksDictionaryThenPlain count them too), in which no "
+          "page held a null: read from the pages' definition levels, "
+          "always so for a REQUIRED column. They decode without the "
+          "definition-level table, the prefix sum and the gather through "
+          "slots (parquet_decode_*_nn programs); a chunk with a null "
+          "takes the nullable program of its kind."),
     _spec("planRuns", MetricKind.SUM, ESSENTIAL,
           "Runs of the plan behind one collect()/cache(): 1, plus "
           "join-capacity re-runs and dispatch retries (TpuSession node; "

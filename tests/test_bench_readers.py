@@ -23,6 +23,7 @@ READERS = {
     "scan_upload_s_per_query": ("scanUploadNs", "counters"),
     "scan_launch_s_per_query": ("scanLaunchNs", "counters"),
     "cold_programs_in_setup": ("persistentCacheMisses", "setup_counters"),
+    "scan_nonnull_chunks_per_query": ("scanChunksNoNulls", "counters"),
 }
 
 
@@ -95,6 +96,9 @@ def test_readings_at_a_ninetieth(runs):
     # the projection reaches the scan: of lineitem's 16 columns the 4 that
     # Q6 references are decoded, and no other
     assert _reader("scan_decoded_per_referenced")(q6) == 1.0
+    # TPC-H holds no null: each of the 4 chunks a query (one row group at
+    # this scale) decodes without the null machinery
+    assert _reader("scan_nonnull_chunks_per_query")(q6) == 4.0
     assert q1["counters"].get("scanColumnChunksDecoded") is None
     spent = sum(_reader(f"scan_{part}_s_per_query")(q6)
                 for part in ("parse", "upload", "launch"))

@@ -115,6 +115,36 @@ def test_dictionary_then_plain_decode_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes >= 0
 
 
+@pytest.mark.parametrize("kind", ["plain_nn", "dict_nn", "dictplain_nn"])
+def test_decode_without_nulls_compiles_for_v5e(one_chip, kind):
+    """The decode programs of a chunk in which no page holds a null, at
+    the shapes of a default-written SF1 lineitem chunk: the validity of a
+    PLAIN chunk; l_shipdate-like (2,098 index runs bucketed to 4,096, a
+    small dictionary); l_extendedprice-like (as the case above, without
+    its definition-level table)."""
+    from spark_rapids_tpu.io import parquet_device as PD
+    s = jax.ShapeDtypeStruct
+    n = s((), jnp.int32)
+    if kind == "plain_nn":
+        def kern(n):
+            return PD._live_rows(n, ROWS)[1]
+        abstract = (n,)
+    elif kind == "dict_nn":
+        def kern(it, pk, dtab, n):
+            return PD._decode_chunk_no_nulls(it, pk, None, dtab, n, ROWS)
+        abstract = (tuple(s((4096,), jnp.int32) for _ in range(5)),
+                    s((1 << 21,), jnp.uint8), s((4096,), jnp.int32), n)
+    else:
+        def kern(it, pk, pl, dtab, n, dict_count):
+            return PD._decode_chunk_no_nulls(it, pk, pl, dtab, n, ROWS,
+                                             dict_count)
+        abstract = (tuple(s((512,), jnp.int32) for _ in range(5)),
+                    s((1 << 19,), jnp.uint8), s((ROWS,), jnp.float64),
+                    s((1 << 18,), jnp.float64), n, n)
+    compiled = jax.jit(kern).lower(*_placed(abstract, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes >= 0
+
+
 # -- the Pallas families, compiled (not interpreted) --------------------------
 
 def _pallas_cases():

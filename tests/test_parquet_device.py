@@ -427,7 +427,7 @@ def test_default_written_file_crosses_the_real_dictionary_limit(tmp_path):
     _assert_decoded_as_pyarrow_reads(path, got, totals, 1)
     assert totals["scanChunksDictionary"] == 2
     from spark_rapids_tpu.utils import kernel_cache
-    assert "parquet_decode_double_bw18_dictplain" in {
+    assert "parquet_decode_double_dictplain_nn" in {
         fn.__name__ for fn in kernel_cache._CACHE.values()}
 
 
@@ -454,12 +454,11 @@ def test_dictionary_page_after_plain_pages_is_refused(tmp_path,
         PD.decode_row_group(path, 0, T.schema_from_arrow(tbl.schema))
 
 
-def test_pure_chunks_keep_their_programs(tmp_path, monkeypatch):
-    """A pure PLAIN and a pure dictionary chunk ask ``cached_kernel`` for
-    the key and the name they asked for before chunks could fall back
-    (PERF.md section 5 and the ledger's ``breakdown`` hold the names): the
-    accepted cells' compile caches cannot move unseen. A chunk that falls
-    back has a key, a name and one operand more of its own."""
+def _spy_on_programs(monkeypatch, with_hlo=False):
+    """Every decode program ``decode_chunk`` calls from here on: (name,
+    cache key, operands, [first 16 hex of the sha256 of its lowered
+    text]), in call order."""
+    import hashlib
     from spark_rapids_tpu.utils.kernel_cache import program_name
     asked = []
     real = PD.cached_kernel
@@ -468,46 +467,256 @@ def test_pure_chunks_keep_their_programs(tmp_path, monkeypatch):
         fn = real(kind, key, builder, static_argnums, suffix)
 
         def call(*operands):
-            asked.append((program_name(kind, suffix), key, len(operands)))
+            entry = (program_name(kind, suffix), key, len(operands))
+            if with_hlo:
+                text = fn.lower(*operands).as_text()
+                entry += (hashlib.sha256(text.encode()).hexdigest()[:16],)
+            asked.append(entry)
             return fn(*operands)
         return call
     monkeypatch.setattr(PD, "cached_kernel", spy)
-    n = 5000
+    return asked
+
+
+def _four_columns(n, holes=None):
     rng = np.random.default_rng(1)
-    tbl = pa.table({
-        "x": pa.array(rng.integers(0, 10 ** 9, n) / 100.0, pa.float64()),
+    return pa.table({
+        "x": pa.array(rng.integers(0, 10 ** 9, n) / 100.0, pa.float64(),
+                      mask=holes),
         "d": pa.array(rng.integers(0, 2500, n).astype(np.int32),
-                      pa.date32()),
-        "k": pa.array(rng.integers(0, 10 ** 9, n), pa.int64()),
-        "s": pa.array([f"s{i % 13}" for i in range(n)])})
+                      pa.date32(), mask=holes),
+        "k": pa.array(rng.integers(0, 10 ** 9, n), pa.int64(), mask=holes),
+        "s": pa.array([f"s{i % 13}" for i in range(n)], pa.string(),
+                      mask=holes)})
+
+
+def _decode_three_ways(tbl, path):
+    """The table written PLAIN (strings on their dictionary), by the
+    writer's defaults, and its first column with a 4 KiB dictionary page
+    that overflows: each read back through the device decoder."""
     schema = T.schema_from_arrow(tbl.schema)
-    path = str(tmp_path / "t.parquet")
     pq.write_table(tbl, path, use_dictionary=["s"])
     PD.decode_row_group(path, 0, schema)
     pq.write_table(tbl, path)
     PD.decode_row_group(path, 0, schema)
     pq.write_table(tbl.select(["x"]), path, dictionary_pagesize_limit=4096)
     PD.decode_row_group(path, 0, T.Schema(schema.fields[:1]))
+
+
+def test_pure_chunks_keep_their_programs(tmp_path, monkeypatch):
+    """A chunk WITH nulls asks ``cached_kernel`` for the key, the name and
+    the operands it asked for before chunks without nulls got programs of
+    their own, and lowers to the same text (the digests are of the commit
+    before that change, from this table, on this backend; a JAX upgrade
+    that moves them moves all of them: re-pin from a checkout that still
+    passes): a file with nulls compiles and runs what it ran."""
+    n = 5000
+    holes = np.zeros(n, bool)
+    holes[n - 1] = True         # one null a column, in its last page
+    asked = _spy_on_programs(monkeypatch, with_hlo=True)
+    _decode_three_ways(_four_columns(n, holes), str(tmp_path / "t.parquet"))
     assert asked == [
         ("parquet_decode_double_bw0_plain",
-         ("double", 8192, 0, False, False, True, 128), 6),
+         ("double", 8192, 0, False, False, True, 128), 6,
+         "f05d49efbe52a95e"),
         ("parquet_decode_date_bw0_plain",
-         ("date", 8192, 0, False, False, True, 128), 6),
+         ("date", 8192, 0, False, False, True, 128), 6,
+         "c2d7779a6efc2662"),
         ("parquet_decode_bigint_bw0_plain",
-         ("bigint", 8192, 0, False, False, True, 128), 6),
+         ("bigint", 8192, 0, False, False, True, 128), 6,
+         "2964ad0b865f31d4"),
         ("parquet_decode_string_bw4_dictstr",
-         ("string", 8192, 4, True, True, False, 128), 6),
+         ("string", 8192, 4, True, True, False, 128), 6,
+         "35219163f6826281"),
         ("parquet_decode_double_bw13_dict",
-         ("double", 8192, 13, True, False, False, 128), 6),
+         ("double", 8192, 13, True, False, False, 128), 6,
+         "da0c047f25bc16ca"),
         ("parquet_decode_date_bw12_dict",
-         ("date", 8192, 12, True, False, False, 128), 6),
+         ("date", 8192, 12, True, False, False, 128), 6,
+         "22bd95769912ce1d"),
         ("parquet_decode_bigint_bw13_dict",
-         ("bigint", 8192, 13, True, False, False, 128), 6),
+         ("bigint", 8192, 13, True, False, False, 128), 6,
+         "bad169d070ea0787"),
         ("parquet_decode_string_bw4_dictstr",
-         ("string", 8192, 4, True, True, False, 128), 6),
+         ("string", 8192, 4, True, True, False, 128), 6,
+         "35219163f6826281"),
         ("parquet_decode_double_bw10_dictplain",
-         ("double", 8192, 10, True, False, True, 128), 7),
+         ("double", 8192, 10, True, False, True, 128), 7,
+         "cb1c44bfdc36d15d"),
     ]
+
+
+def test_chunks_without_nulls_have_programs_of_their_own(tmp_path,
+                                                         monkeypatch):
+    """The same table without a null: names of their own, keyed by what
+    the kernel reads (no index bit width), no definition-level table
+    among the operands; a PLAIN chunk launches the validity alone."""
+    asked = _spy_on_programs(monkeypatch)
+    _decode_three_ways(_four_columns(5000), str(tmp_path / "t.parquet"))
+    assert asked == [
+        ("parquet_decode_plain_nn", ("plain_nn", 8192), 1),
+        ("parquet_decode_plain_nn", ("plain_nn", 8192), 1),
+        ("parquet_decode_plain_nn", ("plain_nn", 8192), 1),
+        ("parquet_decode_string_dictstr_nn",
+         ("string", 8192, "dictstr_nn", 128), 4),
+        ("parquet_decode_double_dict_nn",
+         ("double", 8192, "dict_nn", 128), 4),
+        ("parquet_decode_date_dict_nn", ("date", 8192, "dict_nn", 128), 4),
+        ("parquet_decode_bigint_dict_nn",
+         ("bigint", 8192, "dict_nn", 128), 4),
+        ("parquet_decode_string_dictstr_nn",
+         ("string", 8192, "dictstr_nn", 128), 4),
+        ("parquet_decode_double_dictplain_nn",
+         ("double", 8192, "dictplain_nn", 128), 6),
+    ]
+
+
+def test_index_bit_widths_share_one_program(tmp_path, monkeypatch):
+    """l_quantity (6 bits), l_discount and l_tax (4 bits) of a
+    default-written lineitem: the width is per run, in the run table, and
+    in no key — one ``double`` dictionary program for all three."""
+    n = 3000
+    rng = np.random.default_rng(2)
+    tbl = pa.table({
+        "quantity": pa.array(rng.integers(1, 51, n).astype(np.float64)),
+        "discount": pa.array(rng.integers(0, 11, n) / 100.0),
+        "tax": pa.array(rng.integers(0, 9, n) / 100.0)})
+    path = str(tmp_path / "t.parquet")
+    pq.write_table(tbl, path)
+    schema = T.schema_from_arrow(tbl.schema)
+    md = pq.ParquetFile(path).metadata.row_group(0)
+    with open(path, "rb") as f:
+        widths = [PD.plan_column_chunk(f, md.column(i), schema.fields[i],
+                                       1).idx_bit_width for i in range(3)]
+    assert widths == [6, 4, 4]
+    asked = _spy_on_programs(monkeypatch)
+    out = PD.decode_row_group(path, 0, schema).to_arrow()
+    assert pa.Table.from_batches([out]).equals(tbl)
+    assert asked == [("parquet_decode_double_dict_nn",
+                      ("double", 4096, "dict_nn", 128), 4)] * 3
+    from spark_rapids_tpu.utils import kernel_cache
+    assert sum(key == ("parquet_decode", asked[0][1])
+               for key in kernel_cache._CACHE) == 1
+
+
+# -- kind x type x definition levels ----------------------------------------
+
+_NN_ROWS = 6000
+_ARROW = {"int32": ("int", pa.int32()), "int64": ("bigint", pa.int64()),
+          "float32": ("float", pa.float32()),
+          "float64": ("double", pa.float64()),
+          "date32": ("date", pa.date32()), "string": ("string", pa.string())}
+_LEVELS = ("required", "optional_no_null", "one_null_in_last_page",
+           "all_null")
+_KIND_CASES = [
+    (kind, dtype, levels)
+    for kind, dtypes in (
+        ("plain", ("int32", "int64", "float32", "float64", "date32")),
+        ("dict", ("int32", "int64", "float32", "float64", "date32")),
+        ("dictstr", ("string",)),
+        ("dictplain", ("int32", "int64", "float32", "float64", "date32")))
+    for dtype in dtypes for levels in _LEVELS
+    # a chunk of nulls alone holds no value to fall back over
+    if not (kind == "dictplain" and levels == "all_null")]
+
+
+def _kind_column(kind, dtype, levels):
+    """6,000 rows of one column whose chunk the writer options of
+    ``_write_kind`` turn into ``kind``: 50 distinct values stay on their
+    dictionary, 6,000 distinct ones overflow a 4 KiB dictionary page."""
+    rng = np.random.default_rng(7)
+    n = _NN_ROWS
+    values = rng.permutation(n * 4)[:n] if kind == "dictplain" \
+        else rng.integers(0, 50, n) if kind in ("dict", "dictstr") \
+        else rng.integers(0, 10 ** 6, n)
+    arrow_type = _ARROW[dtype][1]
+    if dtype == "string":
+        values = [f"word{v}" for v in values]
+    elif dtype in ("float32", "float64"):
+        values = values / 4.0
+    elif dtype == "date32":
+        values = values.astype(np.int32)
+    mask = None
+    if levels == "one_null_in_last_page":
+        mask = np.zeros(n, bool)
+        mask[n - 1] = True
+    elif levels == "all_null":
+        mask = np.ones(n, bool)
+    arr = pa.array(values, arrow_type, mask=mask)
+    field = pa.field("v", arrow_type, nullable=levels != "required")
+    return pa.Table.from_arrays([arr], schema=pa.schema([field]))
+
+
+def _write_kind(tbl, path, kind):
+    pq.write_table(tbl, path, use_dictionary=kind != "plain",
+                   dictionary_pagesize_limit=4096 if kind == "dictplain"
+                   else 1 << 20,
+                   data_page_size=2048, write_batch_size=256)
+
+
+@pytest.mark.parametrize("kind,dtype,levels", _KIND_CASES)
+def test_chunk_decodes_by_what_its_pages_hold(tmp_path, monkeypatch, kind,
+                                              dtype, levels):
+    """Each kind of chunk, over each fixed-width type, under each shape of
+    definition levels: bit for bit what pyarrow reads; the nullable
+    program exactly when a page holds a null (one null in the last of
+    several pages is enough), else the program without the null
+    machinery; ``scanChunksNoNulls`` counts exactly the latter."""
+    tbl = _kind_column(kind, dtype, levels)
+    path = str(tmp_path / "t.parquet")
+    _write_kind(tbl, path, kind)
+    schema = T.schema_from_arrow(tbl.schema)
+    pf = pq.ParquetFile(path)
+    max_def = pf.schema.column(0).max_definition_level
+    assert max_def == (0 if levels == "required" else 1)
+    pages = []
+    real = PD._parse_page_header
+
+    def counting(buf, pos):
+        ph = real(buf, pos)
+        pages.append(ph.page_type)
+        return ph
+    monkeypatch.setattr(PD, "_parse_page_header", counting)
+    with open(path, "rb") as f:
+        plan = PD.plan_column_chunk(f, pf.metadata.row_group(0).column(0),
+                                    schema.fields[0], max_def)
+    monkeypatch.setattr(PD, "_parse_page_header", real)
+    # (a chunk of nulls alone holds no bytes to split: one page)
+    assert pages.count(0) > 1 or levels == "all_null", pages
+    has_nulls = levels in ("one_null_in_last_page", "all_null")
+    assert plan.has_nulls == has_nulls
+    # the file is what the case says
+    holds = ("dictstr" if plan.dict_rank is not None
+             else "dictplain" if plan.idx_runs is not None
+             and plan.plain_values is not None
+             else "dict" if plan.idx_runs is not None else "plain")
+    assert holds == kind
+
+    asked = _spy_on_programs(monkeypatch)
+    counters = {}
+    out = PD.decode_row_group(path, 0, schema, counters=counters).to_arrow()
+    want = pq.read_table(path).column("v")
+    assert out.column("v").to_pylist() == want.to_pylist()
+    assert out.column("v").null_count == want.null_count
+    name = _ARROW[dtype][0]
+    (program, key, operands), = asked
+    if has_nulls:
+        assert program == \
+            f"parquet_decode_{name}_bw{plan.idx_bit_width}_{kind}"
+        assert len(key) == 7 and operands == (7 if kind == "dictplain"
+                                              else 6)
+    elif kind == "plain":
+        assert (program, key, operands) == (
+            "parquet_decode_plain_nn", ("plain_nn", 8192), 1)
+    else:
+        assert program == f"parquet_decode_{name}_{kind}_nn"
+        assert key[:3] == (name, 8192, f"{kind}_nn") and len(key) == 4
+        assert operands == (6 if kind == "dictplain" else 4)
+    assert counters["scanChunksNoNulls"] == int(not has_nulls)
+    assert counters["scanColumnChunksDecoded"] == 1
+    assert counters[{"plain": "scanChunksPlain",
+                     "dictplain": "scanChunksDictionaryThenPlain"}.get(
+                         kind, "scanChunksDictionary")] == 1
 
 
 def test_dictionary_typed_arrow_field_reads_as_its_values(tmp_path):

@@ -173,10 +173,10 @@ def test_decode_and_fused_programs_carry_their_names(tmp_path):
     session.read.parquet(path).where(col("l_orderkey") >= lit(0)).collect()
     _q6(session.read.parquet(path)).collect()
     names = {fn.__name__ for fn in kernel_cache._CACHE.values()}
-    assert {"parquet_decode_bigint_bw0_plain",
-            "parquet_decode_double_bw0_plain",
-            "parquet_decode_int_bw0_plain",
-            "parquet_decode_string_bw2_dictstr"} <= names
+    # (no null in this file: a PLAIN chunk is its uploaded buffer, and one
+    # validity program serves every type)
+    assert {"parquet_decode_plain_nn",
+            "parquet_decode_string_dictstr_nn"} <= names
     fused = {p.fn.__name__ for p in fusion._FUSED_CACHE.values()}
     assert fused and all(re.fullmatch(r"fused_[0-9a-f]{8}", n)
                          for n in fused)
@@ -200,6 +200,26 @@ def test_decode_phases_are_named_scopes(tmp_path):
     for scope in ("def_levels/expand_hybrid", "def_levels/unpack",
                   "dict_gather"):
         assert scope in text, scope
+
+
+def test_a_chunk_without_nulls_has_no_definition_level_phase():
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io import parquet_device as PD
+    runs = tuple(jnp.zeros(8, jnp.int32) for _ in range(5))
+    packed = jnp.zeros(16, jnp.uint8)
+    dictionary = jnp.zeros(8, jnp.int32)
+
+    def decode(idx_table, packed, dictionary, n):
+        return PD._decode_chunk_no_nulls(idx_table, packed, None,
+                                         dictionary, n, 128)
+    text = jax.jit(decode).lower(
+        runs, packed, dictionary,
+        jnp.asarray(100, jnp.int32)).as_text(debug_info=True)
+    for scope in ("live_rows", "expand_hybrid", "unpack", "dict_gather"):
+        assert scope in text, scope
+    # the one run-table expansion is the index stream's
+    assert "def_levels" not in text
 
 
 def test_fused_operators_are_named_scopes():
@@ -242,6 +262,8 @@ def test_scan_counters_of_a_small_q6(tmp_path):
     # the projection reaches the scan: the 4 columns Q6 references of 6
     assert totals["scanColumnChunksDecoded"] == 3 * 4
     assert totals["planRuns"] == 1
+    # no null in the file: every chunk decoded without the null machinery
+    assert totals["scanChunksNoNulls"] == totals["scanChunksPlain"] == 3 * 4
     referenced = table.select(["l_quantity", "l_extendedprice",
                                "l_discount", "l_shipdate"])
     assert totals["uploadBytes"] > referenced.nbytes // 2

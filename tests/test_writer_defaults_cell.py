@@ -79,6 +79,9 @@ def test_query_over_default_written_files_is_correct(runs, query):
         == counters["scanColumnChunksDecoded"]
     # nothing is written PLAIN by the writer's defaults
     assert "scanChunksPlain" not in counters
+    # and TPC-H holds no null: no chunk takes a nullable program
+    assert counters["scanChunksNoNulls"] \
+        == counters["scanColumnChunksDecoded"]
 
 
 def test_the_cell_is_in_the_benchmark_with_its_metrics(bench_run):
@@ -87,7 +90,8 @@ def test_the_cell_is_in_the_benchmark_with_its_metrics(bench_run):
         == "tpch_writer_defaults"
     names = {m["name"] for m in cell["per_layer"]}
     assert {"scan_fallback_chunks_per_query", "scan_dict_chunks_per_query",
-            "scan_decode_roofline", "launches_per_query", "query_roofline",
+            "scan_decode_roofline", "scan_nonnull_chunks_per_query",
+            "launches_per_query", "query_roofline",
             "device_idle_pct", "compiles_in_window"} <= names
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -119,6 +123,21 @@ def test_chunk_readers_read_their_counters(runs, name, counter, want):
     assert read(dict(run, completed=0)) is None
 
 
+def test_nonnull_reader_reads_its_counter(runs):
+    run = runs("q6")
+    read = _reader("scan_nonnull_chunks_per_query").read
+    assert read(run) == 4.0
+    # a program that does not count such chunks (the parent), though it
+    # counts chunks by kind: nothing to read
+    parent = dict(run, counters={k: v for k, v in run["counters"].items()
+                                 if k != "scanChunksNoNulls"})
+    assert read(parent) is None
+    # counted, and every chunk held a null: a reading of 0, not a silence
+    assert read(dict(run, counters=dict(run["counters"],
+                                        scanChunksNoNulls=0))) == 0.0
+    assert read(dict(run, completed=0)) is None
+
+
 def test_decode_roofline_reads_the_listed_decode_operations(runs):
     run = runs("q6")
     reader = _reader("scan_decode_roofline")
@@ -128,9 +147,9 @@ def test_decode_roofline_reads_the_listed_decode_operations(runs):
     per_query = run["counters"]["uploadBytes"] / run["completed"]
     traced = dict(run, peaks=peaks, traced_queries=["q6", "q6"], trace={
         "busy_s": 3.0, "device_ops": [
-            ["jit_parquet_decode_double_bw18_dictplain/fusion.1", 0.5],
+            ["jit_parquet_decode_double_dictplain_nn/fusion.1", 0.5],
             ["jit_fused_07f46e2d/fusion.18", 1.0],
-            ["jit_parquet_decode_date_bw12_dict/fusion.2", 0.25]]})
+            ["jit_parquet_decode_date_dict_nn/fusion.2", 0.25]]})
     assert reader.bytes_read(traced) == 2 * per_query
     assert reader.bytes_written(traced) == 2 * rows * 28
     least_s = (2 * per_query + 2 * rows * 28) / 819e9
