@@ -470,12 +470,6 @@ def run_suite(budget_s=DEFAULT_BUDGET_S,
         # retried through); non-zero counters under fault injection prove
         # the recovery machinery actually ran.
         "faults": _fault_section(profiles),
-        # Pallas kernel evidence (ISSUE 8, docs/tuning-guide.md): which
-        # hand-written kernels served each query (staged launches,
-        # compiled pallas programs, fallback reasons). With the gate off
-        # (the default) this records {enabled: false} — the per-kernel
-        # win curve comes from tools/kernel_bench.py's BENCH_kernels.json.
-        "pallas": _pallas_bench_section(profiles),
     }
     # Pipelined-execution A/B (ISSUE-5 acceptance): cold q3/q5 with the
     # pipeline on vs off, budget-guarded like everything else. Runs at a
@@ -547,35 +541,6 @@ def _fault_section(profiles) -> dict:
         if any(counters.values()):
             per_query[qname] = counters
     out = {"totals": totals}
-    if per_query:
-        out["queries"] = per_query
-    return out
-
-
-def _pallas_bench_section(profiles) -> dict:
-    """The BENCH JSON ``pallas`` section: per-kernel suite totals
-    (staged launches, compiled programs, fallback reasons) plus the
-    per-query kernel breakdown for queries where any Pallas kernel ran
-    or fell back — all zeros / empty with the gate off (the default)."""
-    totals: dict = {}
-    per_query: dict = {}
-    enabled = False
-    for qname, p in profiles.items():
-        engine = getattr(p, "engine", None) or {}
-        pal = engine.get("pallas") or {}
-        enabled = enabled or bool(pal.get("enabled"))
-        kernels = pal.get("kernels") or {}
-        if not kernels:
-            continue
-        per_query[qname] = kernels
-        for k, m in kernels.items():
-            t = totals.setdefault(k, {"staged": 0, "programsCompiled": 0,
-                                      "fallbacks": {}})
-            t["staged"] += int(m.get("staged", 0))
-            t["programsCompiled"] += int(m.get("programsCompiled", 0))
-            for r, n in (m.get("fallbacks") or {}).items():
-                t["fallbacks"][r] = t["fallbacks"].get(r, 0) + int(n)
-    out = {"enabled": enabled, "totals": totals}
     if per_query:
         out["queries"] = per_query
     return out

@@ -137,8 +137,9 @@ EXPORT_COLUMNAR_RDD = conf_bool(
 
 UDF_COMPILER_ENABLED = conf_bool(
     "spark.rapids.sql.udfCompiler.enabled", True,
-    "Compile Python UDF bytecode into the expression IR so UDFs run as fused "
-    "XLA/Pallas code instead of falling back to the CPU.")
+    "Compile Python UDF bytecode into the expression IR, so a UDF is "
+    "planned, fused and compiled by XLA with the built-in expressions "
+    "around it instead of falling back to the CPU.")
 
 # ---------------------------------------------------------------------------
 # Batch sizing (reference RapidsConf.scala:306-325)
@@ -533,42 +534,6 @@ TOPK_THRESHOLD = conf_int(
     "ORDER BY ... LIMIT n with n at or below this collapses to the "
     "streaming top-k exec (lax.top_k, O(n log k)) instead of a global "
     "sort. 0 disables limit-into-sort.")
-
-TPU_PALLAS_ENABLED = conf_bool(
-    "spark.rapids.tpu.pallas.enabled", False,
-    "Run the join/sort/groupby/string hot paths through the hand-written "
-    "Pallas TPU kernel library (ops/kernels/pallas/: fused hash-join "
-    "build+probe with the key table VMEM-resident across the probe grid, "
-    "sorted-order segmented aggregation, blockwise bitonic sort over a "
-    "packed key lane, ragged string gather/compare, and the string "
-    "murmur3 row hash) instead of the default jnp implementations — "
-    "which remain the bit-identity oracles. Read PER SESSION at "
-    "dispatch; shapes a kernel cannot serve fall back to the oracle "
-    "with a recorded reason (QueryProfile engine.pallas). On non-TPU "
-    "backends kernels run in Pallas interpreter mode (slow; intended "
-    "for tests). See docs/tuning-guide.md.")
-
-TPU_PALLAS_KERNELS = conf_str(
-    "spark.rapids.tpu.pallas.kernels", "all",
-    "Comma-separated Pallas kernel families to enable when "
-    "spark.rapids.tpu.pallas.enabled is on: hash, joinProbe, segmented, "
-    "sortStep, strings — or 'all' (default). Use with "
-    "tools/kernel_bench.py's per-kernel A/B (BENCH_kernels.json) to "
-    "enable only the families that win on your shapes.")
-
-TPU_PALLAS_VMEM_BUDGET = conf_int(
-    "spark.rapids.tpu.pallas.vmemBudgetBytes", 8 << 20,
-    "Byte budget a Pallas kernel may keep resident in VMEM (join key "
-    "tables, whole sort lanes, ragged source matrices). Shapes over "
-    "budget fall back to the jnp oracle and record a 'vmem' fallback "
-    "reason. TPU cores have ~16MB VMEM; the default leaves headroom for "
-    "blocks and double buffering.")
-
-TPU_PALLAS_BLOCK_ROWS = conf_int(
-    "spark.rapids.tpu.pallas.blockRows", 256,
-    "Rows per Pallas grid step (rounded down to a divisor of the batch "
-    "capacity). Larger blocks amortize grid overhead, smaller ones cut "
-    "VMEM residency per step.")
 
 TPU_UPLOAD_CACHE_BYTES = conf_int(
     "spark.rapids.tpu.uploadCache.maxBytes", 1 << 30,

@@ -713,16 +713,14 @@ class TpuSortExec(TpuExec):
         key_exprs = [o.child.bind(schema) for o in self.orders]
         asc = [o.ascending for o in self.orders]
         nf = [o.effective_nulls_first for o in self.orders]
-        pallas = ctx.pallas  # per-session Pallas gate, read at dispatch
 
         def build():
             def do_sort(b):
                 keys = [e.eval_device(b) for e in key_exprs]
-                return KR.sort_batch_by_columns(b, keys, asc, nf,
-                                                pallas=pallas)
+                return KR.sort_batch_by_columns(b, keys, asc, nf)
             return do_sort
         do_sort = cached_kernel(
-            "sort", kernel_key(key_exprs, asc, nf, pallas.token()), build)
+            "sort", kernel_key(key_exprs, asc, nf), build)
 
         def gen():
             from ..config import SORT_EXTERNAL_THRESHOLD
@@ -1001,22 +999,20 @@ class TpuHashAggregateExec(TpuExec):
         agg_key = kernel_key(groupings, [(a.name, a.func) for a in aggs],
                              buf_schema)
 
-        def build_partial(dense_mode, pallas):
+        def build_partial(dense_mode):
             def partial(batch: ColumnarBatch):
                 return _aggregate_batch(batch, groupings, aggs, buf_schema,
                                         n_keys, update_mode=True,
-                                        dense_mode=dense_mode,
-                                        pallas=pallas)
+                                        dense_mode=dense_mode)
             return partial
 
-        def build_merge(dense_mode, pallas):
+        def build_merge(dense_mode):
             def merge(batch: ColumnarBatch):
                 key_refs = [BoundReference(i, f.data_type, f.nullable)
                             for i, f in enumerate(buf_schema)][:n_keys]
                 return _aggregate_batch(batch, key_refs, aggs, buf_schema,
                                         n_keys, update_mode=False,
-                                        dense_mode=dense_mode,
-                                        pallas=pallas)
+                                        dense_mode=dense_mode)
             return merge
 
         def gen():
@@ -1027,17 +1023,13 @@ class TpuHashAggregateExec(TpuExec):
             site = ctx.next_join_site()
             dense_mode = 1 if ctx.eager_overflow else \
                 min(ctx.dense_modes.get(site, 0), 1)
-            # Per-session Pallas gate: read at dispatch, folded into the
-            # process-wide kernel-cache key so sessions with different
-            # gates never share a traced kernel.
-            pallas = ctx.pallas
-            pkey = agg_key + (dense_mode, pallas.token())
+            pkey = agg_key + (dense_mode,)
             partial_k = cached_kernel(
                 "agg_partial", pkey,
-                lambda: build_partial(dense_mode, pallas))
+                lambda: build_partial(dense_mode))
             merge_k = cached_kernel(
                 "agg_merge", pkey,
-                lambda: build_merge(dense_mode, pallas))
+                lambda: build_merge(dense_mode))
 
             def run_k(k, b):
                 out, fail = k(b)
@@ -1135,7 +1127,7 @@ def finalize_agg_kernel(n_keys: int, aggregates: List[AGG.AggregateExpression],
 def _aggregate_batch(batch: ColumnarBatch, key_exprs: List[Expression],
                      aggs: List[AGG.AggregateExpression],
                      buf_schema: T.Schema, n_keys: int,
-                     update_mode: bool, dense_mode: int = 1, pallas=None):
+                     update_mode: bool, dense_mode: int = 1):
     """One grouping pass. update_mode: inputs are raw rows (evaluate agg
     children, apply update ops). merge mode: inputs are buffer columns.
 
@@ -1175,7 +1167,7 @@ def _aggregate_batch(batch: ColumnarBatch, key_exprs: List[Expression],
     if keys:
         key_cols, results, n_groups, group_live, fail = \
             KG.grouped_aggregate(keys, live, triples,
-                                 dense_mode=dense_mode, pallas=pallas)
+                                 dense_mode=dense_mode)
         if fail is False:
             fail = None  # statically exact path: nothing to observe
     else:
@@ -1200,21 +1192,14 @@ def _aggregate_batch(batch: ColumnarBatch, key_exprs: List[Expression],
 
 
 def hash_join_kernel(jt: str, lkeys: List[Expression],
-                     rkeys: List[Expression], out_schema: T.Schema,
-                     pallas=None):
+                     rkeys: List[Expression], out_schema: T.Schema):
     """Process-cached local equi-join kernel ``(probe, build, out_cap)``.
 
     Shared by the streaming exec and the SPMD mesh path (exec/mesh.py):
     both are, per shard, exactly this local join. Semantics per join type:
     semi/anti return a compacted probe; left/full expand unmatched probe
     rows with nulls; full also returns the build-side hit mask for the
-    caller's unmatched-build pass. ``pallas`` is the caller's per-session
-    gate snapshot (ExecContext.pallas): it selects the fused VMEM
-    build+probe for the dense modes and the ragged string gather for the
-    output assembly, and rides the cache key so differently-gated
-    sessions never share a kernel."""
-    from ..ops.kernels.pallas import resolve as _pallas_resolve
-    pallas = _pallas_resolve(pallas)
+    caller's unmatched-build pass."""
 
     def kernel_impl(probe, build, out_cap, dense=0):
         pk = [e.eval_device(probe) for e in lkeys]
@@ -1225,12 +1210,12 @@ def hash_join_kernel(jt: str, lkeys: List[Expression],
             # a dense-fail flag the retry machinery consumes; no overflow
             # possible.
             return KJ.dense_join(jt, probe, build, pk[0], bk[0],
-                                 out_schema, pallas=pallas)
+                                 out_schema)
         if dense == 2:
             # Swapped mode (inner only): the table builds over the
             # UNIQUE-keyed probe side — the dim.join(fact) shape.
             return KJ.dense_join_swapped(probe, build, pk[0], bk[0],
-                                         out_schema, pallas=pallas)
+                                         out_schema)
         hits = None
         if jt != "full" and len(bk) == 1 \
                 and KJ.binsearch_joinable(bk[0]) \
@@ -1257,16 +1242,14 @@ def hash_join_kernel(jt: str, lkeys: List[Expression],
             lo, exp_counts, build_at_rank, out_cap)
         real = matched[p_idx]
         out_live = jnp.arange(out_cap, dtype=jnp.int32) < n_out
-        pcols = KR.gather_columns(probe.columns, p_idx, out_live,
-                                  pallas=pallas)
-        bcols = KR.gather_columns(build.columns, b_idx, out_live & real,
-                                  pallas=pallas)
+        pcols = KR.gather_columns(probe.columns, p_idx, out_live)
+        bcols = KR.gather_columns(build.columns, b_idx, out_live & real)
         out = ColumnarBatch(tuple(pcols) + tuple(bcols), n_out, out_schema)
         return (out, hits), total
 
     return cached_kernel(
         "hash_join",
-        kernel_key(jt, lkeys, rkeys, out_schema, pallas.token()),
+        kernel_key(jt, lkeys, rkeys, out_schema),
         lambda: kernel_impl, static_argnums=(2, 3))
 
 
@@ -1350,8 +1333,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         rkeys = _bind_all(self.right_keys, right.schema)
         jt = self.join_type
         out_schema = self._schema
-        kernel = hash_join_kernel(jt, lkeys, rkeys, out_schema,
-                                  pallas=ctx.pallas)
+        kernel = hash_join_kernel(jt, lkeys, rkeys, out_schema)
         post_filter = join_post_filter(self.condition, out_schema)
 
         dense_eligible = KJ.dense_joinable(jt, _bind_all(

@@ -240,8 +240,7 @@ class ExternalSorter:
         self._runs: List[_Run] = []
         #: ExecContext for the OOM-retry combinator around merge steps
         #: (spill + retry only — a merge step cannot split); None keeps
-        #: the bare-unit-test construction unchanged. Assigned BEFORE
-        #: _make_sort_one, which reads ctx.pallas.
+        #: the bare-unit-test construction unchanged.
         self._ctx = ctx
         self._sort_one = self._make_sort_one()
 
@@ -261,21 +260,14 @@ class ExternalSorter:
 
     def _make_sort_one(self):
         key_exprs, asc, nf = self.key_exprs, self.asc, self.nf
-        # Run generation is the external sort's device hot loop; the
-        # per-session Pallas gate (ctx.pallas) routes a single packable
-        # key through the VMEM bitonic kernel, and rides the cache key.
-        from ..ops.kernels.pallas import resolve as _pallas_resolve
-        pallas = _pallas_resolve(getattr(self._ctx, "pallas", None))
 
         def build():
             def do_sort(b):
                 keys = [e.eval_device(b) for e in key_exprs]
-                return KR.sort_batch_by_columns(b, keys, asc, nf,
-                                                pallas=pallas)
+                return KR.sort_batch_by_columns(b, keys, asc, nf)
             return do_sort
         return cached_kernel("sort", kernel_key(key_exprs, tuple(asc),
-                                                tuple(nf), pallas.token()),
-                             build)
+                                                tuple(nf)), build)
 
     def add_batch(self, batch: ColumnarBatch):
         sdb = self._sort_one(batch)

@@ -320,11 +320,8 @@ def fused_collect(root: DeviceToHostExec, ctx: ExecContext
     fused_plan = _split(device_plan, boundaries, inline, demote)
     guess_rows = ctx.conf.collect_guess_rows
     caps = tuple(sorted(ctx.join_caps.items())) if ctx.join_caps else ()
-    # The per-session Pallas gate changes the traced program (fused
-    # kernels pick Pallas or jnp paths at trace time), so it must key the
-    # fused cache — sessions with different gates get distinct programs.
     sig = (_plan_sig(fused_plan), float(ctx.join_growth), guess_rows, caps,
-           tuple(sorted(ctx.dense_modes.items())), ctx.pallas.token())
+           tuple(sorted(ctx.dense_modes.items())))
     fn = _FUSED_CACHE.get(sig)
     if fn is None:
         # FusedProgram: the jitted callable plus its AOT executable table,

@@ -150,7 +150,6 @@ class TpuBroadcastNestedLoopJoinExec(TpuExec):
         left, right = self.children
         jt = self.join_type
         out_schema = self._schema
-        pallas = ctx.pallas  # per-session Pallas gate, read at dispatch
         pair_schema = T.Schema(
             list(left.schema) + [
                 T.StructField(f"__b_{f.name}", f.data_type, f.nullable)
@@ -169,10 +168,8 @@ class TpuBroadcastNestedLoopJoinExec(TpuExec):
             p_idx = jnp.repeat(jnp.arange(pcap, dtype=jnp.int32), bcap)
             b_idx = jnp.tile(jnp.arange(bcap, dtype=jnp.int32), pcap)
             live = probe.row_mask()[p_idx] & build.row_mask()[b_idx]
-            pcols = KR.gather_columns(probe.columns, p_idx, live,
-                                      pallas=pallas)
-            bcols = KR.gather_columns(build.columns, b_idx, live,
-                                      pallas=pallas)
+            pcols = KR.gather_columns(probe.columns, p_idx, live)
+            bcols = KR.gather_columns(build.columns, b_idx, live)
             pairs = ColumnarBatch(tuple(pcols) + tuple(bcols),
                                   jnp.asarray(n_pairs, jnp.int32), pair_schema)
             if cond is not None:
@@ -197,10 +194,8 @@ class TpuBroadcastNestedLoopJoinExec(TpuExec):
             out_live = jnp.arange(out_cap, dtype=jnp.int32) < n_match
             sp_idx = p_idx[sel]
             sb_idx = b_idx[sel]
-            ocols = KR.gather_columns(probe.columns, sp_idx, out_live,
-                                      pallas=pallas) \
-                + KR.gather_columns(build.columns, sb_idx, out_live,
-                                    pallas=pallas)
+            ocols = KR.gather_columns(probe.columns, sp_idx, out_live) \
+                + KR.gather_columns(build.columns, sb_idx, out_live)
             out = ColumnarBatch(tuple(ocols),
                                 jnp.minimum(n_match, out_cap).astype(jnp.int32),
                                 out_schema)
@@ -212,7 +207,7 @@ class TpuBroadcastNestedLoopJoinExec(TpuExec):
 
         kernel = cached_kernel(
             "nested_loop_join",
-            kernel_key(jt, cond, pair_schema, out_schema, pallas.token()),
+            kernel_key(jt, cond, pair_schema, out_schema),
             lambda: kernel_impl, static_argnums=(2,))
 
         name = self.node_name()

@@ -84,16 +84,14 @@ def _require(cond: bool, why: str):
 
 
 def _exchange_by_key(batch: ColumnarBatch, key_exprs: List[Expression],
-                     n_parts: int, bucket_cap: int, flags: List,
-                     pallas=None) -> ColumnarBatch:
+                     n_parts: int, bucket_cap: int, flags: List
+                     ) -> ColumnarBatch:
     """Repartition a local shard batch by Spark-murmur3 of the keys: rows
     whose keys hash to chip p land on chip p. One scatter into
     [n_parts, bucket_cap] send buffers, one XLA all_to_all, one compaction.
-    Appends a bucket-overflow flag (psum-reduced) to ``flags``.
-    ``pallas`` is the session's gate snapshot (string keys route through
-    the VMEM murmur3 kernel when enabled)."""
+    Appends a bucket-overflow flag (psum-reduced) to ``flags``."""
     keys = [e.eval_device(batch) for e in key_exprs]
-    h = spark_hash_columns_device(keys, pallas=pallas)
+    h = spark_hash_columns_device(keys)
     pid = pmod_partition(h, n_parts)
     return _exchange_by_pid(batch, pid, n_parts, bucket_cap, flags)
 
@@ -245,11 +243,9 @@ def _compile(node, sources: List, n_parts: int, bucket_growth: float,
                                        n_keys, update_mode=True,
                                        dense_mode=1)
             cap = max(part.capacity // n_parts, 128)
-            from ..ops.kernels.pallas import from_conf as _pallas_from_conf
             shuffled = _exchange_by_key(
                 part, key_refs, n_parts,
-                bucket_capacity(int(cap * bucket_growth)), flags,
-                pallas=_pallas_from_conf(conf))
+                bucket_capacity(int(cap * bucket_growth)), flags)
             merged, _ = _aggregate_batch(shuffled, key_refs, aggs,
                                          buf_schema, n_keys,
                                          update_mode=False, dense_mode=1)
@@ -296,9 +292,7 @@ def _compile(node, sources: List, n_parts: int, bucket_growth: float,
         lkeys = _bind_all(node.left_keys, left.schema)
         rkeys = _bind_all(node.right_keys, right_src.schema)
         out_schema = node.schema
-        from ..ops.kernels.pallas import from_conf as _pallas_from_conf
-        kernel = hash_join_kernel(jt, lkeys, rkeys, out_schema,
-                                  pallas=_pallas_from_conf(conf))
+        kernel = hash_join_kernel(jt, lkeys, rkeys, out_schema)
         post = join_post_filter(node.condition, out_schema)
         unmatched = unmatched_build_kernel(left.schema, out_schema) \
             if jt == "full" else None
@@ -317,14 +311,8 @@ def _compile(node, sources: List, n_parts: int, bucket_growth: float,
                     max(int(probe.capacity * bucket_growth) // n_parts, 128))
                 bcap = bucket_capacity(
                     max(int(build.capacity * bucket_growth) // n_parts, 128))
-                from ..ops.kernels.pallas import \
-                    from_conf as _pallas_from_conf2
-                probe = _exchange_by_key(probe, lkeys, n_parts, pcap,
-                                         flags,
-                                         pallas=_pallas_from_conf2(conf))
-                build = _exchange_by_key(build, rkeys, n_parts, bcap,
-                                         flags,
-                                         pallas=_pallas_from_conf2(conf))
+                probe = _exchange_by_key(probe, lkeys, n_parts, pcap, flags)
+                build = _exchange_by_key(build, rkeys, n_parts, bcap, flags)
             out_cap = bucket_capacity(
                 max(int(probe.capacity * node.growth * bucket_growth), 128))
             if jt in ("left_semi", "left_anti"):

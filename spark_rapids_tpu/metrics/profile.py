@@ -142,14 +142,6 @@ class QueryProfile:
         if mlsec and any(v for v in mlsec.values()
                          if isinstance(v, (int, float))):
             lines.append(f"+ ml  {_fmt_metrics(mlsec)}")
-        pal = self.engine.get("pallas")
-        if pal and (pal.get("enabled") or pal.get("kernels")):
-            kparts = [f"{k}={m.get('staged', 0)}"
-                      for k, m in sorted(pal.get("kernels", {}).items())]
-            lines.append("+ pallas  [enabled="
-                         f"{pal.get('enabled')}"
-                         + (", " + ", ".join(kparts) if kparts else "")
-                         + "]")
         return "\n".join(lines) + "\n"
 
 
@@ -204,7 +196,6 @@ class QueryProfiler:
         from ..compile import executables as _exe
         from ..compile import warmup as _warmup
         from ..compile import xla_events as _xla
-        from ..ops.kernels import pallas as _pallas
         from ..utils import checksum as _ck
         from ..utils import kernel_cache as _kc
         self._kc0 = _kc.cache_stats()
@@ -212,8 +203,6 @@ class QueryProfiler:
         self._exe0 = _exe.stats()
         self._warm0 = _warmup.stats()
         self._ck0 = _ck.stats()
-        self._pallas0 = _pallas.stats()
-        self._pallas_keys0 = _pallas.snapshot_program_keys()
         dm = session.device_manager
         self._spill0 = dict(dm.catalog.metrics)
         self._sem0 = dm.semaphore.wait_ns
@@ -231,7 +220,6 @@ class QueryProfiler:
         from ..compile import executables as _exe
         from ..compile import warmup as _warmup
         from ..compile import xla_events as _xla
-        from ..ops.kernels import pallas as _pallas
         from ..utils import checksum as _ck
         from ..utils import kernel_cache as _kc
         wall_ns = time.perf_counter_ns() - self._t0
@@ -316,17 +304,6 @@ class QueryProfiler:
                 "warmupSkippedCovered": _delta(warm, self._warm0,
                                                "skipped_covered"),
             },
-            # Pallas kernel attribution (ISSUE 8, docs/monitoring.md):
-            # per-kernel stagings (each staging is one launch per dispatch
-            # of the program it was traced into), newly-compiled pallas
-            # program signatures, and the fallback reasons where a kernel
-            # was requested but the jnp oracle ran. Empty when the gate is
-            # off — the section itself proves which kernels served the
-            # query.
-            "pallas": _pallas_section(self._session, self._pallas0,
-                                      _pallas.stats(),
-                                      registry.device_timing,
-                                      self._pallas_keys0),
             # ML scenario attribution (ISSUE 14, docs/monitoring.md):
             # rows exported to trainers, rows scored by ModelScore
             # operators (one deferred device read of the traced per-batch
@@ -376,43 +353,6 @@ def _delta(now: dict, base: dict, key: str) -> int:
 def _rate_per_sec(amount: int, ns: int) -> int:
     """amount / (ns as seconds), 0 when nothing was measured."""
     return int(amount * 1e9 / ns) if ns > 0 else 0
-
-
-def _pallas_section(session, base: dict, now: dict,
-                    device_timing: bool = False,
-                    base_keys: dict = None) -> dict:
-    """The ``engine.pallas`` section: gate state + per-kernel deltas of
-    staged launches / compiled programs / fallback reasons over this
-    query (process-wide stats deltas, like checksumFailures — Pallas
-    wrappers run at trace time, below the per-query registry).
-
-    Under ``spark.rapids.tpu.metrics.deviceTiming`` each kernel that
-    staged this query also gets ``deviceTimeNs``: a fenced zero-input
-    replay of its staged program signatures (a traced pallas_call
-    inlines into the fused XLA program, so its share of the fused
-    dispatch cannot be split out; the replay measures the same program
-    in isolation — same opt-in, fence-free default as the fused
-    deviceTime)."""
-    from ..ops.kernels import pallas as PAL
-    enabled = PAL.from_conf(session.conf).enabled
-    probe = PAL.probe_device_times(base_keys or {}) \
-        if device_timing and enabled else {}
-    kernels = {}
-    for name in sorted(now):
-        cur, old = now[name], base.get(name, {})
-        staged = cur["staged"] - old.get("staged", 0)
-        programs = cur["programs"] - old.get("programs", 0)
-        fb0 = old.get("fallbacks", {})
-        fallbacks = {r: n - fb0.get(r, 0)
-                     for r, n in cur["fallbacks"].items()
-                     if n - fb0.get(r, 0)}
-        if staged or programs or fallbacks:
-            kernels[name] = {"staged": staged, "programsCompiled": programs,
-                             **({"fallbacks": fallbacks} if fallbacks
-                                else {}),
-                             **({"deviceTimeNs": probe[name]}
-                                if name in probe else {})}
-    return {"enabled": enabled, "kernels": kernels}
 
 
 def _ml_section(ctx) -> dict:

@@ -412,8 +412,7 @@ def maybe_tracer(conf, tenant: str = "") -> Optional[Tracer]:
     """A fresh per-query tracer when THIS conf sets
     ``spark.rapids.tpu.trace.enabled``, else None — the one lookup the
     default path pays. Gating is per session: a traced session never
-    turns tracing on for an untraced sibling (the Pallas per-session
-    gate stance)."""
+    turns tracing on for an untraced sibling."""
     from ..config import TRACE_ENABLED
     try:
         if not conf.get(TRACE_ENABLED):

@@ -62,7 +62,7 @@ _STATS = {"export_rows": 0, "train_seconds": 0.0, "model_bytes": 0,
 
 def stats() -> dict:
     """Snapshot of the process-wide ML counters (deltas become the
-    ``engine.ml`` QueryProfile section — the pallas-stats idiom)."""
+    ``engine.ml`` QueryProfile section)."""
     with _STATS_LOCK:
         return dict(_STATS)
 

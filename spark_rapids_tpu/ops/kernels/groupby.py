@@ -32,26 +32,16 @@ from .rowops import (gather_column, orderable_key, orderable_values,
                      sort_permutation, string_sort_keys)
 
 
-def _equal_adjacent(col: DeviceColumn, perm: jnp.ndarray,
-                    pallas=None) -> jnp.ndarray:
+def _equal_adjacent(col: DeviceColumn, perm: jnp.ndarray) -> jnp.ndarray:
     """bool[capacity]: row i (sorted order) has the same key as row i-1.
 
-    The flat-string branch compares W-wide char rows; under the
-    per-session Pallas gate that rowwise compare runs as one VMEM pass
-    (pallas/strings.py ragged_row_equal), jnp twin the oracle."""
+    The flat-string branch compares W-wide char rows."""
     sorted_validity = col.validity[perm]
     vprev = jnp.concatenate([sorted_validity[:1], sorted_validity[:-1]])
     if col.is_string:
         m = char_matrix(col)[perm]
         prev = jnp.concatenate([m[:1], m[:-1]], axis=0)
-        from .pallas import resolve
-        p = resolve(pallas)
-        data_eq = None
-        if p.wants("strings"):
-            from .pallas.strings import ragged_row_equal
-            data_eq = ragged_row_equal(m, prev, p)
-        if data_eq is None:
-            data_eq = jnp.all(m == prev, axis=1)
+        data_eq = jnp.all(m == prev, axis=1)
     else:
         # (bucket, key) pair equality: NaN rides the bucket with a zeroed
         # key and -0.0 canonicalizes, so this is Spark grouping equality.
@@ -65,8 +55,7 @@ def _equal_adjacent(col: DeviceColumn, perm: jnp.ndarray,
     return (data_eq & sorted_validity & vprev) | both_null
 
 
-def group_ids(keys: Sequence[DeviceColumn], n_rows: jnp.ndarray,
-              pallas=None
+def group_ids(keys: Sequence[DeviceColumn], n_rows: jnp.ndarray
               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Compute (segment_id_per_original_row, n_groups, first_row_index_per_group).
 
@@ -79,7 +68,7 @@ def group_ids(keys: Sequence[DeviceColumn], n_rows: jnp.ndarray,
     perm = sort_permutation(keys, n_rows)
     eq = jnp.ones(capacity, dtype=jnp.bool_)
     for k in keys:
-        eq = eq & _equal_adjacent(k, perm, pallas=pallas)
+        eq = eq & _equal_adjacent(k, perm)
     live_sorted = (jnp.arange(capacity, dtype=jnp.int32) < n_rows)
     # First row of the sorted array starts a segment by definition.
     is_boundary = (~eq | (jnp.arange(capacity) == 0)) & live_sorted
@@ -358,7 +347,7 @@ def _dense_int_aggregate(keys, live, inputs):
 
 def grouped_aggregate(keys: Sequence[DeviceColumn], live: jnp.ndarray,
                       inputs: Sequence[Tuple[jnp.ndarray, jnp.ndarray, str]],
-                      dense_mode: int = 0, pallas=None
+                      dense_mode: int = 0
                       ) -> Tuple[List[DeviceColumn],
                                  List[Tuple[jnp.ndarray, jnp.ndarray]],
                                  jnp.ndarray, jnp.ndarray, object]:
@@ -404,15 +393,13 @@ def grouped_aggregate(keys: Sequence[DeviceColumn], live: jnp.ndarray,
                 + (False,)
     if dense_mode == 0 and _dense_eligible(keys, inputs):
         return _dense_int_aggregate(keys, live, inputs)
-    return _sort_grouped_aggregate(keys, live, inputs,
-                                   pallas=pallas) + (False,)
+    return _sort_grouped_aggregate(keys, live, inputs) + (False,)
 
 
 def _sort_grouped_aggregate(keys: Sequence[DeviceColumn],
                             live: jnp.ndarray,
                             inputs: Sequence[Tuple[jnp.ndarray, jnp.ndarray,
-                                                   str]],
-                            pallas=None
+                                                   str]]
                             ) -> Tuple[List[DeviceColumn],
                                        List[Tuple[jnp.ndarray, jnp.ndarray]],
                                        jnp.ndarray, jnp.ndarray]:
@@ -473,23 +460,10 @@ def _sort_grouped_aggregate(keys: Sequence[DeviceColumn],
     key_cols = [gather_column(k, orig_starts, group_live) for k in keys]
 
     # -- per-input reductions (shared dispatch; segment scatters are
-    # single-op HLO: cheap to compile, ~free at runtime). Under the
-    # per-session Pallas gate the sorted prefix-dense gid lane routes
-    # through the one-VMEM-pass segmented kernel (pallas/segmented.py);
-    # ineligible lanes (float sums, over-budget shapes) and the default
-    # path use the jnp oracle below, bit-identically. -------------------
-    from .pallas import resolve as _pallas_resolve
-    _pl = _pallas_resolve(pallas)
-    _pl_seg = _pl.wants("segmented")
-
+    # single-op HLO: cheap to compile, ~free at runtime). ------------------
     def seg(x, op="sum"):
         # One body serves both the 1-D and the lane-stacked 2-D case
-        # (segment_* and the Pallas twin are rank-agnostic here).
-        if _pl_seg:
-            from .pallas.segmented import segment_reduce_sorted
-            out = segment_reduce_sorted(x, gid, capacity, op, _pl)
-            if out is not None:
-                return out
+        # (segment_* is rank-agnostic here).
         f = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
              "max": jax.ops.segment_max}[op]
         return f(x, gid, num_segments=capacity)

@@ -257,28 +257,15 @@ _DENSE_TABLE_FACTOR = 4
 
 
 def _table_build_probe(slot: jnp.ndarray, pslot: jnp.ndarray, tbl: int,
-                       cap_b: int, pallas) -> Tuple[jnp.ndarray,
-                                                    jnp.ndarray,
-                                                    jnp.ndarray]:
+                       cap_b: int) -> Tuple[jnp.ndarray, jnp.ndarray,
+                                            jnp.ndarray]:
     """The direct-address table inner path shared by :func:`dense_join`
     and :func:`dense_join_swapped`: build the (count, first-row) table
     over ``slot`` (pre-sentineled to ``tbl`` for unusable rows) and probe
     it at ``pslot``. Returns ``(cnt_at_probe, row_at_probe, dup)`` where
     ``dup`` is the duplicate-build-key flag ``any(cnt_tbl > 1)``.
 
-    Default: two XLA segment scatters + two full HBM gathers — the jnp
-    oracle. Gated (``spark.rapids.tpu.pallas.enabled`` via the
-    per-session conf): ONE fused Pallas kernel with the table resident
-    in VMEM across the probe grid (pallas/join_probe.py), bit-identical
-    (tests/test_pallas_kernels.py)."""
-    from .pallas import resolve
-    p = resolve(pallas)
-    if p.wants("joinProbe"):
-        from .pallas.join_probe import dense_build_probe
-        fused = dense_build_probe(slot, pslot, tbl, p)
-        if fused is not None:
-            cnt_p, row_p, max_cnt = fused
-            return cnt_p, row_p, max_cnt > 1
+    Two XLA segment scatters + two full HBM gathers."""
     ok = slot < tbl
     cnt_tbl = jax.ops.segment_sum(ok.astype(jnp.int32), slot,
                                   num_segments=tbl + 1)[:tbl]
@@ -304,14 +291,13 @@ def dense_joinable(jt: str, keys) -> bool:
 
 
 def dense_join_swapped(probe, build, pk: DeviceColumn, bk: DeviceColumn,
-                       out_schema, pallas=None):
+                       out_schema):
     """INNER-join dense mode 2: the PROBE side's keys are unique, so the
     table builds over the probe and every BUILD row gathers its (single)
     probe match — the dim.join(fact) shape where the huge fact sits on
     the build side. Output at BUILD capacity, lazy, probe columns first
     (schema order preserved). The table inner path (build + probe) runs
-    through :func:`_table_build_probe` — jnp oracle by default, fused
-    VMEM-resident Pallas kernel under the per-session gate."""
+    through :func:`_table_build_probe`."""
     from ...data.batch import ColumnarBatch
     cap_p = pk.capacity
     tbl = cap_p * _DENSE_TABLE_FACTOR
@@ -328,20 +314,19 @@ def dense_join_swapped(probe, build, pk: DeviceColumn, bk: DeviceColumn,
     in_range_b = usable_b & (kb >= 0) & (kb < tbl)
     bslot = jnp.where(in_range_b, kb, 0).astype(jnp.int32)
 
-    cnt_b, row_b, dup = _table_build_probe(slot, bslot, tbl, cap_p, pallas)
+    cnt_b, row_b, dup = _table_build_probe(slot, bslot, tbl, cap_p)
     fail = jnp.any(usable_p & ~in_range_p) | dup
     matched = in_range_b & (cnt_b > 0)
     probe_row = jnp.clip(row_b, 0, cap_p - 1)
     from .rowops import gather_columns
-    pcols = gather_columns(probe.columns, probe_row, matched,
-                           pallas=pallas)
+    pcols = gather_columns(probe.columns, probe_row, matched)
     return ColumnarBatch(pcols + tuple(build.columns),
                          jnp.sum(matched.astype(jnp.int32)), out_schema,
                          live=matched), fail
 
 
 def dense_join(jt: str, probe, build, pk: DeviceColumn, bk: DeviceColumn,
-               out_schema, pallas=None):
+               out_schema):
     """Direct-address (perfect-hash) equi join for UNIQUE integer build
     keys — the fact-to-dimension shape that dominates TPC-H/DS/xBB.
 
@@ -353,9 +338,7 @@ def dense_join(jt: str, probe, build, pk: DeviceColumn, bk: DeviceColumn,
     match mask), so no compaction pass is paid either; with unique build
     keys the output can never exceed the probe row count, so this path
     cannot overflow. The table build + probe gathers run through
-    :func:`_table_build_probe` — jnp oracle by default, one fused Pallas
-    kernel with the table VMEM-resident across the probe grid under the
-    per-session ``spark.rapids.tpu.pallas.enabled`` gate.
+    :func:`_table_build_probe`.
 
     Returns ``(out_batch, fail)`` where ``fail`` is a traced bool: build
     keys were duplicated or out of table range — the caller's retry
@@ -377,7 +360,7 @@ def dense_join(jt: str, probe, build, pk: DeviceColumn, bk: DeviceColumn,
     in_range_p = usable_p & (kp >= 0) & (kp < tbl)
     pslot = jnp.where(in_range_p, kp, 0).astype(jnp.int32)
 
-    cnt_p, row_p, dup = _table_build_probe(slot, pslot, tbl, cap_b, pallas)
+    cnt_p, row_p, dup = _table_build_probe(slot, pslot, tbl, cap_b)
     # semi/anti only test MEMBERSHIP — duplicate build keys are fine
     # there (the fact-side build of an EXISTS), and only out-of-range
     # keys disqualify the table.
@@ -399,7 +382,7 @@ def dense_join(jt: str, probe, build, pk: DeviceColumn, bk: DeviceColumn,
     build_row = jnp.clip(row_p, 0, cap_b - 1)
     bvalid = matched
     from .rowops import gather_columns
-    bcols = gather_columns(build.columns, build_row, bvalid, pallas=pallas)
+    bcols = gather_columns(build.columns, build_row, bvalid)
     keep = matched if jt == "inner" else live_p
     return ColumnarBatch(tuple(probe.columns) + bcols,
                          jnp.sum(keep.astype(jnp.int32)), out_schema,

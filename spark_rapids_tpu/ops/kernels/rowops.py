@@ -125,21 +125,17 @@ def sort_permutation(keys: Sequence[DeviceColumn], n_rows: jnp.ndarray,
 
 
 def gather_column(col: DeviceColumn, indices: jnp.ndarray,
-                  index_valid: Optional[jnp.ndarray] = None,
-                  pallas=None) -> DeviceColumn:
+                  index_valid: Optional[jnp.ndarray] = None) -> DeviceColumn:
     """Gather rows of ``col`` at ``indices`` (int32[out_capacity]).
 
-    Flat-string rows move through the char matrix; under the per-session
-    ``spark.rapids.tpu.pallas.enabled`` gate that W-wide ragged gather
-    runs as one VMEM pass (pallas/strings.py), jnp twin the default and
-    oracle."""
+    Flat-string rows move through the char matrix."""
     out_cap = indices.shape[0]
     safe = jnp.clip(indices, 0, col.capacity - 1)
     validity = col.validity[safe]
     if index_valid is not None:
         validity = validity & index_valid
     if col.is_struct:
-        kids = tuple(gather_column(c, indices, index_valid, pallas=pallas)
+        kids = tuple(gather_column(c, indices, index_valid)
                      for c in col.children)
         return DeviceColumn(data=None, validity=validity, dtype=col.dtype,
                             children=kids)
@@ -159,15 +155,8 @@ def gather_column(col: DeviceColumn, indices: jnp.ndarray,
         codes = jnp.where(validity, col.codes[safe], 0)
         return col.replace_rows(validity, codes=codes)
     # Flat strings: gather rows of the char matrix, rebuild offsets+payload.
-    from .pallas import resolve
-    p = resolve(pallas)
-    m = None
-    if p.wants("strings"):
-        from .pallas.strings import ragged_gather
-        m = ragged_gather(char_matrix(col), safe, validity, p)
-    if m is None:
-        m = char_matrix(col)[safe]  # [out_cap, W]
-        m = jnp.where(validity[:, None], m, PAD)
+    m = char_matrix(col)[safe]  # [out_cap, W]
+    m = jnp.where(validity[:, None], m, PAD)
     return strings_from_matrix(m, validity, col.max_bytes)
 
 
@@ -198,8 +187,7 @@ def strings_from_matrix(m: jnp.ndarray, validity: jnp.ndarray,
 
 
 def gather_columns(columns, indices: jnp.ndarray,
-                   index_valid: Optional[jnp.ndarray] = None,
-                   pallas=None) -> tuple:
+                   index_valid: Optional[jnp.ndarray] = None) -> tuple:
     """Gather rows of MANY columns at once: fixed-width/dict lanes stack
     by dtype and move with ONE 2D gather per dtype (plus one for the bool
     validity lanes) instead of one kernel launch per column — the TPU
@@ -241,18 +229,17 @@ def gather_columns(columns, indices: jnp.ndarray,
                     out[i] = DeviceColumn(data=d, validity=v, dtype=c.dtype)
     for i, c in enumerate(columns):
         if out[i] is None:
-            out[i] = gather_column(c, indices, index_valid, pallas=pallas)
+            out[i] = gather_column(c, indices, index_valid)
     return tuple(out)
 
 
 def gather_batch(batch: ColumnarBatch, indices: jnp.ndarray,
                  new_n_rows: jnp.ndarray,
-                 index_valid: Optional[jnp.ndarray] = None,
-                 pallas=None) -> ColumnarBatch:
+                 index_valid: Optional[jnp.ndarray] = None) -> ColumnarBatch:
     out_cap = indices.shape[0]
     live = jnp.arange(out_cap, dtype=jnp.int32) < new_n_rows
     iv = live if index_valid is None else (index_valid & live)
-    cols = gather_columns(batch.columns, indices, iv, pallas=pallas)
+    cols = gather_columns(batch.columns, indices, iv)
     return ColumnarBatch(cols, new_n_rows.astype(jnp.int32), batch.schema)
 
 
@@ -361,77 +348,14 @@ def physical_jit(batch: ColumnarBatch) -> ColumnarBatch:
     return _physical_kernel(batch)
 
 
-def packed_sort_lane(batch: ColumnarBatch, keys: Sequence[DeviceColumn],
-                     ascending: Sequence[bool],
-                     nulls_first: Sequence[bool]
-                     ) -> Optional[jnp.ndarray]:
-    """Pack the sort operands into ONE int64 lane for the Pallas bitonic
-    sort (pallas/sort_steps.py), or None when the keys cannot pack.
-
-    Eligible: a single key, <= 32-bit orderable (ints/date/bool/
-    sorted-dict codes; floats stay float in this toolchain and cannot
-    ride an int lane). Layout, high to low — exactly the stable
-    ``lax.sort`` operand order (dead flag, null bucket, key, row index),
-    each field non-negative within its width so int64 compare order ==
-    lexicographic operand order, and the low-bits row index makes every
-    lane unique (bitonic instability cannot reorder equal keys):
-    ``[bit63: 0][4: dead(8)/bucket+4][32: key + 2^31][27: row index]``."""
-    from .pallas.sort_steps import INDEX_BITS
-    if len(keys) != 1:
-        return None
-    k = keys[0]
-    if k.is_complex:
-        return None
-    if k.is_string and not (k.is_dict and k.dict_sorted):
-        return None
-    if not k.is_string and (k.dtype.is_floating
-                            or k.data.dtype.itemsize > 4
-                            or jnp.issubdtype(k.data.dtype,
-                                              jnp.unsignedinteger)):
-        return None
-    capacity = batch.capacity
-    if capacity > 1 << INDEX_BITS:
-        return None
-    a, nf = ascending[0], nulls_first[0]
-    if k.is_string:
-        ops = string_sort_keys(k, a, nf)
-        bucket, key = ops[0], ops[1]
-    else:
-        key, bucket = orderable_key(k, a, nf)
-    live = batch.row_mask()
-    field = jnp.where(live, bucket.astype(jnp.int64) + 4, 8)
-    u = key.astype(jnp.int64) + (1 << 31)       # order-preserving >= 0
-    iota = jnp.arange(capacity, dtype=jnp.int64)
-    return (field << (32 + INDEX_BITS)) | (u << INDEX_BITS) | iota
-
-
 def sort_batch_by_columns(batch: ColumnarBatch,
                           keys: Sequence[DeviceColumn],
                           ascending: Sequence[bool],
-                          nulls_first: Sequence[bool],
-                          pallas=None) -> ColumnarBatch:
+                          nulls_first: Sequence[bool]) -> ColumnarBatch:
     """Sort a batch by evaluated key columns, carrying payload through the
     one sort (see :func:`_permute_by_sort`). Lazy-filtered inputs are
     handled natively: their scattered dead rows sink to the tail through
-    the same dead-row operand, so no separate compaction pass is paid.
-
-    Under the per-session Pallas gate, a single packable key sorts via
-    the VMEM-resident bitonic network over one packed int64 lane
-    (pallas/sort_steps.py) + one payload gather, bit-identical to the
-    ``lax.sort`` oracle (the lane is unique per row)."""
-    from .pallas import resolve
-    p = resolve(pallas)
-    if p.wants("sortStep"):
-        from .pallas.sort_steps import packed_argsort
-        lane = packed_sort_lane(batch, keys, ascending, nulls_first)
-        perm = packed_argsort(lane, p) if lane is not None else None
-        if perm is not None:
-            live_out = jnp.arange(batch.capacity,
-                                  dtype=jnp.int32) < batch.n_rows
-            cols = gather_columns(batch.columns, perm, live_out,
-                                  pallas=pallas)
-            return ColumnarBatch(cols, batch.n_rows.astype(jnp.int32),
-                                 batch.schema)
+    the same dead-row operand, so no separate compaction pass is paid."""
     capacity = batch.capacity
     live = batch.row_mask()
     operands: List[jnp.ndarray] = [jnp.where(live, 0, 1).astype(jnp.int8)]

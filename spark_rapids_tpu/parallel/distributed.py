@@ -56,7 +56,7 @@ def _groupby_sum_count(key, key_valid, val, val_valid, live, n_rows,
 def distributed_sum_by_key(mesh: Mesh, key, key_valid, val, val_valid,
                            n_rows_per_shard,
                            key_dtype=T.LONG, val_dtype=T.LONG,
-                           bucket_cap: int = None, pallas=None):
+                           bucket_cap: int = None):
     """The full distributed aggregation step, jitted over the mesh.
 
     Inputs are globally-sharded arrays: leading dim = total capacity,
@@ -85,10 +85,8 @@ def distributed_sum_by_key(mesh: Mesh, key, key_valid, val, val_valid,
             key, key_valid, val, val_valid, live, n, key_dtype, val_dtype)
 
         # ---- hash partition the groups (Spark murmur3 placement) ----
-        # ``pallas``: the caller's session gate snapshot, if any (this
-        # helper is conf-less; None = the jnp oracle path).
         h = spark_hash_columns_device(
-            [_col(gk, gkv & group_live, key_dtype)], pallas=pallas)
+            [_col(gk, gkv & group_live, key_dtype)])
         pid = pmod_partition(h, n_parts)
 
         # ---- ICI all_to_all exchange ----

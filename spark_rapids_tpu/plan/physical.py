@@ -96,13 +96,6 @@ class ExecContext:
     #: lineage recompute + peer blacklist state that must survive
     #: per-query context rebuilds. Lazily created for bare contexts.
     shuffle_tracker: object = None
-    #: Per-session Pallas kernel gate snapshot (ops/kernels/pallas/
-    #: PallasConf), resolved from conf by __post_init__. Dispatch sites
-    #: read THIS — never the process-global default — and fold its
-    #: token() into their kernel-cache keys, so concurrent sessions with
-    #: different gates cannot poison each other's cached kernels (the
-    #: PR-5 pipeline-sizing fix applied to the Pallas layer).
-    pallas: object = None
     #: QoS identity of this query for spill victim selection
     #: (memory/spill.py QosTag): the session's tenant id
     #: (spark.rapids.tpu.tenantId) plus this query's deadline. Built by
@@ -135,9 +128,6 @@ class ExecContext:
         if self.fault_injector is None:
             from ..utils.fault_injection import FaultInjector
             self.fault_injector = FaultInjector.maybe(self.conf)
-        if self.pallas is None:
-            from ..ops.kernels import pallas as PAL
-            self.pallas = PAL.from_conf(self.conf)
         if self.qos is None:
             from ..config import TENANT_ID
             from ..memory.spill import QosTag

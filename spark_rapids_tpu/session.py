@@ -55,15 +55,9 @@ class TpuSession:
         self.conf = TpuConf(conf)
         self.device_manager = DeviceManager.get_or_create(self.conf)
         self._overrides = TpuOverrides(self.conf)
-        from .config import TPU_PALLAS_ENABLED, TPU_UPLOAD_CACHE_BYTES
+        from .config import TPU_UPLOAD_CACHE_BYTES
         from .data import upload_cache
-        from .ops.kernels import pallas_kernels
         upload_cache.set_budget(self.conf.get(TPU_UPLOAD_CACHE_BYTES))
-        # Legacy process-default only: every dispatch site with an
-        # ExecContext reads the PER-SESSION gate (ExecContext.pallas,
-        # ops/kernels/pallas/) — concurrent sessions no longer override
-        # each other through this call (ISSUE 8).
-        pallas_kernels.configure(self.conf.get(TPU_PALLAS_ENABLED))
         # Compile-once layer: bucket ladder, persistent XLA executable
         # cache, AOT warm-up worker (compile/, docs/compile-cache.md).
         from . import compile as compile_layer
@@ -195,7 +189,6 @@ class TpuSession:
         from .compile import budget, executables, ladder, persist, warmup
         from .exec import fusion
         from .utils import kernel_cache
-        from .ops.kernels import pallas as pallas_lib
         return {
             "ladder": dataclasses.asdict(ladder.get_ladder()),
             "persistent_cache": persist.status(),
@@ -205,12 +198,6 @@ class TpuSession:
             "pad_programs": fusion.pad_program_count(),
             "kernel_cache": kernel_cache.cache_stats(),
             "compile_budget": budget.stats(),
-            # Pallas pallas_call jits bypass the operator kernel cache
-            # (like the pad kernels above), so they get their own
-            # visibility + compile-gate ratchet (ISSUE 8;
-            # tests/test_compile_gate.py pallas_programs_budget).
-            "pallas_programs": pallas_lib.program_count(),
-            "pallas_kernels": pallas_lib.stats(),
         }
 
     # -- ML scenario subsystem (ml/, docs/ml-integration.md) ----------------

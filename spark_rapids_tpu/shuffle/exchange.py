@@ -872,11 +872,6 @@ class TpuShuffleExchangeExec(PhysicalPlan):
         catalog = _shuffle_env(ctx)
         shuffle_id = _new_shuffle_id()
         n_parts = self.n_parts
-        # Snapshot the gate, NOT ctx: the build closure lives in the
-        # process-wide kernel cache, and capturing the whole ExecContext
-        # would pin this session's registry/catalog/tracker for the
-        # cache entry's lifetime.
-        pallas = ctx.pallas
 
         def build():
             from .partitioners import RoundRobinPartitioner
@@ -893,13 +888,12 @@ class TpuShuffleExchangeExec(PhysicalPlan):
                 iota = jnp.arange(batch.capacity, dtype=jnp.int32)
                 sorted_ids, perm = jax.lax.sort((ids, iota), num_keys=1,
                                                 is_stable=True)
-                return KR.gather_batch(batch, perm, batch.n_rows,
-                                       pallas=pallas), sorted_ids
+                return KR.gather_batch(batch, perm, batch.n_rows), sorted_ids
             return partition_sort
         partition_sort = cached_kernel(
             "shuffle_partition_sort",
             kernel_key(type(partitioner).__qualname__, partitioner.__dict__,
-                       n_parts, pallas.token()),
+                       n_parts),
             build)
 
         # WRITE side (RapidsCachingWriter analog, host-serialized payloads).

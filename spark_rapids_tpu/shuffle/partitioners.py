@@ -69,24 +69,16 @@ class RoundRobinPartitioner(Partitioner):
 
 
 class HashPartitioner(Partitioner):
-    """Spark murmur3 hash pmod n (GpuHashPartitioning.scala:141).
-
-    ``pallas`` is the owning session's Pallas gate snapshot (read from
-    the ExecContext at exchange dispatch): it routes string-key hashing
-    through the VMEM murmur3 kernel and — being part of this object's
-    ``__dict__`` — rides the exchange's partition-kernel cache key, so
-    differently-gated sessions never share the traced partition sort."""
+    """Spark murmur3 hash pmod n (GpuHashPartitioning.scala:141)."""
 
     def __init__(self, keys: List[Expression], n_parts: int,
-                 child_schema: T.Schema, pallas=None):
-        from ..ops.kernels.pallas import resolve
+                 child_schema: T.Schema):
         self.n_parts = n_parts
         self._bound = [k.bind(child_schema) for k in keys]
-        self.pallas = resolve(pallas)
 
     def device_ids(self, batch):
         cols = [e.eval_device(batch) for e in self._bound]
-        h = spark_hash_columns_device(cols, pallas=self.pallas)
+        h = spark_hash_columns_device(cols)
         return pmod_partition(h, self.n_parts)
 
     def host_ids(self, hb):
@@ -372,11 +364,7 @@ def partitioner_factory(mode: str, n_parts: int, keys=None, orders=None,
         if mode == "round_robin":
             return RoundRobinPartitioner(n_parts, start)
         if mode == "hash":
-            # Per-session Pallas gate, read at dispatch (ISSUE 8): two
-            # concurrent sessions no longer override each other through
-            # the old process-global pallas_kernels.configure().
-            return HashPartitioner(list(keys), n_parts, schema,
-                                   pallas=getattr(ctx, "pallas", None))
+            return HashPartitioner(list(keys), n_parts, schema)
         assert mode == "range", mode
         key_exprs = [o.child for o in orders]
         asc = [o.ascending for o in orders]

@@ -147,36 +147,24 @@ def _fmix_len(xp, h1, lengths):
 
 
 def spark_hash_columns_device(cols: Sequence[DeviceColumn],
-                              seed: int = SPARK_SEED,
-                              pallas=None) -> jnp.ndarray:
-    """Row hash over device columns (int32, Spark-compatible).
-
-    ``pallas`` is the caller's per-session gate snapshot
-    (ops/kernels/pallas PallasConf); None means the jnp oracle path —
-    a caller without a session context cannot safely consult any
-    process-global gate (its traced kernel's cache key carries no gate
-    token), so un-threaded callers never run Pallas."""
-    from ..ops.kernels.pallas import resolve
-    p = resolve(pallas)
+                              seed: int = SPARK_SEED) -> jnp.ndarray:
+    """Row hash over device columns (int32, Spark-compatible)."""
     n = cols[0].capacity
     h = jnp.full(n, jnp.uint32(seed & 0xFFFFFFFF), dtype=jnp.uint32)
     for c in cols:
-        h = _hash_device_column(c, h, p)
+        h = _hash_device_column(c, h)
     return h.astype(jnp.int32)
 
 
-def _hash_device_column(c: DeviceColumn, h: jnp.ndarray,
-                        pallas=None) -> jnp.ndarray:
+def _hash_device_column(c: DeviceColumn, h: jnp.ndarray) -> jnp.ndarray:
     """Fold one column into the running row hash, Spark semantics: null
     values (and null elements/fields) leave the hash unchanged; arrays and
     structs fold element-by-element / field-by-field
     (Spark HashExpression.computeHash on ArrayType/StructType)."""
-    from ..ops.kernels.pallas import resolve
-    p = resolve(pallas)
     if c.is_struct:
         hh = h
         for kid in c.children:
-            hh = _hash_device_column(kid, hh, p)
+            hh = _hash_device_column(kid, hh)
         return jnp.where(c.validity, hh, h)
     if c.is_array:
         # Sequential fold over the padded element lanes; masked lanes keep
@@ -193,14 +181,7 @@ def _hash_device_column(c: DeviceColumn, h: jnp.ndarray,
     if c.is_string:
         from ..ops.strings_util import lengths as str_lengths
         m = char_matrix(c)
-        if p.wants("hash"):
-            # Hand-written Pallas kernel: the whole W-step mix chain runs
-            # in VMEM (spark.rapids.tpu.pallas.enabled, per session).
-            from ..ops.kernels.pallas.hashing import murmur3_bytes_rows \
-                as pallas_murmur3
-            nh = pallas_murmur3(m, str_lengths(c), h)
-        else:
-            nh = murmur3_bytes_rows(jnp, m, str_lengths(c), h)
+        nh = murmur3_bytes_rows(jnp, m, str_lengths(c), h)
         return jnp.where(c.validity, nh, h)
     return hash_column(jnp, c.data, c.validity, c.dtype, h)
 

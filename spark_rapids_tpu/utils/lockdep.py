@@ -2,7 +2,7 @@
 
 PRs 5-8 made the engine heavily concurrent (the elastic pipeline pool,
 OOM-recovery serialization, shuffle catalogs + the net server thread,
-deadline checks, per-session Pallas gates), which means every new lock is
+deadline checks), which means every new lock is
 a potential deadlock or priority-inversion liability that tier-1 only
 catches if it happens to interleave the bad schedule. This module is the
 Linux-lockdep analog for the engine: every lock construction routes

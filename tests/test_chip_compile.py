@@ -1,7 +1,7 @@
 """The v5e compiler, asked without the chip (on-chip-measurement guide,
-section 2): the fused programs of the smoke's query shapes at 1,048,576
-rows and each Pallas family with ``interpret=False`` must compile for one
-described v5e chip. Nothing runs; a pass here is not a chip run.
+section 2): the fused programs of the smoke's query shapes and the device
+parquet decode programs, at 1,048,576 rows, must compile for one described
+v5e chip. Nothing runs; a pass here is not a chip run.
 
 Everything TPU-related happens inside fixtures and tests of THIS file: only
 one process may hold libtpu, so no other module describes the topology,
@@ -143,77 +143,3 @@ def test_decode_without_nulls_compiles_for_v5e(one_chip, kind):
                     s((1 << 18,), jnp.float64), n, n)
     compiled = jax.jit(kern).lower(*_placed(abstract, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes >= 0
-
-
-# -- the Pallas families, compiled (not interpreted) --------------------------
-
-def _pallas_cases():
-    """family -> (traced function, abstract argument shapes) at one
-    realistic block shape: 1M probe/input rows where the family streams
-    blocks, the largest VMEM-resident operand its default budget admits
-    where it keeps one resident."""
-    from spark_rapids_tpu.ops.kernels import pallas as PK
-    from spark_rapids_tpu.ops.kernels.pallas import (hashing, join_probe,
-                                                     segmented, sort_steps,
-                                                     strings)
-    conf = PK.PallasConf(enabled=True)
-    s = jax.ShapeDtypeStruct
-    return {
-        "join_probe": (
-            lambda b, p: join_probe.dense_build_probe(b, p, 1 << 16, conf),
-            (s((1 << 16,), jnp.int32), s((ROWS,), jnp.int32))),
-        "segmented": (
-            lambda x, g: segmented.segment_reduce_sorted(
-                x, g, 1 << 16, "sum", conf),
-            (s((ROWS, 4), jnp.int64), s((ROWS,), jnp.int32))),
-        "sort_steps": (
-            lambda lane: sort_steps.packed_argsort(lane, conf),
-            (s((1 << 17,), jnp.int64),)),
-        "strings": (
-            lambda a, b: strings.ragged_row_equal(a, b, conf),
-            (s((ROWS, 64), jnp.int16), s((ROWS, 64), jnp.int16))),
-        "hashing": (
-            lambda m, n, seed: hashing.murmur3_bytes_rows(m, n, seed),
-            (s((ROWS, 64), jnp.int16), s((ROWS,), jnp.int32),
-             s((ROWS,), jnp.uint32))),
-    }
-
-
-def _refused(family, raises, message):
-    """A family the Mosaic compiler refuses today is recorded, not repaired
-    here (ROADMAP D5); strict, so the case fails the day it compiles."""
-    return pytest.param(family, marks=pytest.mark.xfail(
-        strict=True, raises=raises, reason=message))
-
-
-_CONVERT_RECURSION = (
-    "RecursionError: maximum recursion depth exceeded — Mosaic's "
-    "_convert_element_type_lowering_rule re-enters itself on the 64-bit "
-    "types jax_enable_x64 puts into the kernel body")
-
-
-@pytest.mark.parametrize("family", [
-    _refused("join_probe", NotImplementedError,
-             "Unimplemented primitive in Pallas TPU lowering for "
-             "KernelType.TC: scatter-add"),
-    _refused("segmented", RecursionError, _CONVERT_RECURSION),
-    _refused("sort_steps", RecursionError, _CONVERT_RECURSION),
-    _refused("strings", Exception,
-             "MosaicError: INTERNAL: Mosaic failed to compile TPU kernel: "
-             "Unsupported element type for the selected reduction"),
-    _refused("hashing", RecursionError, _CONVERT_RECURSION),
-])
-def test_pallas_family_compiles_for_v5e(one_chip, family):
-    from spark_rapids_tpu.ops.kernels import pallas as PK
-    fn, shapes = _pallas_cases()[family]
-
-    def traced(*args):
-        out = fn(*args)
-        assert out is not None, f"{family}: shape fell back to the jnp twin"
-        return out
-    PK.set_interpret_override(False)     # jax.default_backend() is cpu here
-    try:
-        compiled = jax.jit(traced).lower(*_placed(shapes, one_chip)).compile()
-    finally:
-        PK.set_interpret_override(None)
-    assert "tpu_custom_call" in compiled.as_text()

@@ -16,7 +16,6 @@ import os
 
 from spark_rapids_tpu.compile import executables
 from spark_rapids_tpu.exec import fusion
-from spark_rapids_tpu.ops.kernels import pallas as PAL
 from spark_rapids_tpu.session import TpuSession
 from spark_rapids_tpu.utils import kernel_cache as KC
 from spark_rapids_tpu.workloads import tpch
@@ -59,35 +58,3 @@ def test_tpch_smoke_stays_within_compile_budget():
         f"budget {budget['pad_programs_budget']} — these tiny per-rung "
         f"_grow_batch compiles bypass the kernel cache, so this is the "
         f"only counter that can catch them growing ({BASELINE}).")
-
-
-def test_pallas_smoke_stays_within_program_budget():
-    """Pallas ``pallas_call`` jits bypass the operator kernel cache
-    exactly like the PR-6 pad kernels, so they get their own ratchet:
-    q1/q3 at TWO ladder rungs inside one polymorphic tier with every
-    kernel family enabled must stay within the baselined count of
-    distinct pallas program signatures. A kernel that re-specializes per
-    rung (instead of per tier) doubles this count and fails here long
-    before a benchmark notices. Counter: compile_status()['pallas_programs']
-    (per-kernel detail under 'pallas_kernels')."""
-    with open(BASELINE, encoding="utf-8") as f:
-        budget = json.load(f)
-    tables = tpch.gen_tables(1 << 10, seed=3)     # rung 1024
-    big = tpch.gen_tables(1 << 11, seed=3)        # rung 2048, same tier
-    tpu = TpuSession({"spark.rapids.sql.enabled": True,
-                      "spark.rapids.sql.variableFloatAgg.enabled": True,
-                      "spark.rapids.tpu.pallas.enabled": True})
-    before = tpu.compile_status()["pallas_programs"]
-    assert before == PAL.program_count()
-    for name in ("q1", "q3"):
-        q = tpch.QUERIES[name]
-        q(tpch.load(tpu, tables)).collect()
-        q(tpch.load(tpu, big)).collect()
-    programs = tpu.compile_status()["pallas_programs"] - before
-    assert programs <= budget["pallas_programs_budget"], (
-        f"pallas smoke staged {programs} distinct pallas program "
-        f"signatures, budget {budget['pallas_programs_budget']} — "
-        f"pallas_call jits bypass the kernel cache, so per-shape "
-        f"re-specialization shows up ONLY here; lower counts ratchet "
-        f"the baseline down, raising it needs a review note "
-        f"({BASELINE}).")

@@ -220,14 +220,20 @@ class TestTpchSmokeUnderInjection:
     """The acceptance smoke: TPC-H queries complete bit-identically with
     at least one injected OOM at every retry site they visit."""
 
-    @pytest.mark.parametrize("name", ["q1", "q6", "q3"])
-    def test_query_with_oom_at_every_site(self, name):
+    #: (first visits that OOM, seed, fused): -1 unfused faults every
+    #: operator boundary once; -3 under the fused executor makes the
+    #: split-in-half escalation change batch capacities mid-query, so the
+    #: join and aggregate kernels run across shapes while faults retry.
+    @pytest.mark.parametrize("name,oom,seed,fused", [
+        ("q1", -1, 0, False), ("q6", -1, 0, False), ("q3", -1, 0, False),
+        ("q5", -1, 0, False), ("q3", -3, 11, True), ("q5", -3, 11, True)])
+    def test_query_with_oom_at_every_site(self, name, oom, seed, fused):
         from spark_rapids_tpu.workloads import tpch
         from spark_rapids_tpu.workloads.compare import tables_match
         tables = tpch.gen_tables(1 << 10, seed=7)
         tpu = TpuSession(_inject_conf(
-            sites="*", oom=-1,
-            **{"spark.rapids.tpu.fusion.enabled": False,
+            sites="*", oom=oom, seed=seed,
+            **{"spark.rapids.tpu.fusion.enabled": fused,
                "spark.rapids.sql.variableFloatAgg.enabled": True}))
         q = tpch.QUERIES[name]
         got = q(tpch.load(tpu, tables)).collect()

@@ -11,9 +11,12 @@ import pytest
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.data.batch import ColumnarBatch, HostBatch
+from spark_rapids_tpu.data.column import DeviceColumn
 from spark_rapids_tpu.ops.kernels import groupby as G
 from spark_rapids_tpu.ops.kernels import join as J
 from spark_rapids_tpu.ops.kernels import rowops as R
+from spark_rapids_tpu.ops.strings_util import char_matrix, lengths
+from spark_rapids_tpu.shuffle import partitioning as SP
 
 from datagen import FloatGen, IntGen, StringGen, gen_batch
 
@@ -240,3 +243,333 @@ class TestJoin:
                                      b.n_rows, p.n_rows)
         hits = J.build_hit_mask(bids, None, pids, p.n_rows)
         assert np.asarray(hits)[:4].tolist() == [False, True, False, False]
+
+
+# ---------------------------------------------------------------------------
+# The kernels the chip runs, against plain numpy on seeded inputs. The
+# shapes are the matrix these kernels were once only checked on through a
+# second implementation's comparison with them.
+# ---------------------------------------------------------------------------
+
+
+def _keyed_batch(keys, valid, live, prefix):
+    """A lazy two-column batch: int key (nullable), int64 payload that
+    names its row."""
+    cap = len(keys)
+    kcol = DeviceColumn.from_numpy(keys, valid, T.INT, cap)
+    pay = DeviceColumn.from_numpy(np.arange(cap) * 10 + 1, None, T.LONG, cap)
+    schema = T.Schema([T.StructField(prefix + "k", T.INT, True),
+                       T.StructField(prefix + "v", T.LONG, False)])
+    return ColumnarBatch((kcol, pay), jnp.asarray(int(live.sum()), jnp.int32),
+                         schema, live=jnp.asarray(live))
+
+
+def _dense_case(case):
+    """(table keys/valid/live, scan keys/valid/live): the table side is
+    the one the direct-address table builds over."""
+    if case == "one_live_row":
+        cap_t = cap_s = 128
+        kt = np.zeros(cap_t, np.int64)
+        kt[0] = 7
+        live_t = np.zeros(cap_t, bool)
+        live_t[0] = True
+        ks = np.zeros(cap_s, np.int64)
+        ks[3] = 7
+        return (kt, np.ones(cap_t, bool), live_t,
+                ks, np.ones(cap_s, bool), np.ones(cap_s, bool))
+    cap_t, cap_s, dead_frac, dup = case
+    rng = np.random.default_rng(cap_t * cap_s)
+    tbl = cap_t * 4
+    if dup:
+        kt = rng.integers(0, tbl // 2, cap_t)
+        kt[1] = kt[0]                       # one collision at least
+    else:
+        kt = rng.permutation(tbl)[:cap_t]   # unique, spread over the table
+    live_t = rng.random(cap_t) >= dead_frac
+    valid_t = rng.random(cap_t) >= dead_frac / 4
+    if dup:
+        live_t[:2] = valid_t[:2] = True
+    # scan keys: mostly in range, some past either end of the table
+    ks = rng.integers(-tbl // 8, tbl + tbl // 8, cap_s)
+    live_s = rng.random(cap_s) >= dead_frac / 2
+    valid_s = rng.random(cap_s) >= 0.05
+    return kt, valid_t, live_t, ks, valid_s, live_s
+
+
+@pytest.mark.parametrize("case", [
+    (128, 128, 0.0, False),      # minimal table
+    (256, 1024, 0.3, False),     # dead and null rows kept out of the table
+    (384, 896, 0.1, True),       # duplicate table keys raise the flag
+    (128, 256, 1.0, False),      # no usable table row at all
+    "one_live_row",
+], ids=lambda c: c if isinstance(c, str) else "-".join(map(str, c)))
+@pytest.mark.parametrize("swapped", [False, True],
+                         ids=["dense_join", "dense_join_swapped"])
+def test_direct_address_join_matches_numpy(swapped, case):
+    """``_table_build_probe`` through both joins that use it: which scan
+    rows match, the table row each gathers (the first of duplicates) and
+    the duplicate flag, against a dictionary built in Python."""
+    kt, valid_t, live_t, ks, valid_s, live_s = _dense_case(case)
+    table = _keyed_batch(kt, valid_t, live_t, "t_")
+    scan = _keyed_batch(ks, valid_s, live_s, "s_")
+    first, seen_twice = {}, False
+    for i in np.flatnonzero(live_t & valid_t):
+        seen_twice |= int(kt[i]) in first
+        first.setdefault(int(kt[i]), i)
+    want_row = np.asarray([first.get(int(k), -1) for k in ks])
+    want_match = live_s & valid_s & (want_row >= 0)
+    if swapped:
+        out_schema = T.Schema(list(table.schema) + list(scan.schema))
+        out, fail = J.dense_join_swapped(table, scan, table.column(0),
+                                         scan.column(0), out_schema)
+        t_cols, s_cols = out.columns[:2], out.columns[2:]
+    else:
+        out_schema = T.Schema(list(scan.schema) + list(table.schema))
+        out, fail = J.dense_join("inner", scan, table, scan.column(0),
+                                 table.column(0), out_schema)
+        s_cols, t_cols = out.columns[:2], out.columns[2:]
+    assert bool(fail) == seen_twice
+    assert (np.asarray(out.live) == want_match).all()
+    assert int(out.n_rows) == int(want_match.sum())
+    m = want_match
+    assert (np.asarray(t_cols[1].data)[m] == want_row[m] * 10 + 1).all()
+    assert (np.asarray(t_cols[0].data)[m] == ks[m]).all()
+    assert (np.asarray(s_cols[0].data)[m] == ks[m]).all()
+    assert (np.asarray(s_cols[1].data)[m]
+            == (np.arange(len(ks)) * 10 + 1)[m]).all()
+    for c in t_cols:            # an unmatched row gathers nulls
+        assert not np.asarray(c.validity)[~m].any()
+
+
+# -- segment reductions through the sort path and the packed-dictionary path -
+
+#: q1's shape: two sorted-dictionary keys, (3 + null) x (2 + null) = 12
+#: slots and the spare one dead rows land in — 13 segments.
+_DICT_KEYS = (["A", "N", "R"], ["F", "O"])
+_DICT_SLOTS = 12
+
+
+def _dict_key_columns(slots):
+    """Two sorted-dictionary string columns whose packed slot is ``slots``
+    (slot = (code1 + 1 | 0 for null) * 3 + (code2 + 1 | 0 for null))."""
+    cols = []
+    for part, entries in zip((slots // 3, slots % 3), _DICT_KEYS):
+        vals = [None if p == 0 else entries[p - 1] for p in part]
+        col = DeviceColumn.dict_string_from_arrow(
+            pa.array(vals + entries, pa.string()), len(vals) + len(entries))
+        # the appended entries only pin the dictionary; drop their rows
+        cols.append(col.head(len(vals)))
+    return cols
+
+
+def _grouping(path, spec, n, rng):
+    """(key columns, group label per row, n) for one path: labels order
+    as the path's output groups do (nulls first, then ascending)."""
+    if path == "dict13":
+        if spec == "own":
+            n = _DICT_SLOTS
+            slots = rng.permutation(_DICT_SLOTS)
+        elif spec == "one":
+            slots = np.full(n, 7)
+        else:
+            slots = rng.integers(0, _DICT_SLOTS, n)
+        return _dict_key_columns(slots), slots.astype(np.int64), n
+    if spec == "own":
+        keys, valid = rng.permutation(n).astype(np.int64), np.ones(n, bool)
+    elif spec == "one":
+        keys, valid = np.full(n, 5, np.int64), np.ones(n, bool)
+    else:
+        keys = rng.integers(-n // 20, n // 20, n)
+        valid = rng.random(n) >= 0.05
+    col = DeviceColumn.from_numpy(keys, valid, T.INT, n)
+    return [col], np.where(valid, keys, np.iinfo(np.int64).min), n
+
+
+_REDUCE_CASES = (
+    [pytest.param(("random", 1024, dt, op, 1), id=f"{dt}-{op}")
+     for dt in ("int32", "int64") for op in ("sum", "min", "max")]
+    + [pytest.param(("random", 512, "float64", op, 1), id=f"float64-{op}")
+       for op in ("min", "max", "sum")]
+    + [pytest.param(("own", 256, "int64", "sum", 5), id="2d-lanes-own-group"),
+       pytest.param(("one", 512, "int64", "sum", 1), id="one-group"),
+       pytest.param(("one", 1, "int64", "sum", 1), id="one-row"),
+       pytest.param(("random", 128, "int64", "sum", 1, True), id="empty")])
+
+
+@pytest.mark.parametrize("case", _REDUCE_CASES)
+@pytest.mark.parametrize("path", ["sort", "dict13"])
+def test_grouped_reductions_match_numpy(path, case):
+    """``jax.ops.segment_{sum,min,max}`` as the two grouping paths call
+    them — 1-D lanes and the (kind, dtype)-stacked 2-D lanes — against a
+    loop over the groups in numpy: group order, keys, counts, results."""
+    spec, n, dtype, op, lanes = case[:5]
+    empty = len(case) > 5
+    rng = np.random.default_rng(
+        sum(map(ord, f"{path}{spec}{n}{dtype}{op}")))
+    keys, labels, n = _grouping(path, spec, n, rng)
+    live = np.zeros(n, bool) if empty else rng.random(n) >= 0.1
+    if spec != "random":
+        live[:] = True
+    vals, valids = [], []
+    for _ in range(lanes):
+        if dtype == "float64":
+            vals.append(rng.standard_normal(n))
+        else:
+            vals.append(rng.integers(-10**6, 10**6, n).astype(dtype))
+        valids.append(rng.random(n) >= (0.1 if spec == "random" else 0.0))
+    inputs = [(jnp.asarray(v), jnp.asarray(ok), op)
+              for v, ok in zip(vals, valids)]
+    if path == "sort":
+        key_cols, results, n_groups, group_live = \
+            G._sort_grouped_aggregate(keys, jnp.asarray(live), inputs)
+    else:
+        key_cols, results, n_groups, group_live = \
+            G._dict_grouped_aggregate(keys, jnp.asarray(live), inputs,
+                                      _DICT_SLOTS)
+    groups = sorted(set(labels[live].tolist()))
+    g = len(groups)
+    assert int(n_groups) == g
+    assert np.asarray(group_live).tolist() == \
+        [True] * g + [False] * (len(np.asarray(group_live)) - g)
+    # the keys of each output group
+    if path == "sort":
+        null = np.iinfo(np.int64).min
+        assert key_cols[0].to_arrow(g).to_pylist() == \
+            [None if k == null else k for k in groups]
+    else:
+        want = [[None if p == 0 else e[p - 1] for p in part]
+                for part, e in zip((np.asarray(groups, np.int64) // 3,
+                                    np.asarray(groups, np.int64) % 3),
+                                   _DICT_KEYS)]
+        assert [c.to_arrow(g).to_pylist() for c in key_cols] == want
+    f = {"sum": np.sum, "min": np.min, "max": np.max}[op]
+    for (res, cnt), v, ok in zip(results, vals, valids):
+        res, cnt = np.asarray(res), np.asarray(cnt)
+        assert res.dtype == v.dtype
+        for i, label in enumerate(groups):
+            rows = live & ok & (labels == label)
+            assert cnt[i] == rows.sum()
+            if not rows.any():
+                continue
+            want = np.asarray(f(v[rows])).astype(v.dtype)
+            if dtype == "float64" and op == "sum":
+                np.testing.assert_allclose(res[i], want,
+                                           rtol=1e-12, atol=1e-12)
+            else:                           # bit for bit, floats too
+                assert res[i].tobytes() == want.tobytes()
+        assert not cnt[g:].any() and not res[g:].any()
+
+
+# -- the stable sort ----------------------------------------------------------
+
+@pytest.mark.parametrize("n,equal", [(1, False), (7, False), (128, False),
+                                     (777, False), (1024, False),
+                                     (640, True), (0, False)],
+                         ids=["1", "7", "128", "777", "1024", "all-equal",
+                              "empty"])
+def test_sort_is_numpy_stable_argsort(n, equal):
+    """One int key over the whole int32 range carrying a row-id payload:
+    the payload comes out as ``np.argsort(kind="stable")``, so equal keys
+    keep their input order; an empty batch stays empty."""
+    rng = np.random.default_rng(n)
+    cap = max(n, 128) if n in (0, 1, 7) else n
+    keys = np.zeros(cap, np.int64) if equal else \
+        rng.integers(-2**31, 2**31, cap)
+    if not equal and n > 7:
+        keys[rng.integers(0, n, n // 4)] = keys[0]     # ties to keep in order
+    kcol = DeviceColumn.from_numpy(keys, None, T.INT, cap)
+    ids = DeviceColumn.from_numpy(np.arange(cap), None, T.INT, cap)
+    schema = T.Schema([T.StructField("k", T.INT, False),
+                       T.StructField("id", T.INT, False)])
+    batch = ColumnarBatch((kcol, ids), jnp.asarray(n, jnp.int32), schema)
+    out = R.sort_batch_by_columns(batch, [kcol], [True], [True])
+    assert int(out.n_rows) == n
+    want = np.argsort(keys[:n], kind="stable")
+    assert (np.asarray(out.columns[1].data)[:n] == want).all()
+    assert (np.asarray(out.columns[0].data)[:n] == keys[:n][want]).all()
+
+
+# -- flat strings: row gather and adjacent-row equality ----------------------
+
+def _flat_strings(rng, n, w, null_frac=0.1):
+    """A flat (not dictionary-encoded) string column of ``n`` rows of at
+    most ``w`` bytes, and the Python values it holds."""
+    alphabet = list("abcxyz019 _") + ["é", "語"]
+    vals = []
+    for _ in range(n):
+        if rng.random() < null_frac:
+            vals.append(None)
+            continue
+        s = ""
+        for ch in rng.choice(alphabet, rng.integers(0, w + 1)):
+            if len((s + ch).encode()) > w:
+                break
+            s += ch
+        vals.append(s)
+    arr = pa.array(vals, pa.string())
+    offsets = np.frombuffer(arr.buffers()[1], np.int32, n + 1)
+    data = np.frombuffer(arr.buffers()[2] or b"", np.uint8)
+    col = DeviceColumn.string_from_host(
+        offsets, data, np.asarray([v is not None for v in vals]), n)
+    return col, vals
+
+
+@pytest.mark.parametrize("n,m,w", [(128, 128, 1), (300, 512, 24),
+                                   (64, 1024, 48), (128, 256, 8)],
+                         ids=["128x128x1", "300x512x24", "64x1024x48",
+                              "empty"])
+def test_flat_string_gather_matches_numpy(n, m, w):
+    """``gather_column`` of char-matrix rows: indices past either end
+    clip, a row the index mask drops is null, and no index kept at all
+    leaves an empty payload."""
+    rng = np.random.default_rng(n * m)
+    col, vals = _flat_strings(rng, n, w)
+    assert not col.is_dict
+    idx = rng.integers(-5, n + 5, m)
+    keep = np.zeros(m, bool) if (n, m, w) == (128, 256, 8) \
+        else rng.random(m) < 0.8
+    out = R.gather_column(col, jnp.asarray(idx, jnp.int32),
+                          jnp.asarray(keep))
+    want = [vals[int(np.clip(i, 0, n - 1))] if k else None
+            for i, k in zip(idx, keep)]
+    assert out.to_arrow(m).to_pylist() == want
+    assert int(out.offsets[-1]) == \
+        sum(len(v.encode()) for v in want if v is not None)
+
+
+def test_flat_string_adjacent_equality_matches_numpy():
+    """``_equal_adjacent`` on flat strings in a given row order: equal
+    bytes and both valid, or both null."""
+    rng = np.random.default_rng(9)
+    n = 512
+    col, vals = _flat_strings(rng, n, 2, null_frac=0.2)   # many repeats
+    perm = rng.permutation(n)
+    got = np.asarray(G._equal_adjacent(col, jnp.asarray(perm, jnp.int32)))
+    s = [vals[i] for i in perm]
+    want = [True] + [s[i] == s[i - 1] for i in range(1, n)]
+    assert got.tolist() == want
+    assert 0.05 < np.mean(want[1:]) < 0.95
+
+
+# -- the string row hash -------------------------------------------------------
+
+@pytest.mark.parametrize("n,w,columns,all_empty", [
+    (128, 8, 1, False), (512, 24, 1, False), (300, 7, 1, False),
+    (1024, 64, 1, False), (256, 16, 3, False), (128, 8, 1, True)],
+    ids=["128x8", "512x24", "300x7", "1024x64", "chained-seeds",
+         "empty-strings"])
+def test_string_row_hash_matches_host_hash(n, w, columns, all_empty):
+    """``murmur3_bytes_rows`` on the device's char matrix against the host
+    hash the CPU oracle partitions by (``spark_hash_columns_host``); with
+    several columns each row's hash is the next column's seed."""
+    rng = np.random.default_rng(n * w)
+    h = jnp.full(n, np.uint32(SP.SPARK_SEED), jnp.uint32)
+    arrays = []
+    for _ in range(columns):
+        col, vals = _flat_strings(rng, n, 0 if all_empty else w,
+                                  null_frac=0.0)
+        h = SP.murmur3_bytes_rows(jnp, char_matrix(col, w), lengths(col), h)
+        arrays.append(pa.array(vals, pa.string()))
+    want = SP.spark_hash_columns_host(arrays, [T.STRING] * columns)
+    assert (np.asarray(h).astype(np.int32) == want).all()
+    assert all_empty or len(set(want.tolist())) > n // 4

@@ -45,6 +45,17 @@ def _simple_df(s, n=300):
             .agg(AGG.AggregateExpression(AGG.Sum(col("v")), "sv")))
 
 
+def _tpch_q3(s):
+    from spark_rapids_tpu.workloads import tpch
+    return tpch.QUERIES["q3"](tpch.load(s, tpch.gen_tables(1 << 9, seed=4)))
+
+
+#: the queries the fence tests run: one aggregate, and joins + aggregate +
+#: top-k in one fused program
+_FENCE_QUERIES = pytest.mark.parametrize(
+    "query", [_simple_df, _tpch_q3], ids=["simple", "tpch-q3"])
+
+
 class TestRegistry:
     def test_level_parsing(self):
         assert parse_level("none") == NONE
@@ -224,7 +235,9 @@ class TestEventLog:
 
 
 class TestDeviceTimingAndEquivalence:
-    def test_no_fences_by_default_and_bit_identical(self, monkeypatch):
+    @_FENCE_QUERIES
+    def test_no_fences_by_default_and_bit_identical(self, monkeypatch,
+                                                    query):
         import jax
         fences = []
         orig = jax.block_until_ready
@@ -235,20 +248,27 @@ class TestDeviceTimingAndEquivalence:
         monkeypatch.setattr(jax, "block_until_ready", counting)
 
         off = TpuSession({"spark.rapids.sql.enabled": True,
+                          "spark.rapids.sql.variableFloatAgg.enabled": True,
                           "spark.rapids.tpu.metrics.level": "NONE"})
-        got_off = _simple_df(off).collect()
+        got_off = query(off).collect()
         assert not fences, "metrics disabled must insert zero fences"
 
         ess = TpuSession({"spark.rapids.sql.enabled": True,
+                          "spark.rapids.sql.variableFloatAgg.enabled": True,
                           "spark.rapids.tpu.metrics.level": "ESSENTIAL"})
-        got_ess = _simple_df(ess).collect()
+        got_ess = query(ess).collect()
         assert not fences, \
             "metrics WITHOUT deviceTiming must still insert zero fences"
         assert got_off.equals(got_ess), "metrics must not perturb results"
         assert off.last_query_profile() is None
-        assert ess.last_query_profile() is not None
+        prof = ess.last_query_profile()
+        assert prof is not None
+        assert "deviceTime" not in json.dumps(prof.to_dict()), \
+            "no device time without the fence that measures it"
 
-    def test_device_timing_records_fenced_device_time(self, monkeypatch):
+    @_FENCE_QUERIES
+    def test_device_timing_records_fenced_device_time(self, monkeypatch,
+                                                      query):
         import jax
         fences = []
         orig = jax.block_until_ready
@@ -258,10 +278,11 @@ class TestDeviceTimingAndEquivalence:
             return orig(x)
         monkeypatch.setattr(jax, "block_until_ready", counting)
         s = TpuSession({"spark.rapids.sql.enabled": True,
+                        "spark.rapids.sql.variableFloatAgg.enabled": True,
                         "spark.rapids.tpu.metrics.level": "ESSENTIAL",
                         "spark.rapids.tpu.metrics.deviceTiming": "true"})
-        got = _simple_df(s).collect()
-        assert got.num_rows == 3
+        got = query(s).collect()
+        assert got.num_rows > 0
         assert fences, "deviceTiming=true must fence the fused dispatch"
         prof = s.last_query_profile()
         assert prof.extras["WholeStageFusion"]["deviceTime"] > 0

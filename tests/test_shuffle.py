@@ -9,6 +9,7 @@ import pytest
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.config import TpuConf
 from spark_rapids_tpu.data.batch import HostBatch
+from spark_rapids_tpu.ops import aggregates as AGG
 from spark_rapids_tpu.ops.expression import col
 from spark_rapids_tpu.plan.logical import SortOrder
 from spark_rapids_tpu.shuffle import partitioners as PT
@@ -251,11 +252,16 @@ class TestExchange:
         lambda df: df.repartition(4, "k"),
         lambda df: df.repartition(3),
         lambda df: df.repartition_by_range(4, "v"),
-    ])
+        # string keys through the hash exchange: the murmur3 row hash of
+        # char-matrix rows places each group, empty string included
+        lambda df: df.repartition(4, "s").group_by(col("s")).agg(
+            AGG.AggregateExpression(AGG.Sum(col("v")), "sv")),
+    ], ids=["hash", "round-robin", "range", "string-key-aggregate"])
     def test_repartition_differential(self, call):
         data = {"k": [i % 11 for i in range(300)],
                 "v": list(range(300)),
-                "s": [f"x{i % 5}" for i in range(300)]}
+                "s": [["apple", "pear", "fig", "kiwi", "dragonfruit",
+                       ""][i % 6] for i in range(300)]}
         assert_tpu_and_cpu_are_equal(
             lambda s: call(s.create_dataframe(data)))
 

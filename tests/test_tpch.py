@@ -27,9 +27,10 @@ def sessions():
 
 #: Default-tier subset covering the operator families (scan/filter/
 #: project/agg q1/q6, top-k-over-join q3, band/disjunctive join q19,
-#: float scoring xbb_score); deep join trees, semi/anti, and the rest of
-#: the 22 run under ``-m "slow or not slow"``.
-FAST = {"q1", "q3", "q6", "q19", "xbb_score"}
+#: float scoring xbb_score, the six-table join tree with dense and
+#: swapped direct-address joins q5); deeper join trees, semi/anti, and
+#: the rest of the 22 run under ``-m "slow or not slow"``.
+FAST = {"q1", "q3", "q5", "q6", "q19", "xbb_score"}
 
 
 @pytest.mark.parametrize(
