@@ -3,6 +3,9 @@ validated against numpy/pandas oracles — the analog of the reference's
 runtime-internals suites (GpuPartitioningSuite, HashAggregatesSuite internals).
 """
 
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pandas as pd
@@ -11,10 +14,11 @@ import pytest
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.data.batch import ColumnarBatch, HostBatch
-from spark_rapids_tpu.data.column import DeviceColumn
+from spark_rapids_tpu.data.column import DeviceColumn, bucket_capacity
 from spark_rapids_tpu.ops.kernels import groupby as G
 from spark_rapids_tpu.ops.kernels import join as J
 from spark_rapids_tpu.ops.kernels import rowops as R
+from spark_rapids_tpu.ops.kernels.concat import concat_batches
 from spark_rapids_tpu.ops.strings_util import char_matrix, lengths
 from spark_rapids_tpu.shuffle import partitioning as SP
 
@@ -573,3 +577,158 @@ def test_string_row_hash_matches_host_hash(n, w, columns, all_empty):
     want = SP.spark_hash_columns_host(arrays, [T.STRING] * columns)
     assert (np.asarray(h).astype(np.int32) == want).all()
     assert all_empty or len(set(want.tolist())) > n // 4
+
+
+# -- concat_batches: placement against plain numpy ---------------------------
+
+def _concat_column(kind, rng, cap, salt):
+    """(pyarrow array of ``cap`` rows with nulls, its DeviceColumn): every
+    row holds data, so a batch's dead rows carry values the kernel must
+    mask."""
+    null = rng.random(cap) < 0.2
+    if kind == "plain_string":
+        col, vals = _flat_strings(rng, cap, 3 + 4 * (salt % 3), 0.2)
+        return pa.array(vals, pa.string()), col
+    if kind == "dict_string":
+        vals = [None if z else f"b{salt}-{int(v)}"
+                for z, v in zip(null, rng.integers(0, 9 + salt, cap))]
+        arr = pa.array(vals, pa.string())
+    elif kind == "array":
+        # widths differ by batch (1, 2, 4 elements), so narrower inputs pad
+        arr = pa.array(
+            [None if z else
+             [None if rng.random() < 0.2 else int(v) for v in
+              rng.integers(-9, 9, rng.integers(0, 2 ** (salt % 3) + 1))]
+             for z in null], pa.list_(pa.int32()))
+    elif kind == "struct":
+        arr = pa.array(
+            [None if z else
+             {"a": None if rng.random() < 0.2 else int(rng.integers(-99, 99)),
+              "b": None if rng.random() < 0.2 else float(rng.random())}
+             for z in null],
+            pa.struct([("a", pa.int32()), ("b", pa.float64())]))
+    else:
+        typ, values = {
+            "int32": (pa.int32(), rng.integers(-2 ** 31, 2 ** 31, cap)),
+            "int64": (pa.int64(), rng.integers(-2 ** 62, 2 ** 62, cap)),
+            "float64": (pa.float64(), rng.standard_normal(cap) * 1e9),
+            "date": (pa.date32(),
+                     rng.integers(0, 20000, cap).astype(np.int32)),
+            "bool": (pa.bool_(), rng.random(cap) < 0.5)}[kind]
+        arr = pa.array(values, mask=null).cast(typ)
+    return arr, DeviceColumn.from_arrow(arr, cap)
+
+
+def _concat_input(kinds, rng, cap, mode, salt):
+    """One input batch and the rows (per column) a concat must take from
+    it, in order."""
+    arrays, cols = zip(*[_concat_column(k, rng, cap, salt) for k in kinds])
+    if mode == "lazy":                       # a live mask with holes
+        keep = rng.random(cap) < 0.6
+        keep[0], keep[-1] = False, True
+    else:
+        n = {"empty": 0, "one": 1, "full": cap}.get(
+            mode, int(rng.integers(1, cap)))
+        keep = np.arange(cap) < n
+    schema = T.schema_from_arrow(pa.schema(
+        [pa.field(f"c{i}", a.type) for i, a in enumerate(arrays)]))
+    batch = ColumnarBatch(
+        tuple(cols), jnp.asarray(keep.sum(), jnp.int32), schema,
+        live=jnp.asarray(keep) if mode == "lazy" else None)
+    rows = [[v for v, k in zip(a.to_pylist(), keep) if k] for a in arrays]
+    return batch, rows
+
+
+def _assert_dead_past(col, total):
+    """Every lane of ``col`` past row ``total`` is invalid and zero."""
+    assert not np.asarray(col.validity)[total:].any()
+    if col.is_struct:
+        for kid in col.children:
+            _assert_dead_past(kid, total)
+    elif col.is_array:
+        assert not np.asarray(col.data)[total:].any()
+        assert not np.asarray(col.elem_validity)[total:].any()
+        assert not np.asarray(col.lengths)[total:].any()
+    elif col.is_dict:
+        assert not np.asarray(col.codes)[total:].any()
+    elif col.is_string:
+        offsets = np.asarray(col.offsets)
+        assert (offsets[total:] == offsets[total]).all()
+    else:
+        assert not np.asarray(col.data)[total:].any()
+
+
+_CONCAT_KINDS = {
+    "fixed": ("int32", "int64", "float64", "date", "bool"),
+    "dict_string": ("dict_string", "int64"),
+    "plain_string": ("plain_string", "float64"),
+    "array": ("array",),
+    "struct": ("struct", "int32"),
+}
+_CONCAT_LAYOUTS = {
+    "2": [(128, "lazy"), (256, "partial")],
+    "3": [(256, "full"), (128, "empty"), (512, "lazy")],
+    "6": [(128, "lazy"), (256, "partial"), (128, "empty"), (512, "full"),
+          (128, "partial"), (256, "lazy")],
+    # external_sort._merge_step_kernel: carry, chunk, one-row sentinel
+    "merge_step": [(256, "partial"), (512, "full"), (128, "one")],
+}
+
+
+@pytest.mark.parametrize("room", ["exact", "bucket_above"])
+@pytest.mark.parametrize("layout", list(_CONCAT_LAYOUTS))
+@pytest.mark.parametrize("kinds", list(_CONCAT_KINDS))
+def test_concat_batches_matches_numpy(kinds, layout, room):
+    """``concat_batches`` against plain Python: the live rows of every input
+    in batch order, nulls kept, and nothing but dead zero lanes after."""
+    rng = np.random.default_rng(len(kinds) * 31 + len(layout))
+    inputs = [_concat_input(_CONCAT_KINDS[kinds], rng, cap, mode, i)
+              for i, (cap, mode) in enumerate(_CONCAT_LAYOUTS[layout])]
+    caps = sum(cap for cap, _ in _CONCAT_LAYOUTS[layout])
+    out_capacity = caps if room == "exact" else 2 * bucket_capacity(caps)
+    out = concat_batches([b for b, _ in inputs], out_capacity)
+    assert out.live is None and out.capacity == out_capacity
+    total = sum(len(rows[0]) for _, rows in inputs)
+    assert int(out.n_rows) == total
+    got = out.to_arrow()
+    for ci in range(len(_CONCAT_KINDS[kinds])):
+        want = [v for _, rows in inputs for v in rows[ci]]
+        assert got.column(ci).to_pylist() == want
+        _assert_dead_past(out.columns[ci], total)
+
+
+def test_concat_batches_refuses_output_under_sum_of_capacities():
+    """A batch is placed whole, so the output has to hold every input's
+    capacity even when the live rows would fit."""
+    rng = np.random.default_rng(5)
+    batches = [_concat_input(("int64",), rng, cap, "one", i)[0]
+               for i, cap in enumerate((128, 256))]
+    with pytest.raises(ValueError, match="sum of the input capacities"):
+        concat_batches(batches, 256)
+
+
+@pytest.mark.parametrize("shape", ["q3_coalesce", "q6_merge_tree"])
+def test_concat_batches_lowers_without_wide_scatter(shape):
+    """The mechanism's counter: at the benchmark's shapes no scatter writes
+    a lane of the output's width; the ones left are ``physical()``'s
+    ``s32[capacity]`` index maps. Lowered from shapes, nothing runs."""
+    if shape == "q3_coalesce":      # lineitem's six filtered row groups
+        n, cap, out, lazy = 6, 1 << 20, 8 << 20, True
+        types = (T.LONG, T.DOUBLE, T.DOUBLE)
+    else:                           # two one-row global-aggregate states
+        n, cap, out, lazy = 2, 2 << 20, 4 << 20, False
+        types = (T.DOUBLE,)
+    schema = T.Schema([T.StructField(f"c{i}", t)
+                       for i, t in enumerate(types)])
+    lane = lambda dt: jax.ShapeDtypeStruct((cap,), dt)
+    batch = ColumnarBatch(
+        tuple(DeviceColumn(lane(t.np_dtype), lane(np.bool_), t)
+              for t in types),
+        jax.ShapeDtypeStruct((), np.int32), schema,
+        live=lane(np.bool_) if lazy else None)
+    text = jax.jit(concat_batches, static_argnums=(1,)).lower(
+        [batch] * n, out).as_text(dialect="hlo")
+    scatters = re.findall(r"= (\w+\[[\d,]*\])\S* scatter\(", text)
+    assert scatters == ([f"s32[{cap}]"] * n if lazy else [])
+    assert len(re.findall(r"\[%d\]\S* dynamic-update-slice\(" % out, text)) \
+        == 2 * n * len(types)
