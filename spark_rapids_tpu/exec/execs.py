@@ -1218,12 +1218,12 @@ def hash_join_kernel(jt: str, lkeys: List[Expression],
                                          out_schema)
         hits = None
         if jt != "full" and len(bk) == 1 \
-                and KJ.binsearch_joinable(bk[0]) \
-                and KJ.binsearch_joinable(pk[0]):
-            # Fact-to-dimension shape: build-side-only sort + probe binary
-            # search (full joins need the build hit mask, which this path
-            # can't produce without sorting the probe side).
-            lo, counts, build_at_rank = KJ.join_match_binsearch(
+                and KJ.single_key_joinable(bk[0]) \
+                and KJ.single_key_joinable(pk[0]):
+            # Fact-to-dimension shape: the build side sorted once, every
+            # probe key ranked among it (full joins need the build hit
+            # mask, which this path does not produce).
+            lo, counts, build_at_rank = KJ.join_match_sorted_build(
                 bk[0], pk[0], build.row_mask(), probe.row_mask())
         else:
             lo, counts, build_at_rank, hits = KJ.join_match(

@@ -28,10 +28,19 @@ group-by / join uses the fast code paths.
 
 Scope (falls back to the host scan otherwise, reference-style graceful
 degradation): v1 data pages, PLAIN + PLAIN_DICTIONARY/RLE_DICTIONARY
-encodings, flat schemas, dictionary bit widths <= 24. A fixed-width chunk
-may start on its dictionary and finish in PLAIN pages, as parquet-mr and
-parquet-cpp write one whose dictionary page passes its size limit (1 MiB
-by default); PLAIN byte-array and boolean pages stay outside.
+encodings, flat schemas, dictionary bit widths <= 24. A chunk may start on
+its dictionary and finish in PLAIN pages, as parquet-mr and parquet-cpp
+write one whose dictionary page passes its size limit (1 MiB by default);
+PLAIN boolean pages stay outside.
+
+A BYTE_ARRAY chunk with PLAIN values (``[u32 length][bytes]`` a value; all
+of it, or what follows its dictionary pages) becomes a FLAT string column.
+The host concatenates the page payloads and notes where each page's values
+start and how many it holds; the device walks the length prefixes (serial
+within a page, one lane a page: ``length_walk``), gives every row its
+source and length, and copies the text into the Arrow layout
+(``text_place``). A dictionary page is one more page of that walk, so
+nothing is compiled for a dictionary's length.
 """
 
 from __future__ import annotations
@@ -262,6 +271,13 @@ class ColumnChunkPlan:
     dict_rank: Optional[np.ndarray]
     dict_offsets: Optional[np.ndarray]
     dict_payload: Optional[np.ndarray]
+    # a BYTE_ARRAY chunk with PLAIN values: the pages' value bytes back to
+    # back (the dictionary page first, where there is one), zero-padded to
+    # their byte bucket, and per page where its values start in them and
+    # how many it holds
+    text_src: Optional[np.ndarray] = None
+    page_starts: Optional[List[int]] = None
+    page_counts: Optional[List[int]] = None
 
 
 def _decompress(codec: str, payload: bytes, uncompressed_size: int) -> bytes:
@@ -300,6 +316,8 @@ def plan_column_chunk(f, col_md, field: T.StructField,
     idx_runs = _HybridRuns()
     packed = bytearray()
     plain_parts: List[bytes] = []
+    plain_counts: List[int] = []
+    n_dict = 0
     idx_bw = 0
     n_rows = 0
     dict_count = 0
@@ -314,6 +332,7 @@ def plan_column_chunk(f, col_md, field: T.StructField,
         pos += ph.compressed_size
         if ph.page_type == 2:  # dictionary page (PLAIN-encoded)
             dict_vals_raw = payload
+            n_dict = ph.num_values
             continue
         if ph.page_type != 0:
             raise NotImplementedError(f"page type {ph.page_type} (v2?)")
@@ -359,11 +378,11 @@ def plan_column_chunk(f, col_md, field: T.StructField,
         elif ph.encoding == PLAIN:
             uses_plain = True
             plain_parts.append(payload[p:])
+            plain_counts.append(non_null)
         else:
             raise NotImplementedError(f"encoding {ph.encoding}")
         n_rows += ph.num_values
-    if phys == "BYTE_ARRAY" and (uses_plain or not uses_dict):
-        raise NotImplementedError("PLAIN byte-array pages")
+    text_plain = phys == "BYTE_ARRAY" and (uses_plain or not uses_dict)
 
     plan = ColumnChunkPlan(
         dtype=field.data_type, n_rows=n_rows, nullable=field.nullable,
@@ -373,6 +392,20 @@ def plan_column_chunk(f, col_md, field: T.StructField,
         plain_values=None, dict_count=dict_count, dict_values=None,
         dict_rank=None, dict_offsets=None, dict_payload=None)
 
+    if text_plain:
+        # the values' bytes as the pages hold them; finding each value in
+        # them is the device's work (no per-value host loop)
+        parts = ([dict_vals_raw] if uses_dict else []) + plain_parts
+        plan.page_counts = ([n_dict] if uses_dict else []) + plain_counts
+        ends = np.cumsum([len(part) for part in parts], dtype=np.int64)
+        plan.page_starts = [int(end) - len(part)
+                            for end, part in zip(ends, parts)]
+        plan.text_src = np.zeros(bucket_byte_capacity(
+            max(int(ends[-1]) if parts else 0, 4)), np.uint8)
+        for start, part in zip(plan.page_starts, parts):
+            plan.text_src[start: start + len(part)] = np.frombuffer(
+                part, np.uint8)
+        return plan
     if uses_plain:
         raw = b"".join(plain_parts)
         if phys == "BOOLEAN":
@@ -525,6 +558,21 @@ def _count(counters: Optional[dict], name: str, value: int) -> None:
         counters[name] = counters.get(name, 0) + value
 
 
+def _count_chunk(counters: Optional[dict], plan: "ColumnChunkPlan",
+                 kinds, host, upload_ns: int, launch_ns: int) -> None:
+    """One decoded chunk into the scan's counters: the upload's and the
+    launch's host nanoseconds, the bytes uploaded, and the chunk under
+    each of ``kinds`` (what its pages hold)."""
+    _count(counters, "scanUploadNs", upload_ns)
+    _count(counters, "uploadBytes",
+           sum(a.nbytes for a in jax.tree_util.tree_leaves(host)))
+    _count(counters, "scanLaunchNs", launch_ns)
+    _count(counters, "scanColumnChunksDecoded", 1)
+    for kind in kinds:
+        _count(counters, kind, 1)
+    _count(counters, "scanChunksNoNulls", int(not plan.has_nulls))
+
+
 def _live_rows(n_rows, capacity):
     """(row numbers, validity) of a chunk without nulls: all there is to
     compute for a PLAIN one, whose uploaded, zero-padded buffer is the
@@ -624,6 +672,212 @@ def _no_nulls_program(plan: ColumnChunkPlan, capacity: int, kind: str):
         else ["dict", "n_rows"])
 
 
+# -- PLAIN byte arrays: a flat string column --------------------------------
+
+
+def _width_bucket(n: int) -> int:
+    """A flat column's ``max_bytes`` from its longest value: sixteens up
+    to 128 (a char matrix is rows x this, and TPC-H's comments are 43 to
+    198 bytes wide), the byte ladder above."""
+    n = max(int(n), 1)
+    return -(-n // 16) * 16 if n <= 128 else bucket_byte_capacity(n, 8)
+
+
+def _length_walk(src, page_starts, page_counts, walk_steps, steps_cap):
+    """(starts, lengths), each ``int32[steps_cap * pages]`` with value
+    ``k`` of page ``p`` at ``k * pages + p``: where its bytes start in
+    ``src`` and how many they are. A value's place follows from the length
+    before it, so the walk is serial within a page; the pages walk side by
+    side, one lane each, ``walk_steps`` (a traced scalar: the most values
+    any page holds) steps in all."""
+    with jax.named_scope("length_walk"):
+        n_pages = page_starts.shape[0]
+        last = src.shape[0] - 1
+        # The buffer as little-endian 32-bit words: a prefix at any byte is
+        # two words shifted together, two gathers a step and not one a
+        # byte (a gather out of HBM is the walk's whole cost).
+        words = jax.lax.bitcast_convert_type(
+            jnp.pad(src, (0, -src.shape[0] % 4)).reshape(-1, 4), jnp.uint32)
+
+        def step(k, state):
+            pos, table = state
+            word = pos >> 2
+            shift = ((pos & 3) << 3).astype(jnp.uint32)
+            low = words.at[word].get(mode="clip") >> shift
+            high = jnp.where(shift == 0, jnp.uint32(0),
+                             words.at[word + 1].get(mode="clip")
+                             << (jnp.uint32(32) - shift))
+            length = (low | high).astype(jnp.int32)
+            live = k < page_counts
+            # a corrupt prefix cannot walk out of the buffer
+            length = jnp.where(live, jnp.clip(length, 0, last - pos), 0)
+            table = jax.lax.dynamic_update_slice(
+                table, jnp.stack([pos + 4, length])[None],
+                (k, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32)))
+            return jnp.where(live, pos + 4 + length, pos), table
+
+        _, table = jax.lax.fori_loop(
+            0, walk_steps, step,
+            (page_starts, jnp.zeros((steps_cap, 2, n_pages), jnp.int32)))
+        return table[:, 0].reshape(-1), table[:, 1].reshape(-1)
+
+
+def _decode_text_rows(src, page_starts, page_counts, walk_steps, n_rows,
+                      def_table, idx_table, packed, dict_count,
+                      capacity, steps_cap, idx_cap):
+    """Traced: every row's source in ``src``, its length and its validity,
+    and ``[total bytes, longest value]`` for the host to size the column
+    by. ``def_table`` comes with a chunk that holds nulls, ``idx_table``
+    (expanded over ``idx_cap`` slots only) and ``dict_count`` with one
+    that starts on its dictionary: page 0 of the walk is then the
+    dictionary page, non-null value ``s`` below ``dict_count`` is the entry
+    its index names and value ``s`` from there on is PLAIN value
+    ``s - dict_count``."""
+    row, validity = _live_rows(n_rows, capacity)
+    slot = row
+    if def_table is not None:
+        with jax.named_scope("def_levels"):
+            dk, dc, dv, db, dw = def_table
+            levels = _expand_hybrid(dk, dc, dv, db, dw, packed, capacity)
+            validity = (levels == 1) & validity
+            slot = jnp.clip(jnp.cumsum(validity.astype(jnp.int32)) - 1,
+                            0, capacity - 1)
+    starts, lens = _length_walk(src, page_starts, page_counts, walk_steps,
+                                steps_cap)
+    with jax.named_scope("row_source"):
+        n_pages = page_starts.shape[0]
+        plain_counts = page_counts
+        plain_slot = slot
+        if idx_table is not None:
+            plain_counts = page_counts.at[0].set(0)
+            plain_slot = slot - dict_count
+        ends = jnp.cumsum(plain_counts)
+        page = jnp.clip(jnp.searchsorted(ends, plain_slot, side="right",
+                                         method="compare_all"),
+                        0, n_pages - 1)
+        value = plain_slot - (ends - plain_counts)[page]
+        if idx_table is not None:
+            ik, ic, iv, ib, iw = idx_table
+            idx = _expand_hybrid(ik, ic, iv, ib, iw, packed, idx_cap)
+            entry = jnp.clip(idx[jnp.clip(slot, 0, idx_cap - 1)], 0,
+                             jnp.maximum(page_counts[0] - 1, 0))
+            from_dict = slot < dict_count
+            page = jnp.where(from_dict, 0, page)
+            value = jnp.where(from_dict, entry, value)
+        at = jnp.clip(value, 0, steps_cap - 1) * n_pages + page
+        row_start = jnp.where(validity, starts[at], 0)
+        row_len = jnp.where(validity, lens[at], 0)
+        stats = jnp.stack([jnp.sum(row_len), jnp.max(row_len)])
+    return row_start, row_len, validity, stats
+
+
+def _place_text(src, row_start, row_len, out_cap):
+    """Traced: (payload ``uint8[out_cap]``, offsets ``int32[rows + 1]``) of
+    the flat column: the rows' bytes back to back in row order. Output byte
+    ``j`` of row ``r`` is ``src[j + delta[r]]`` with ``delta[r] =
+    row_start[r] - offsets[r]``; the steps of ``delta`` are scattered to
+    the rows' first bytes (a million updates, not a search a byte) and a
+    prefix sum carries each to the row's other bytes. Rows without bytes
+    (null, empty, dead) share their successor's first byte, where the
+    steps add up to the successor's ``delta``."""
+    with jax.named_scope("text_place"):
+        offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                   jnp.cumsum(row_len).astype(jnp.int32)])
+        delta = row_start - offsets[:-1]
+        step = delta - jnp.concatenate([jnp.zeros(1, jnp.int32), delta[:-1]])
+        marks = jnp.zeros(out_cap, jnp.int32).at[offsets[:-1]].add(
+            step, mode="drop", indices_are_sorted=True)
+        byte = jnp.arange(out_cap, dtype=jnp.int32)
+        source = jnp.clip(byte + jnp.cumsum(marks), 0, src.shape[0] - 1)
+        payload = jnp.where(byte < offsets[-1], src[source],
+                            jnp.zeros((), jnp.uint8))
+        return payload, offsets
+
+
+def _text_programs(plan: ColumnChunkPlan, capacity: int, kind: str):
+    """(rows program, host operands, their order) of a BYTE_ARRAY chunk
+    with PLAIN values. Every shape is a bucket: rows, source bytes, pages,
+    the most values a page holds, and (a chunk that starts on its
+    dictionary) the slots its index stream is expanded over and its run
+    table; the true counts are operands."""
+    has_idx = plan.idx_runs is not None
+    n_pages = bucket_byte_capacity(max(len(plan.page_counts), 1), 8)
+    steps_cap = bucket_capacity(max(plan.page_counts + [1]))
+    idx_cap = bucket_capacity(max(plan.dict_count, 1)) if has_idx else 0
+    pad = _run_table_bucket(plan.def_runs if plan.has_nulls else None,
+                            plan.idx_runs)
+    nulls = plan.has_nulls
+
+    def build():
+        def kern(src, starts, counts, steps, n, *rest):
+            rest = list(rest)
+            dt = rest.pop(0) if nulls else None
+            it = rest.pop(0) if has_idx else None
+            pk = rest.pop(0) if nulls or has_idx else None
+            count = rest.pop(0) if has_idx else None
+            return _decode_text_rows(src, starts, counts, steps, n, dt, it,
+                                     pk, count, capacity, steps_cap,
+                                     idx_cap)
+        return kern
+    name = f"string_{kind}" + ("" if nulls else "_nn")
+    kern = cached_kernel(
+        "parquet_decode", (name, capacity, steps_cap, idx_cap, pad), build,
+        suffix=name)
+
+    def padded(xs):
+        a = np.zeros(n_pages, np.int32)
+        a[: len(xs)] = xs
+        return a
+    host = {"src": plan.text_src, "page_starts": padded(plan.page_starts),
+            "page_counts": padded(plan.page_counts),
+            "walk_steps": np.asarray(max(plan.page_counts + [0]), np.int32),
+            "n_rows": np.asarray(plan.n_rows, np.int32)}
+    order = ["src", "page_starts", "page_counts", "walk_steps", "n_rows"]
+    if nulls:
+        host["def"] = _runs_arrays(plan.def_runs, pad)
+        order.append("def")
+    if has_idx:
+        host["idx"] = _runs_arrays(plan.idx_runs, pad)
+        order.append("idx")
+    if nulls or has_idx:
+        host["packed"] = _pad_packed(plan.packed)
+        order.append("packed")
+    if has_idx:
+        host["dict_count"] = np.asarray(plan.dict_count, np.int32)
+        order.append("dict_count")
+    return kern, host, order
+
+
+def _decode_text_chunk(plan: ColumnChunkPlan, capacity: int,
+                       counters: Optional[dict]) -> DeviceColumn:
+    """A BYTE_ARRAY chunk with PLAIN values to a flat string column: the
+    rows program, then — the one wait of a chunk's decode, for two numbers
+    — the text's bytes and the longest value back on the host, which size
+    the payload's bucket and the column's ``max_bytes``, then the copy."""
+    import time
+    has_idx = plan.idx_runs is not None
+    kind, counter = (("dictplain", "scanChunksDictionaryThenPlain")
+                     if has_idx else ("plain", "scanChunksPlain"))
+    kern, host, order = _text_programs(plan, capacity, kind)
+    t0 = time.perf_counter_ns()
+    dev = jax.tree_util.tree_map(jnp.asarray, host)
+    t1 = time.perf_counter_ns()
+    row_start, row_len, validity, stats = kern(*[dev[n] for n in order])
+    total, longest = (int(x) for x in jax.device_get(stats))
+    out_cap = bucket_byte_capacity(max(total, 1))
+    place = cached_kernel(
+        "parquet_decode", ("string_plain_place", out_cap),
+        lambda: lambda src, start, length: _place_text(
+            src, start, length, out_cap),
+        suffix="string_plain_place")
+    payload, offsets = place(dev["src"], row_start, row_len)
+    t2 = time.perf_counter_ns()
+    _count_chunk(counters, plan, (counter, "scanChunksByteArrayPlain"),
+                 host, t1 - t0, t2 - t1)
+    return DeviceColumn(data=payload, validity=validity, dtype=T.STRING,
+                        offsets=offsets, max_bytes=_width_bucket(longest))
+
+
 def decode_chunk(plan: ColumnChunkPlan, capacity: int,
                  counters: Optional[dict] = None) -> DeviceColumn:
     """Upload one chunk's page bytes + run tables and decode on device.
@@ -631,6 +885,8 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int,
     and the launch's host nanoseconds, the bytes uploaded and the chunk
     itself: three clock reads a chunk, nothing per row."""
     import time
+    if plan.text_src is not None:
+        return _decode_text_chunk(plan, capacity, counters)
     dict_string = plan.dict_rank is not None
     has_idx = plan.idx_runs is not None
     has_plain = plan.plain_values is not None
@@ -673,13 +929,7 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int,
     data, validity = out if has_idx or plan.has_nulls \
         else (dev["plain"], out)
     t2 = time.perf_counter_ns()
-    _count(counters, "scanUploadNs", t1 - t0)
-    _count(counters, "uploadBytes",
-           sum(a.nbytes for a in jax.tree_util.tree_leaves(host)))
-    _count(counters, "scanLaunchNs", t2 - t1)
-    _count(counters, "scanColumnChunksDecoded", 1)
-    _count(counters, counter, 1)
-    _count(counters, "scanChunksNoNulls", int(not plan.has_nulls))
+    _count_chunk(counters, plan, (counter,), host, t1 - t0, t2 - t1)
     if dict_string:
         max_bytes = 8
         if plan.dict_offsets is not None and len(plan.dict_offsets) > 1:
@@ -956,10 +1206,11 @@ def device_decodable(path: str, schema: T.Schema, pf=None) -> bool:
             encs = set(cm.encodings)
             # NOTE: "PLAIN" always appears (the dictionary page itself is
             # PLAIN-encoded), so a chunk that actually fell back to PLAIN
-            # data pages is indistinguishable here. A fixed-width one
-            # decodes on the device either way; for a byte-array one the
-            # authoritative gate is plan_column_chunk raising at scan time,
-            # which the scan catches to fall back to the host path.
+            # data pages is indistinguishable here; it decodes on the
+            # device either way. What the footer cannot show (a PLAIN
+            # boolean page, a dictionary page after PLAIN pages) is
+            # refused by plan_column_chunk at scan time, which the scan
+            # catches to fall back to the host path.
             if not encs <= {"PLAIN", "PLAIN_DICTIONARY", "RLE_DICTIONARY",
                             "RLE", "BIT_PACKED"}:
                 return False

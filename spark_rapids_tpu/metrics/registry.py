@@ -168,10 +168,20 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "Device parquet scan: column chunks decoded from "
           "dictionary-encoded data pages alone, fixed-width or string."),
     _spec("scanChunksDictionaryThenPlain", MetricKind.SUM, ESSENTIAL,
-          "Device parquet scan: fixed-width column chunks whose writer "
-          "fell back mid-chunk (the dictionary page passed its size "
-          "limit, 1 MiB by default): dictionary-encoded pages, then PLAIN "
-          "pages, decoded by one parquet_decode_*_dictplain[_nn] program."),
+          "Device parquet scan: column chunks, fixed-width or string, "
+          "whose writer fell back mid-chunk (the dictionary page passed "
+          "its size limit, 1 MiB by default): dictionary-encoded pages, "
+          "then PLAIN pages, decoded by one "
+          "parquet_decode_*_dictplain[_nn] program."),
+    _spec("scanChunksByteArrayPlain", MetricKind.SUM, ESSENTIAL,
+          "Device parquet scan: string column chunks whose values came "
+          "wholly or partly from PLAIN byte-array pages ([u32 length]"
+          "[bytes] a value) and were decoded to a flat string column "
+          "(parquet_decode_string_plain[_nn] / _string_dictplain[_nn], "
+          "then parquet_decode_string_plain_place). Each is counted under "
+          "scanChunksPlain or scanChunksDictionaryThenPlain as well. "
+          "scanLaunchNs of such a chunk holds one wait for the device: "
+          "the text's bytes and the longest value size the column."),
     _spec("scanChunksNoNulls", MetricKind.SUM, ESSENTIAL,
           "Device parquet scan: column chunks, of whichever kind "
           "(scanChunksPlain, scanChunksDictionary and "
@@ -233,7 +243,9 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
     _spec("boundaryOverlapNs", MetricKind.NANO_TIMING, ESSENTIAL,
           "Wall time saved by materializing independent fusion-boundary "
           "subtrees concurrently: the sum of per-boundary times minus "
-          "elapsed time (spark.rapids.tpu.pipeline.boundaryParallelism)."),
+          "elapsed time, 0 when the workers' spans did not overlap; "
+          "reported whenever the boundaries ran on workers "
+          "(spark.rapids.tpu.pipeline.boundaryParallelism)."),
     _spec("checksumFailures", MetricKind.SUM, ESSENTIAL,
           "Shuffle-block / spill-range CRC32C verifications that FAILED "
           "(utils/checksum.py; docs/fault-tolerance.md). Every failure "

@@ -212,21 +212,55 @@ def join_match(build_keys: Sequence[DeviceColumn],
     return lo, counts, build_at_rank, hits
 
 
-def join_match_binsearch(build_key: DeviceColumn, probe_key: DeviceColumn,
-                         live_b: jnp.ndarray, live_p: jnp.ndarray
-                         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Single non-string, non-float equi-key fast path: sort ONLY the build
-    side (typically the small dimension table) and match every probe row by
-    two binary searches — log2(cap_b) gather rounds instead of sorting the
-    (usually much larger) probe side at all. This is the fact-to-dimension
-    join shape that dominates TPC-H/DS.
+def sorted_rank_pair(reference: jnp.ndarray, queries: jnp.ndarray
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """For each query q (any order): (count of refs < q, count of refs <= q)
+    — ``searchsorted`` left and right — over the whole int64 range, by ONE
+    stable merge sort and one route-back sort. ``reference`` must be sorted
+    ascending.
+
+    A sort's time on the TPU follows its shape alone. The binary searches
+    this replaces gather from the reference log2(n_ref) times a query, and
+    a gather out of HBM (a reference too large for VMEM) is both the slowest
+    thing the chip does (23-32 ns an element on the v5e against 7 from
+    VMEM) and the one whose time differs from process to process with where
+    the buffer lies: q13's join read 0.93-1.24 s over four runs of one
+    program (PERF.md, PR 34)."""
+    n_ref, n_q = reference.shape[0], queries.shape[0]
+    total = n_ref + n_q
+    key = jnp.concatenate([reference.astype(jnp.int64),
+                           queries.astype(jnp.int64)])
+    iota = jnp.arange(total, dtype=jnp.int32)
+    # Stable: in a run of equal keys the refs (concatenated first) stay
+    # ahead of the queries, so a query's inclusive ref prefix counts every
+    # ref <= it, and the run's first position every ref < it.
+    s_key, s_idx = jax.lax.sort((key, iota), num_keys=1, is_stable=True)
+    s_isref = (s_idx < n_ref).astype(jnp.int32)
+    ref_incl = jnp.cumsum(s_isref)
+    prev = jnp.concatenate([s_key[:1], s_key[:-1]])
+    run_start = (s_key != prev) | (iota == 0)
+    # refs before the run, broadcast across it (nondecreasing: a cummax
+    # over the start-marked values carries each to its run's end)
+    lo_run = jax.lax.cummax(jnp.where(run_start, ref_incl - s_isref, -1))
+    _, lo, hi = jax.lax.sort((s_idx, lo_run, ref_incl), num_keys=1,
+                             is_stable=False)
+    return lo[n_ref:], hi[n_ref:]
+
+
+def join_match_sorted_build(build_key: DeviceColumn, probe_key: DeviceColumn,
+                            live_b: jnp.ndarray, live_p: jnp.ndarray
+                            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Single non-string, non-float equi-key path: sort the build side with
+    its row numbers, then rank every probe key among the sorted build keys
+    (:func:`sorted_rank_pair`) for its [lo, hi) match range. Three sorts
+    with two or three operands each, no gather out of the build side.
 
     Returns (lo, counts, build_at_rank) with the same contract as
     :func:`join_match`. Null/dead build rows carry an INT64_MAX sentinel
     and sort to the tail; ranks clamp to the usable-build count so a real
     INT64_MAX probe key cannot match them.
     """
-    cap_b, cap_p = build_key.capacity, probe_key.capacity
+    cap_b = build_key.capacity
     kb, _ = orderable_key(build_key)
     kp, _ = orderable_key(probe_key)
     usable_b = live_b & build_key.validity
@@ -240,9 +274,7 @@ def join_match_binsearch(build_key: DeviceColumn, probe_key: DeviceColumn,
         (kb, jnp.where(usable_b, 0, 1).astype(jnp.int8),
          jnp.arange(cap_b, dtype=jnp.int32)), num_keys=2,
         is_stable=True)
-    kp64 = kp.astype(jnp.int64)
-    lo = jnp.searchsorted(sorted_kb, kp64, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(sorted_kb, kp64, side="right").astype(jnp.int32)
+    lo, hi = sorted_rank_pair(sorted_kb, kp.astype(jnp.int64))
     lo = jnp.minimum(lo, n_usable)
     hi = jnp.minimum(hi, n_usable)
     usable_p = live_p & probe_key.validity
@@ -389,8 +421,8 @@ def dense_join(jt: str, probe, build, pk: DeviceColumn, bk: DeviceColumn,
                          live=keep), fail
 
 
-def binsearch_joinable(key: DeviceColumn) -> bool:
-    """True when a key column qualifies for the single-key binary-search
+def single_key_joinable(key: DeviceColumn) -> bool:
+    """True when a key column qualifies for the single-key sorted-build
     join path: fixed-width, non-string (dictionary codes are not comparable
     across two independently-built dictionaries), non-float (NaN
     normalization needs the bucket operand the packed path can't carry)."""

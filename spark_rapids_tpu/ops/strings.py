@@ -20,6 +20,7 @@ from __future__ import annotations
 
 from typing import List, Optional
 
+import jax
 import jax.numpy as jnp
 import pyarrow as pa
 import pyarrow.compute as pc
@@ -263,6 +264,85 @@ def _like_dp(m: jnp.ndarray, toks) -> jnp.ndarray:
     return dp[p]
 
 
+def _like_literals(payload: jnp.ndarray, offsets: jnp.ndarray, toks,
+                   width: int) -> jnp.ndarray:
+    """SQL LIKE of a pattern made of literals and '%' alone, over the
+    strings' bytes as they lie (the Arrow payload and its offsets; rows of
+    a flat column or entries of a dictionary): the same answer as
+    :func:`_like_dp` for every byte string, with no char matrix.
+
+    ``windows``: a literal of ``k`` bytes matches at byte ``j`` of the
+    payload where ``k`` shifted compares of the whole payload agree.
+    ``next_hit``: a running minimum over the next ``width`` bytes (no string
+    is longer: the column's ``max_bytes``), log2(width) shifted minima,
+    turns those marks into "the first match at or after ``j``", so a row
+    reads its leftmost match with one gather at the byte it has reached;
+    the match counts if it ends inside the row (a first match that runs
+    past the row's end leaves none that does not). Greedy leftmost is
+    exact where only '%' separates the literals. A literal at the
+    pattern's start must match at the row's first byte, one at its end at
+    ``end - k``."""
+    n_bytes = payload.shape[0]
+    starts, ends = offsets[:-1], offsets[1:]
+    segs, run = [], []
+    for kind, lit in toks:
+        if kind == 2:
+            if run:
+                segs.append(run)
+            run = []
+        else:
+            run.append(lit)
+    if run:
+        segs.append(run)
+    lead, trail = toks[0][0] == 2, toks[-1][0] == 2
+    none = jnp.int32(n_bytes)
+    byte = jnp.arange(n_bytes, dtype=jnp.int32)
+
+    def windows(lit):
+        with jax.named_scope("windows"):
+            hit = byte <= n_bytes - len(lit)
+            for i, b in enumerate(lit):
+                shifted = payload if i == 0 else jnp.concatenate(
+                    [payload[i:], jnp.zeros(i, payload.dtype)])
+                hit = hit & (shifted == b)
+            return hit
+
+    def at(marks, where):
+        return marks[jnp.clip(where, 0, n_bytes - 1)] & (where >= 0) \
+            & (where < n_bytes)
+
+    def next_hit(hit):
+        with jax.named_scope("next_hit"):
+            nxt, reach = jnp.where(hit, byte, none), 1
+            while reach < min(width, n_bytes):
+                nxt = jnp.minimum(nxt, jnp.concatenate(
+                    [nxt[reach:], jnp.full(reach, none)]))
+                reach *= 2
+            return nxt
+
+    if len(segs) == 1 and not lead and not trail:
+        lit = segs[0]           # no '%' at all (escaped ones): the whole
+        return at(windows(lit), starts) & (ends - starts == len(lit))
+    ok = jnp.ones(starts.shape[0], jnp.bool_)
+    reached = starts
+    first = None if lead else segs.pop(0)
+    last = None if trail else (segs.pop() if segs else None)
+    if first is not None:
+        ok = ok & at(windows(first), starts) \
+            & (starts + len(first) <= ends)
+        reached = starts + len(first)
+    for lit in segs:
+        nxt = next_hit(windows(lit))
+        found = jnp.where(reached < n_bytes,
+                          nxt[jnp.clip(reached, 0, n_bytes - 1)], none)
+        ok = ok & (found <= ends - len(lit))
+        reached = jnp.where(ok, found + len(lit), reached)
+    if last is not None:
+        where = ends - len(last)
+        ok = ok & (where >= reached) & at(windows(last), where)
+    return ok
+
+
 class Like(Expression):
     """SQL LIKE with %/_ wildcards. Device support: patterns reducible to
     prefix/suffix/contains/exact; general patterns tagged to CPU."""
@@ -344,13 +424,20 @@ class Like(Expression):
         toks = self.tokens()
         col = self.children[0].eval_device(batch)
         from .expression import make_column
-        if col.is_dict:
-            dm = _matrix_from_offsets(col.data, col.offsets,
-                                      max(col.max_bytes, 1))
-            hit = _like_dp(dm, toks)
-            res = hit[jnp.clip(col.codes, 0, dm.shape[0] - 1)]
+        w = max(col.max_bytes, 1)
+        if all(kind != 1 for kind, _ in toks):
+            # literals and '%' alone (Q13's '%special%requests%'): window
+            # compares and a running minimum over the bytes as they lie,
+            # entries of a dictionary or rows of a flat column alike
+            with jax.named_scope("like_literals"):
+                hit = _like_literals(col.data, col.offsets, toks, w)
         else:
-            res = _like_dp(char_matrix(col), toks)
+            with jax.named_scope("like_dp"):
+                hit = _like_dp(
+                    _matrix_from_offsets(col.data, col.offsets, w)
+                    if col.is_dict else char_matrix(col), toks)
+        res = hit[jnp.clip(col.codes, 0, hit.shape[0] - 1)] \
+            if col.is_dict else hit
         res = res & col.validity
         return make_column(res, col.validity, T.BOOLEAN)
 

@@ -16,6 +16,7 @@ equivalent for bounded-width columns.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..data.column import DeviceColumn
@@ -35,7 +36,8 @@ def char_matrix(col: DeviceColumn, width: int = None) -> jnp.ndarray:
         dm = _matrix_from_offsets(col.data, col.offsets, w)
         safe = jnp.clip(col.codes, 0, dm.shape[0] - 1)
         return dm[safe]
-    return _matrix_from_offsets(col.data, col.offsets, w)
+    with jax.named_scope("char_matrix"):
+        return _matrix_from_offsets(col.data, col.offsets, w)
 
 
 def _matrix_from_offsets(payload: jnp.ndarray, offsets: jnp.ndarray,

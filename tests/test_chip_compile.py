@@ -143,3 +143,50 @@ def test_decode_without_nulls_compiles_for_v5e(one_chip, kind):
                     s((1 << 18,), jnp.float64), n, n)
     compiled = jax.jit(kern).lower(*_placed(abstract, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes >= 0
+
+
+# -- PLAIN byte arrays at SF1's shapes (ISSUE 34) ------------------------------
+
+def test_text_decode_compiles_for_v5e(one_chip):
+    """The programs of a default-written SF1 o_comment chunk (1,048,576
+    near-unique texts of 19..78 bytes: a dictionary page and 52 PLAIN pages
+    of 20,000 values, 55 MB of page bytes in a 64 MiB bucket): the walk
+    over the length prefixes with each row's source, then the copy of the
+    text into the Arrow layout."""
+    from spark_rapids_tpu.io import parquet_device as PD
+    s = jax.ShapeDtypeStruct
+    n = s((), jnp.int32)
+    runs = tuple(s((128,), jnp.int32) for _ in range(5))
+    pages = s((128,), jnp.int32)
+    src = s((1 << 26,), jnp.uint8)
+    rows = s((ROWS,), jnp.int32)
+
+    def walk(src, starts, counts, steps, n_rows, it, pk, dict_count):
+        return PD._decode_text_rows(src, starts, counts, steps, n_rows, None,
+                                    it, pk, dict_count, ROWS, 1 << 15,
+                                    1 << 15)
+
+    def place(src, row_start, row_len):
+        return PD._place_text(src, row_start, row_len, 1 << 26)
+    for kern, abstract in (
+            (walk, (src, pages, pages, n, n, runs, s((1 << 16,), jnp.uint8),
+                    n)),
+            (place, (src, rows, rows))):
+        compiled = jax.jit(kern).lower(*_placed(abstract, one_chip)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes >= 0
+
+
+def test_like_over_a_flat_comment_column_compiles_for_v5e(one_chip):
+    """Q13's ``'%special%requests%'`` over a flat o_comment column as the
+    fused program meets it (64 MiB of text; 1,048,576 rows padded to the
+    2,097,152-row tier): window compares and two windowed minima over the
+    bytes, in well under a chip's memory."""
+    from spark_rapids_tpu.ops.strings import Like, _like_literals
+    s = jax.ShapeDtypeStruct
+    toks = Like(None, "%special%requests%").tokens()
+
+    def like(payload, offsets):
+        return _like_literals(payload, offsets, toks, 80)
+    abstract = (s((1 << 26,), jnp.uint8), s((2 * ROWS + 1,), jnp.int32))
+    compiled = jax.jit(like).lower(*_placed(abstract, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < (2 << 30)

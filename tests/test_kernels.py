@@ -256,13 +256,13 @@ class TestJoin:
 # ---------------------------------------------------------------------------
 
 
-def _keyed_batch(keys, valid, live, prefix):
+def _keyed_batch(keys, valid, live, prefix, key_type=T.INT):
     """A lazy two-column batch: int key (nullable), int64 payload that
     names its row."""
     cap = len(keys)
-    kcol = DeviceColumn.from_numpy(keys, valid, T.INT, cap)
+    kcol = DeviceColumn.from_numpy(keys, valid, key_type, cap)
     pay = DeviceColumn.from_numpy(np.arange(cap) * 10 + 1, None, T.LONG, cap)
-    schema = T.Schema([T.StructField(prefix + "k", T.INT, True),
+    schema = T.Schema([T.StructField(prefix + "k", key_type, True),
                        T.StructField(prefix + "v", T.LONG, False)])
     return ColumnarBatch((kcol, pay), jnp.asarray(int(live.sum()), jnp.int32),
                          schema, live=jnp.asarray(live))
@@ -343,6 +343,65 @@ def test_direct_address_join_matches_numpy(swapped, case):
             == (np.arange(len(ks)) * 10 + 1)[m]).all()
     for c in t_cols:            # an unmatched row gathers nulls
         assert not np.asarray(c.validity)[~m].any()
+
+
+_I64 = np.iinfo(np.int64)
+
+
+@pytest.mark.parametrize("n_ref,n_q,span", [
+    (0, 5, 10), (7, 0, 10), (1, 1, 1),
+    (1000, 300, 40),             # long runs of equal keys on both sides
+    (300, 5000, 10 ** 6),        # more queries than references
+    (4096, 1024, _I64.max),      # the whole int64 range, both ends present
+], ids=lambda v: str(v))
+def test_sorted_rank_pair_is_searchsorted(n_ref, n_q, span):
+    """The merge that stands where two binary searches stood: left and
+    right ranks of every query, against numpy's ``searchsorted``."""
+    rng = np.random.default_rng(n_ref + n_q)
+    low = _I64.min if span == _I64.max else -span
+    ref = np.sort(rng.integers(low, span, n_ref, dtype=np.int64,
+                               endpoint=True))
+    q = rng.integers(low, span, n_q, dtype=np.int64, endpoint=True)
+    if n_ref > 3:
+        ref[0], ref[-2:] = _I64.min, _I64.max
+    if n_q > 3:
+        q[:2] = [_I64.max, _I64.min]
+        q[2:4] = ref[len(ref) // 2: len(ref) // 2 + 2][:2] if n_ref > 3 else 0
+    lo, hi = jax.jit(J.sorted_rank_pair)(jnp.asarray(ref), jnp.asarray(q))
+    assert lo.dtype == hi.dtype == jnp.int32
+    assert (np.asarray(lo) == np.searchsorted(ref, q, "left")).all()
+    assert (np.asarray(hi) == np.searchsorted(ref, q, "right")).all()
+
+
+@pytest.mark.parametrize("case", [
+    (256, 1024, 0.3, True), (384, 896, 0.1, True), (128, 256, 1.0, False),
+    "max_key",
+], ids=lambda c: c if isinstance(c, str) else "-".join(map(str, c)))
+def test_join_match_sorted_build_ranges(case):
+    """Every probe row's [lo, lo + count) names, through ``build_at_rank``,
+    exactly the usable build rows of its key; a dead or null row on either
+    side matches nothing, and a real Long.MaxValue key does not match the
+    sentinel that dead build rows carry."""
+    if case == "max_key":
+        kt = np.asarray([5, _I64.max, 7, _I64.max, 5, 1, 2, 3] * 16)
+        valid_t = np.ones(128, bool)
+        live_t = np.arange(128) % 8 != 3       # every second MAX row dead
+        ks = np.asarray([_I64.max, 5, 9, _I64.min] * 32)
+        valid_s = live_s = np.ones(128, bool)
+    else:
+        kt, valid_t, live_t, ks, valid_s, live_s = _dense_case(case)
+    table = _keyed_batch(kt, valid_t, live_t, "t_", T.LONG)
+    scan = _keyed_batch(ks, valid_s, live_s, "s_", T.LONG)
+    lo, counts, build_at_rank = jax.jit(J.join_match_sorted_build)(
+        table.column(0), scan.column(0), table.row_mask(), scan.row_mask())
+    lo, counts, build_at_rank = map(np.asarray, (lo, counts, build_at_rank))
+    rows_of = {}
+    for i in np.flatnonzero(live_t & valid_t):
+        rows_of.setdefault(int(kt[i]), []).append(i)
+    for j, k in enumerate(ks):
+        want = rows_of.get(int(k), []) if live_s[j] and valid_s[j] else []
+        got = build_at_rank[lo[j]: lo[j] + counts[j]]
+        assert sorted(got.tolist()) == want, (j, k)
 
 
 # -- segment reductions through the sort path and the packed-dictionary path -

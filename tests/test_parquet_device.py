@@ -167,23 +167,22 @@ def test_hive_partitioned_falls_back(tmp_path):
 @pytest.mark.parametrize("test_enabled", [False, True])
 def test_plain_fallback_pages(tmp_path, test_enabled):
     # use_dictionary=False forces PLAIN data pages: fixed-width columns
-    # decode on device via the plain path; byte-array chunks fall back
-    # per row group inside the exec and stay correct — unless
-    # spark.rapids.sql.test.enabled is set, under which nothing may leave
-    # the device quietly and the decoder's refusal is raised.
+    # decode on device via the plain path, and since ISSUE 34 byte-array
+    # chunks do too (a flat string column): with or without
+    # spark.rapids.sql.test.enabled no row group is read on the host.
     tbl = _table(n=300)
     path = str(tmp_path / "t.parquet")
     pq.write_table(tbl, path, use_dictionary=False)
 
     def query(s):
         return s.read.parquet(path).select(col("i"), col("f"), col("s"))
-    if test_enabled:
-        with pytest.raises(NotImplementedError,
-                           match="PLAIN byte-array pages"):
-            query(tpu_session()).collect()
-    else:
-        assert_tpu_and_cpu_are_equal(
-            query, conf={"spark.rapids.sql.test.enabled": False})
+    conf = {"spark.rapids.sql.test.enabled": test_enabled}
+    assert_tpu_and_cpu_are_equal(query, conf=conf)
+    s = tpu_session(**conf)
+    query(s).collect()
+    totals = s.last_query_profile().totals()
+    assert totals.get("hostFallbackRowGroups", 0) == 0
+    assert totals["scanChunksByteArrayPlain"] == 1
 
 
 class TestRebaseGuard:
@@ -274,9 +273,10 @@ def test_scan_decodes_only_referenced_columns(tmp_path, monkeypatch):
 
 
 def test_unreferenced_plain_string_column_stays_on_device(tmp_path):
-    """A PLAIN byte-array column (as Spark writes a near-unique comment)
-    is outside the device decoder; unreferenced, it is not the scan's
-    business: test.enabled would raise if a row group went to the host."""
+    """A PLAIN byte-array column (as Spark writes a near-unique comment),
+    unreferenced, is not the scan's business; referenced, it decodes on
+    the device too (ISSUE 34): test.enabled would raise if a row group
+    went to the host."""
     from harness import wide_query, wide_table
     tbl = wide_table()
     tbl = tbl.append_column("comment", pa.array(
@@ -293,9 +293,15 @@ def test_unreferenced_plain_string_column_stays_on_device(tmp_path):
     assert totals.get("hostFallbackRowGroups", 0) == 0
     want = wide_query(cpu_session().read.parquet(path)).collect()
     assert got.equals(want)
-    # referenced, the same column is refused: the device scan raises
-    with pytest.raises(Exception):
-        df.where(col("c01") >= 250).select(col("comment")).collect()
+    # referenced, the same column is a flat string column on the device
+    got = df.where(col("c01") >= 250).select(col("comment")).collect()
+    totals = s.last_query_profile().totals()
+    assert totals.get("hostFallbackRowGroups", 0) == 0
+    assert totals["scanChunksByteArrayPlain"] == 3
+    want = cpu_session().read.parquet(path).where(col("c01") >= 250) \
+        .select(col("comment")).collect()
+    assert sorted(got.column("comment").to_pylist()) \
+        == sorted(want.column("comment").to_pylist())
 
 
 def test_two_projections_of_one_file_hash_apart(tmp_path):
@@ -750,3 +756,180 @@ def test_dictionary_typed_arrow_field_reads_as_its_values(tmp_path):
             want.column(name).to_pylist(), name
         assert host.column(name).to_pylist() == \
             want.column(name).to_pylist(), name
+
+
+# -- PLAIN byte arrays: a flat string column (ISSUE 34) -----------------------
+
+_TEXT_WIDTH = 79      # o_comment's varchar(79)
+_TEXT_KINDS = {
+    # writer options that make a string chunk all PLAIN, dictionary pages
+    # then PLAIN (the default writers' fallback, at a 4 KiB limit), or all
+    # dictionary; ``distinct``: how many texts the column draws from
+    "plain": (dict(use_dictionary=False), None),
+    "dictplain": (dict(dictionary_pagesize_limit=4096), None),
+    "dict": (dict(), 40),
+}
+_TEXT_CASES = [(kind, nulls, pages)
+               for kind in _TEXT_KINDS for nulls in ("no_nulls", "nulls",
+                                                     "all_null")
+               for pages in ("one_page", "many_pages")
+               if not (kind == "dictplain" and nulls == "all_null")]
+
+
+def _texts(n, nulls, distinct=None, seed=0):
+    """n strings of 0.._TEXT_WIDTH bytes: empty ones, ones of the full
+    width, ASCII and multi-byte UTF-8, near-unique unless ``distinct``."""
+    rng = np.random.default_rng(seed)
+    alphabet = list("abcdefghij klmnop,.") + ["é", "ß", "€", "日"]
+    pool = []
+    for i in range(distinct or n):
+        chars, size = [], 0
+        want = (0, _TEXT_WIDTH)[i % 2] if i % 17 < 2 \
+            else int(rng.integers(0, _TEXT_WIDTH + 1))
+        while True:
+            c = alphabet[int(rng.integers(0, len(alphabet)))]
+            if size + len(c.encode()) > want:
+                break
+            chars.append(c)
+            size += len(c.encode())
+        pool.append("".join(chars) + "x" * (want - size))
+    vals = [pool[i % len(pool)] for i in rng.permutation(n)]
+    if nulls == "nulls":
+        vals = [None if rng.integers(0, 4) == 0 else v for v in vals]
+    elif nulls == "all_null":
+        vals = [None] * n
+    return pa.array(vals, pa.string())
+
+
+def _decode_text_groups(path, counters=None):
+    """[(device column, rows, what pyarrow's own reader gives)] a row
+    group of the file's one column ``s``."""
+    schema = T.Schema([T.StructField("s", T.STRING, True)])
+    pf = pq.ParquetFile(path)
+    out = []
+    for rg in range(pf.metadata.num_row_groups):
+        batch = PD.decode_row_group(path, rg, schema, pf=pf,
+                                    counters=counters)
+        want = pf.read_row_group(rg).column("s").combine_chunks()
+        out.append((batch.columns[0], int(batch.n_rows), want))
+    return out
+
+
+@pytest.mark.parametrize("kind,nulls,pages", _TEXT_CASES)
+def test_byte_array_chunk_decodes_as_pyarrow_reads(tmp_path, kind, nulls,
+                                                   pages):
+    """Payload, offsets and validity of a string chunk, byte for byte,
+    whatever its v1 pages hold; 2,500 rows in row groups of 1,000, so the
+    last one is short."""
+    options, distinct = _TEXT_KINDS[kind]
+    arr = _texts(2500, nulls, distinct)
+    path = str(tmp_path / "text.parquet")
+    pq.write_table(pa.table({"s": arr}), path, row_group_size=1000,
+                   data_page_size=(1 << 20) if pages == "one_page" else 512,
+                   write_batch_size=64, **options)
+    counters = {}
+    groups = _decode_text_groups(path, counters)
+    assert [n for _, n, _ in groups] == [1000, 1000, 500]
+    for column, n, want in groups:
+        got = column.to_arrow(n)
+        assert got.equals(want)
+        assert column.is_dict == (kind == "dict")
+        if kind == "dict":
+            continue
+        # the flat layout IS Arrow's: offsets and payload as pyarrow's
+        # reader lays them out (nulls take no byte)
+        w_off = np.frombuffer(want.buffers()[1], np.int32)[:n + 1]
+        w_off = w_off - w_off[0]
+        offsets = np.asarray(column.offsets)
+        assert np.array_equal(offsets[:n + 1], w_off)
+        assert (offsets[n:] == w_off[-1]).all()         # dead rows clamp
+        w_data = np.frombuffer(want.buffers()[2], np.uint8) \
+            if want.buffers()[2] is not None else np.zeros(0, np.uint8)
+        first = np.frombuffer(want.buffers()[1], np.int32)[0]
+        payload = np.asarray(column.data)
+        assert np.array_equal(payload[:w_off[-1]],
+                              w_data[first:first + w_off[-1]])
+        assert not payload[w_off[-1]:].any()            # and zero past it
+        validity = np.asarray(column.validity)
+        assert np.array_equal(validity[:n], np.asarray(want.is_valid()))
+        assert not validity[n:].any()
+        longest = int(np.diff(w_off).max()) if n else 0
+        assert column.max_bytes >= max(longest, 1)
+    flat = 0 if kind == "dict" else 3
+    assert counters.get("scanChunksByteArrayPlain", 0) == flat
+    assert counters["scanColumnChunksDecoded"] == 3
+    assert sum(counters.get(k, 0) for k in (
+        "scanChunksPlain", "scanChunksDictionary",
+        "scanChunksDictionaryThenPlain")) == 3
+    if kind == "dictplain":
+        assert counters["scanChunksDictionaryThenPlain"] == 3
+    assert counters["scanChunksNoNulls"] == (3 if nulls == "no_nulls" else 0)
+
+
+def test_text_chunks_are_named_by_what_they_decode(tmp_path, monkeypatch):
+    asked = _spy_on_programs(monkeypatch)
+    for kind, nulls in (("plain", "no_nulls"), ("plain", "nulls"),
+                        ("dictplain", "no_nulls"), ("dictplain", "nulls")):
+        path = str(tmp_path / f"{kind}_{nulls}.parquet")
+        pq.write_table(pa.table({"s": _texts(900, nulls)}), path,
+                       write_batch_size=64, **_TEXT_KINDS[kind][0])
+        _decode_text_groups(path)
+    assert [name for name, _, _ in asked] == [
+        "parquet_decode_string_plain_nn",
+        "parquet_decode_string_plain_place",
+        "parquet_decode_string_plain",
+        "parquet_decode_string_plain_place",
+        "parquet_decode_string_dictplain_nn",
+        "parquet_decode_string_plain_place",
+        "parquet_decode_string_dictplain",
+        "parquet_decode_string_plain_place"]
+
+
+def test_dictionaries_of_different_lengths_share_their_programs(tmp_path):
+    """Two files whose string chunks start on dictionaries of different
+    lengths run the same compiled programs: every shape is a bucket, the
+    true counts operands. Counted as JAX counts compiles."""
+    import jax
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    seen = []
+    for seed, limit in ((1, 6000), (2, 7000)):
+        path = str(tmp_path / f"seed{seed}.parquet")
+        pq.write_table(pa.table({"s": _texts(3000, "no_nulls", seed=seed)}),
+                       path, dictionary_pagesize_limit=limit,
+                       write_batch_size=64)
+        with open(path, "rb") as f:
+            md = pq.ParquetFile(path).metadata.row_group(0).column(0)
+            plan = PD.plan_column_chunk(
+                f, md, T.StructField("s", T.STRING, True), 1)
+        seen.append((plan.page_counts[0], plan.dict_count))
+        before = len(compiles)
+        (column, n, want), = _decode_text_groups(path)
+        assert column.to_arrow(n).equals(want)
+        if seed == 2:
+            assert len(compiles) == before, compiles[before:]
+    # the dictionaries differ in length (184 and 179 entries)
+    assert seen[0][0] != seen[1][0]
+
+
+def test_string_dictionary_page_after_plain_pages_is_refused(tmp_path,
+                                                             monkeypatch):
+    path = str(tmp_path / "text.parquet")
+    pq.write_table(pa.table({"s": _texts(2000, "no_nulls")}), path,
+                   dictionary_pagesize_limit=4096, data_page_size=512,
+                   write_batch_size=64)
+    real = PD._parse_page_header
+    seen = []
+
+    def reversed_encodings(buf, pos):
+        ph = real(buf, pos)
+        if ph.page_type == 0:
+            seen.append(ph.encoding)
+            ph.encoding = PD.PLAIN if len(seen) == 1 else PD.RLE_DICTIONARY
+        return ph
+    monkeypatch.setattr(PD, "_parse_page_header", reversed_encodings)
+    with pytest.raises(NotImplementedError,
+                       match="dictionary pages after PLAIN"):
+        _decode_text_groups(path)
