@@ -107,6 +107,15 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "Rows consumed, where host-known."),
     _spec("numInputBatches", MetricKind.SUM, MODERATE,
           "Batches consumed."),
+    _spec("aggMaskedSlotBatches", MetricKind.SUM, ESSENTIAL,
+          "Hash aggregate: input batches whose partial aggregation took "
+          "the masked-slot form — every grouping key a sorted-dictionary "
+          "column and at most ops/kernels/groupby.py _MASKED_SLOT_LIMIT "
+          "packed slots, so each slot's sum, min and max is one masked "
+          "reduction over the batch and nothing scatters (scope "
+          "masked_slot_reduce in agg_partial). Counted on the host from "
+          "the key columns' shapes as the batch is handed to the kernel; "
+          "batches with other keys or more slots are not counted."),
     _spec("spillBytes", MetricKind.SUM, ESSENTIAL,
           "Bytes pushed out of the device tier by the spill framework "
           "during the query (host + disk)."),

@@ -20,7 +20,7 @@ partial/final mode split (``aggregate.scala:259-450``).
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -107,17 +107,53 @@ def _minmax_reinstate_nan(res: jnp.ndarray, nan_cnt: jnp.ndarray,
                      res)
 
 
-#: Max packed-code group count for the direct-indexed fast path. Segment
-#: reductions at this width are a few KB of scatter targets — effectively
-#: free next to any 1M-row sort.
+#: Max packed-code group count for the direct-indexed path: the slot
+#: tables stay a few KB a lane and no grouping sort runs. (What the
+#: reductions into them cost is under ``_MASKED_SLOT_LIMIT``.)
 _DICT_GROUP_LIMIT = 4096
 
+#: Up to this many packed slots ``_dict_grouped_aggregate`` takes each
+#: slot's sum, min and max by one masked reduction over the batch; above
+#: it, by ``jax.ops.segment_*`` (a scatter). A scatter costs rows x lanes
+#: whatever the slot count, a masked reduction rows x lanes x slots. On a
+#: v5e, one 1 Mi-row batch of q1's 18 lanes (PR 36's microbench, PERF.md
+#: section 6): scatter 0.186-0.190 s at every count; masked 0.0035 s at 13
+#: segments, 0.0097 at 128, 0.032 at 512, 0.061 at 1,024, 0.119 at 2,048,
+#: 0.178 at 3,072, 0.238 at 4,096 — the two cross near 3,250. The cut
+#: sits at the last measured count with room to spare (37% under the
+#: scatter): a lane mix heavier in 64-bit min/max would cross earlier.
+_MASKED_SLOT_LIMIT = 2048
 
-#: Slot-table width for the dense/hash grouping fast paths. 2^21 slots of
-#: f64 are 16MB per reduction lane — cheap next to replacing a 1M-row
-#: ``lax.sort`` (~400ms on XLA:CPU, a full O(n log n) pass on TPU) with
-#: O(n) segment scatters (~4ms measured).
+
+#: Slot-table width for the dense grouping path. 2^21 slots of f64 are
+#: 16MB per reduction lane; the path trades the grouping ``lax.sort`` for
+#: O(n) segment scatters. On the v5e a scatter-add runs serially, 10 ns
+#: an update and lane (0.19 s for 1 Mi rows x 18 lanes, PR 36's
+#: microbench; q3's whole aggregate through this path reads 0.063 s a
+#: query, PR 34); the sort it replaces was not timed beside it.
 _DENSE_AGG_SLOTS = 1 << 21
+
+
+def _dict_slots(keys: Sequence[DeviceColumn]) -> Optional[int]:
+    """The packed slot count of the direct-indexed path — each key's
+    dictionary size plus its null slot, multiplied — when every key is a
+    sorted-dictionary column and the product fits ``_DICT_GROUP_LIMIT``;
+    else None. Static: shapes and pytree aux data only."""
+    if not all(k.is_dict and k.dict_sorted for k in keys):
+        return None
+    n_slots = 1
+    for k in keys:
+        n_slots *= k.dict_size + 1  # slot 0 = null
+    return n_slots if n_slots <= _DICT_GROUP_LIMIT else None
+
+
+def masked_slot_form(keys: Sequence[DeviceColumn]) -> bool:
+    """True when :func:`grouped_aggregate` reduces a batch with these keys
+    slot by slot with masked reductions and no scatter. It reads nothing
+    but shapes, so the executor asks it of a batch's abstract key columns
+    to count ``aggMaskedSlotBatches`` on the host."""
+    n_slots = _dict_slots(keys)
+    return n_slots is not None and n_slots <= _MASKED_SLOT_LIMIT
 
 
 def _dense_eligible(keys, inputs) -> bool:
@@ -361,16 +397,20 @@ def grouped_aggregate(keys: Sequence[DeviceColumn], live: jnp.ndarray,
     of the grouping sort; data-dependent fail -> escalate) -> the sort
     path below.
 
-    FAST PATH: when every key is a sorted-dictionary string column and the
-    packed code space is small (<= _DICT_GROUP_LIMIT), the group id IS the
-    packed code — no sort, no permutation, no 1M-wide scatters; every
-    reduction is one masked ``segment_*`` at dictionary width. This is the
-    kernel that runs TPC-H q1-style aggregations (a couple of categorical
-    keys over millions of rows) at memory bandwidth.
+    DIRECT-INDEXED PATH: when every key is a sorted-dictionary string
+    column and the packed code space is small (<= _DICT_GROUP_LIMIT), the
+    group id IS the packed code — no sort, no permutation. Up to
+    ``_MASKED_SLOT_LIMIT`` slots each slot's sum, min and max is one masked
+    reduction over the batch (TPC-H q1: 12 slots, 18 lanes, 1 Mi rows in
+    0.0046 s on a v5e against 0.186 s for the ``segment_*`` scatters it
+    replaced, PR 36); above it the reductions are ``segment_*`` at
+    dictionary width.
 
     Design constraints, in tension, both from this TPU toolchain:
-    * RUNTIME: sorts/gathers are full memory passes; scans and cumsums are
-      ~free; scatters cost ~60ms at 1M rows.
+    * RUNTIME: sorts/gathers are full memory passes; a scatter-add is a
+      serial loop, 10-13 ns an update and lane on a v5e whatever the
+      number of targets (ledger, PR 34; PR 36's microbench), 66 ns for a
+      64-bit ``.at[].set`` (PR 33).
     * COMPILE TIME: every ``lax.sort``/``associative_scan`` unrolls into
       hundreds of HLO stages; compile cost grows superlinearly with sort
       OPERAND COUNT (a 2-operand 1M sort compiles in ~20s, an 18-operand
@@ -384,13 +424,10 @@ def grouped_aggregate(keys: Sequence[DeviceColumn], live: jnp.ndarray,
     (key_columns, [(result[cap], counts[cap])], n_groups, group_live) as
     DENSE group rows (row g = group g).
     """
-    if all(k.is_dict and k.dict_sorted for k in keys):
-        n_slots = 1
-        for k in keys:
-            n_slots *= k.dict_size + 1  # slot 0 = null
-        if n_slots <= _DICT_GROUP_LIMIT:
-            return _dict_grouped_aggregate(keys, live, inputs, n_slots) \
-                + (False,)
+    n_slots = _dict_slots(keys)
+    if n_slots is not None:
+        return _dict_grouped_aggregate(keys, live, inputs, n_slots) \
+            + (False,)
     if dense_mode == 0 and _dense_eligible(keys, inputs):
         return _dense_int_aggregate(keys, live, inputs)
     return _sort_grouped_aggregate(keys, live, inputs) + (False,)
@@ -460,7 +497,8 @@ def _sort_grouped_aggregate(keys: Sequence[DeviceColumn],
     key_cols = [gather_column(k, orig_starts, group_live) for k in keys]
 
     # -- per-input reductions (shared dispatch; segment scatters are
-    # single-op HLO: cheap to compile, ~free at runtime). ------------------
+    # single-op HLO, cheap to compile; at run time the chip's serial
+    # loop: q13's two aggregates at 2 Mi rows 0.31 s each, PR 34). --------
     def seg(x, op="sum"):
         # One body serves both the 1-D and the lane-stacked 2-D case
         # (segment_* is rank-agnostic here).
@@ -501,8 +539,29 @@ def _dict_grouped_aggregate(keys: Sequence[DeviceColumn],
         gid = gid * (k.dict_size + 1) + slot
     gid = jnp.where(live, gid, n_slots)  # dead rows land in a spare slot
 
-    rows_per_slot = jax.ops.segment_sum(live.astype(jnp.int32), gid,
-                                        num_segments=n_slots + 1)[:n_slots]
+    masked = n_slots <= _MASKED_SLOT_LIMIT
+    slot_ids = jnp.arange(n_slots, dtype=jnp.int32)
+
+    def slot_reduce(x, op):
+        """Row lanes to slot rows ``[n_slots, ...]``; an empty slot reads
+        the op's neutral element in either form (0, the dtype's greatest,
+        its least). The masked form takes one lane ``[rows]``: XLA fuses
+        the ``[slots, rows]`` compare into the reduction and never writes
+        it; a dead row's spare slot hits none."""
+        if not masked:
+            f = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+                 "max": jax.ops.segment_max}[op]
+            return f(x, gid, num_segments=n_slots + 1)[:n_slots]
+        neutral = {"sum": jnp.zeros((), x.dtype), "min": _max_value(x.dtype),
+                   "max": _min_value(x.dtype)}[op]
+        with jax.named_scope("masked_slot_reduce"):
+            hit = gid[None, :] == slot_ids[:, None]
+            lanes = jnp.where(hit, x[None, :], neutral)
+            if op == "sum":
+                return jnp.sum(lanes, axis=1, dtype=x.dtype)
+            return (jnp.min if op == "min" else jnp.max)(lanes, axis=1)
+
+    rows_per_slot = slot_reduce(live.astype(jnp.int32), "sum")
     occupied = rows_per_slot > 0
     n_groups = jnp.sum(occupied.astype(jnp.int32))
     # Compact occupied slots to the front, preserving packed (= sorted key)
@@ -535,16 +594,18 @@ def _dict_grouped_aggregate(keys: Sequence[DeviceColumn],
             dict_sorted=k.dict_sorted))
 
     def seg(x, op="sum"):
-        f = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
-             "max": jax.ops.segment_max}[op]
-        full = f(x, gid, num_segments=n_slots + 1)[:n_slots]
-        dense = jnp.pad(full, (0, pad))[slot_of_group]
-        return dense
+        return jnp.pad(slot_reduce(x, op), (0, pad))[slot_of_group]
 
     def seg_many(m, op="sum"):
-        f = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
-             "max": jax.ops.segment_max}[op]
-        full = f(m, gid, num_segments=n_slots + 1)[:n_slots]
+        if masked:
+            # A reduction a lane (XLA reads the lanes out of the stack's
+            # operands and builds neither it nor the compare: 0 bytes of
+            # temporaries, and 0.0035 s for 0.0049 s stacked at q1's
+            # shape, PR 36); one pad and one gather for them all.
+            full = jnp.stack([slot_reduce(m[:, j], op)
+                              for j in range(m.shape[1])], axis=1)
+        else:
+            full = slot_reduce(m, op)
         return jnp.pad(full, ((0, pad), (0, 0)))[slot_of_group]
 
     def post(x):

@@ -406,17 +406,36 @@ def test_join_match_sorted_build_ranges(case):
 
 # -- segment reductions through the sort path and the packed-dictionary path -
 
-#: q1's shape: two sorted-dictionary keys, (3 + null) x (2 + null) = 12
-#: slots and the spare one dead rows land in — 13 segments.
-_DICT_KEYS = (["A", "N", "R"], ["F", "O"])
-_DICT_SLOTS = 12
+#: Sorted-dictionary key pairs by path. ``dict13`` is q1's shape: (3 + null)
+#: x (2 + null) = 12 slots and the spare one dead rows land in, 13 segments,
+#: reduced slot by slot with masked reductions, as are ``dict512``'s (63 +
+#: null) x (7 + null) = 512. ``dict3072`` is (63 + null) x (47 + null) =
+#: 3,072 slots: above ``_MASKED_SLOT_LIMIT``, the scatter form.
+_DICT_KEYS = {
+    "dict13": (["A", "N", "R"], ["F", "O"]),
+    "dict512": ([f"k{i:02d}" for i in range(63)],
+                [f"s{i}" for i in range(7)]),
+    "dict3072": ([f"k{i:02d}" for i in range(63)],
+                 [f"s{i:02d}" for i in range(47)]),
+}
 
 
-def _dict_key_columns(slots):
-    """Two sorted-dictionary string columns whose packed slot is ``slots``
-    (slot = (code1 + 1 | 0 for null) * 3 + (code2 + 1 | 0 for null))."""
+def _dict_slots(path):
+    first, second = _DICT_KEYS[path]
+    return (len(first) + 1) * (len(second) + 1)
+
+
+def _dict_key_parts(path, slots):
+    """Per key, each row's entry number + 1 (0 = null) out of its packed
+    slot (slot = part1 * (len(entries2) + 1) + part2)."""
+    radix = len(_DICT_KEYS[path][1]) + 1
+    return slots // radix, slots % radix
+
+
+def _dict_key_columns(path, slots):
+    """Two sorted-dictionary string columns whose packed slot is ``slots``."""
     cols = []
-    for part, entries in zip((slots // 3, slots % 3), _DICT_KEYS):
+    for part, entries in zip(_dict_key_parts(path, slots), _DICT_KEYS[path]):
         vals = [None if p == 0 else entries[p - 1] for p in part]
         col = DeviceColumn.dict_string_from_arrow(
             pa.array(vals + entries, pa.string()), len(vals) + len(entries))
@@ -428,22 +447,28 @@ def _dict_key_columns(slots):
 def _grouping(path, spec, n, rng):
     """(key columns, group label per row, n) for one path: labels order
     as the path's output groups do (nulls first, then ascending)."""
-    if path == "dict13":
+    if path != "sort":
+        n_slots = _dict_slots(path)
+        radix = len(_DICT_KEYS[path][1]) + 1
         if spec == "own":
-            n = _DICT_SLOTS
-            slots = rng.permutation(_DICT_SLOTS)
+            n = n_slots
+            slots = rng.permutation(n_slots)
         elif spec == "one":
             slots = np.full(n, 7)
+        elif spec == "null-keys":       # every row null in one key or both
+            slots = np.where(rng.random(n) < 0.5,
+                             rng.integers(0, radix, n),
+                             rng.integers(0, n_slots // radix, n) * radix)
         else:
-            slots = rng.integers(0, _DICT_SLOTS, n)
-        return _dict_key_columns(slots), slots.astype(np.int64), n
+            slots = rng.integers(0, n_slots, n)
+        return _dict_key_columns(path, slots), slots.astype(np.int64), n
     if spec == "own":
         keys, valid = rng.permutation(n).astype(np.int64), np.ones(n, bool)
     elif spec == "one":
         keys, valid = np.full(n, 5, np.int64), np.ones(n, bool)
     else:
         keys = rng.integers(-n // 20, n // 20, n)
-        valid = rng.random(n) >= 0.05
+        valid = rng.random(n) >= (0.5 if spec == "null-keys" else 0.05)
     col = DeviceColumn.from_numpy(keys, valid, T.INT, n)
     return [col], np.where(valid, keys, np.iinfo(np.int64).min), n
 
@@ -456,42 +481,73 @@ _REDUCE_CASES = (
     + [pytest.param(("own", 256, "int64", "sum", 5), id="2d-lanes-own-group"),
        pytest.param(("one", 512, "int64", "sum", 1), id="one-group"),
        pytest.param(("one", 1, "int64", "sum", 1), id="one-row"),
-       pytest.param(("random", 128, "int64", "sum", 1, True), id="empty")])
+       pytest.param(("random", 128, "int64", "sum", 1, True), id="empty")]
+    # first / last read positions out of the segment min / max
+    + [pytest.param(("random", 1024, dt, op, 2), id=f"{dt}-{op}")
+       for dt in ("int64", "float64") for op in ("first", "last")]
+    # Spark's NaN: greatest in a max, a min's answer only when alone
+    + [pytest.param(("random", 512, "float64nan", op, 2), id=f"nan-{op}")
+       for op in ("min", "max")]
+    + [pytest.param(("null-keys", 1024, "int64", "sum", 2), id="null-keys")]
+    + [pytest.param(("dead-slot", 1024, "int64", op, 1), id=f"dead-slot-{op}")
+       for op in ("sum", "min")])
+
+
+def _reduce_reference(op, v):
+    """One group's answer over its contributing values, as Spark has it."""
+    if op == "first":
+        return v[0]
+    if op == "last":
+        return v[-1]
+    if op == "max" or not np.isnan(v).any():        # np.max carries a NaN
+        return {"sum": np.sum, "min": np.min, "max": np.max}[op](v)
+    return np.nan if np.isnan(v).all() else np.nanmin(v)
 
 
 @pytest.mark.parametrize("case", _REDUCE_CASES)
-@pytest.mark.parametrize("path", ["sort", "dict13"])
+@pytest.mark.parametrize("path", ["sort", "dict13", "dict512", "dict3072"])
 def test_grouped_reductions_match_numpy(path, case):
-    """``jax.ops.segment_{sum,min,max}`` as the two grouping paths call
-    them — 1-D lanes and the (kind, dtype)-stacked 2-D lanes — against a
-    loop over the groups in numpy: group order, keys, counts, results."""
+    """The grouping paths' slot reductions — the sort path's and the wide
+    dictionary's ``jax.ops.segment_{sum,min,max}``, the narrow dictionary's
+    masked reductions; 1-D lanes and the (kind, dtype)-stacked 2-D lanes —
+    against a loop over the groups in numpy: group order, keys, counts,
+    results."""
     spec, n, dtype, op, lanes = case[:5]
     empty = len(case) > 5
     rng = np.random.default_rng(
         sum(map(ord, f"{path}{spec}{n}{dtype}{op}")))
     keys, labels, n = _grouping(path, spec, n, rng)
     live = np.zeros(n, bool) if empty else rng.random(n) >= 0.1
-    if spec != "random":
+    if spec in ("own", "one"):
         live[:] = True
+    elif spec == "dead-slot":           # one group's rows are all dead
+        live &= labels != labels[0]
     vals, valids = [], []
     for _ in range(lanes):
-        if dtype == "float64":
-            vals.append(rng.standard_normal(n))
+        if dtype.startswith("float64"):
+            v = rng.standard_normal(n)
+            if dtype == "float64nan":
+                v[rng.random(n) < 0.3] = np.nan
+                v[labels == labels[1]] = np.nan     # a group of NaN alone
+            vals.append(v)
         else:
             vals.append(rng.integers(-10**6, 10**6, n).astype(dtype))
-        valids.append(rng.random(n) >= (0.1 if spec == "random" else 0.0))
+        valids.append(rng.random(n) >= (0.0 if spec in ("own", "one")
+                                        else 0.1))
     inputs = [(jnp.asarray(v), jnp.asarray(ok), op)
               for v, ok in zip(vals, valids)]
     if path == "sort":
         key_cols, results, n_groups, group_live = \
             G._sort_grouped_aggregate(keys, jnp.asarray(live), inputs)
     else:
-        key_cols, results, n_groups, group_live = \
-            G._dict_grouped_aggregate(keys, jnp.asarray(live), inputs,
-                                      _DICT_SLOTS)
+        assert G.masked_slot_form(keys) == (path != "dict3072")
+        key_cols, results, n_groups, group_live, fail = \
+            G.grouped_aggregate(keys, jnp.asarray(live), inputs)
+        assert fail is False
     groups = sorted(set(labels[live].tolist()))
     g = len(groups)
     assert int(n_groups) == g
+    assert spec != "dead-slot" or labels[0] not in groups
     assert np.asarray(group_live).tolist() == \
         [True] * g + [False] * (len(np.asarray(group_live)) - g)
     # the keys of each output group
@@ -501,11 +557,12 @@ def test_grouped_reductions_match_numpy(path, case):
             [None if k == null else k for k in groups]
     else:
         want = [[None if p == 0 else e[p - 1] for p in part]
-                for part, e in zip((np.asarray(groups, np.int64) // 3,
-                                    np.asarray(groups, np.int64) % 3),
-                                   _DICT_KEYS)]
+                for part, e in zip(
+                    _dict_key_parts(path, np.asarray(groups, np.int64)),
+                    _DICT_KEYS[path])]
         assert [c.to_arrow(g).to_pylist() for c in key_cols] == want
-    f = {"sum": np.sum, "min": np.min, "max": np.max}[op]
+        assert spec != "null-keys" or all(None in pair
+                                          for pair in zip(*want))
     for (res, cnt), v, ok in zip(results, vals, valids):
         res, cnt = np.asarray(res), np.asarray(cnt)
         assert res.dtype == v.dtype
@@ -514,13 +571,54 @@ def test_grouped_reductions_match_numpy(path, case):
             assert cnt[i] == rows.sum()
             if not rows.any():
                 continue
-            want = np.asarray(f(v[rows])).astype(v.dtype)
+            want = np.asarray(_reduce_reference(op, v[rows])).astype(v.dtype)
             if dtype == "float64" and op == "sum":
                 np.testing.assert_allclose(res[i], want,
                                            rtol=1e-12, atol=1e-12)
+            elif np.isnan(want):
+                assert np.isnan(res[i])
             else:                           # bit for bit, floats too
                 assert res[i].tobytes() == want.tobytes()
         assert not cnt[g:].any() and not res[g:].any()
+
+
+@pytest.mark.parametrize("shape", ["q1_13_slots", "above_the_cut"])
+def test_dict_grouped_aggregate_lowers_without_scatter(shape):
+    """The mechanism's counter, as ``concat_batches`` has its own below: at
+    q1's shape — 13 segments, float64 sums, int64 counts, a min and a max,
+    1 Mi rows — no scatter is lowered, the slot rows come out of ``reduce``
+    operations under the ``masked_slot_reduce`` scope; a dictionary above
+    ``_MASKED_SLOT_LIMIT`` still scatters. Lowered from shapes, nothing
+    runs."""
+    cap = 1 << 20
+    sizes = (3, 2) if shape == "q1_13_slots" else (G._MASKED_SLOT_LIMIT,)
+    lane = lambda dt, n=cap: jax.ShapeDtypeStruct((n,), dt)
+    keys = [DeviceColumn(lane(np.uint8, size), lane(np.bool_), T.STRING,
+                         offsets=lane(np.int32, size + 1), max_bytes=1,
+                         codes=lane(np.int32), dict_sorted=True)
+            for size in sizes]
+    assert G.masked_slot_form(keys) == (shape == "q1_13_slots")
+
+    def aggregate(keys, live, doubles, longs, oks):
+        inputs = [(v, ok, "sum") for v, ok in zip(doubles, oks)] \
+            + [(v, ok, op) for v, ok, op in zip(longs, oks, ("count", "min",
+                                                              "max"))]
+        return G.grouped_aggregate(keys, live, inputs)[:4]
+    lowered = jax.jit(aggregate).lower(
+        keys, lane(np.bool_), [lane(np.float64)] * 7, [lane(np.int64)] * 3,
+        [lane(np.bool_)] * 7)
+    text = lowered.as_text(dialect="hlo")
+    scatters = re.findall(r"= (\w+\[[\d,]*\])\S* scatter\(", text)
+    reduces = re.findall(r"= (\w+\[12\])\S* reduce\(", text)
+    scoped = "masked_slot_reduce" in lowered.as_text(debug_info=True)
+    if shape == "q1_13_slots":
+        # a reduction a lane: rows_per_slot; the doubles' sums; ten
+        # counts, a min and a max
+        assert sorted(reduces) == ["f64[12]"] * 7 + ["s32[12]"] \
+            + ["s64[12]"] * 12
+        assert scatters == [] and scoped
+    else:
+        assert len(scatters) == 5 and not scoped
 
 
 # -- the stable sort ----------------------------------------------------------

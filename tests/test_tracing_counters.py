@@ -285,6 +285,30 @@ def test_scan_counters_of_a_small_q6(tmp_path):
     assert {"hbmBytesInUse", "hbmPeakBytesInUse"} <= set(again.engine)
 
 
+@pytest.mark.parametrize("key,sort,want", [
+    ("l_returnflag", True, 3), ("l_orderkey", True, 0),
+    ("l_returnflag", False, 0)],
+    ids=["dictionary-key", "integer-key", "inlined-in-fused-program"])
+def test_masked_slot_batches_counted_where_the_host_hands_them_over(
+        tmp_path, key, sort, want):
+    """``aggMaskedSlotBatches``: one per batch the host hands to
+    ``agg_partial`` with sorted-dictionary keys of few slots (a sort above
+    the aggregate is a fusion boundary, so its subtree streams); none for
+    an integer key, none where the aggregate is inlined in a fused
+    program."""
+    path, table = _lineitem(tmp_path)
+    session = TpuSession(DEVICE)
+    df = session.read.parquet(path).group_by(col(key)).agg(
+        A.AggregateExpression(A.Sum(col("l_quantity")), "q"),
+        A.AggregateExpression(A.Count(), "n"))
+    got = (df.sort(col(key)) if sort else df).collect()
+    assert sum(got.column("n").to_pylist()) == table.num_rows
+    assert got.column("q").to_numpy().sum() == pytest.approx(
+        table["l_quantity"].to_numpy().sum(), rel=1e-12)
+    totals = session.last_query_profile().totals()
+    assert totals.get("aggMaskedSlotBatches", 0) == want
+
+
 def test_join_over_its_capacity_runs_the_plan_twice():
     n, dup = 600, 4
     session = TpuSession(dict(DEVICE, **{
