@@ -16,10 +16,11 @@ made from ``--seed`` by the generator and written to parquet, one
 ``TpuSession``, the loader (files as they are, or ``cache()``), and every
 query of the mix once. Window: one client in a closed loop, the mix's
 queries in turn through ``DataFrame.collect()``; a new query starts while
-less than ``--seconds`` have passed. After it: the device's peak memory,
-the session closed, the plain reference over the same files and the
-comparison that decides ``correct``. The last line of stdout is the result,
-printed only on a TPU that ``peaks.json`` knows.
+less than ``--seconds`` have passed. The host's counters are read at its
+two ends. After it: the device's peak memory, the session closed, the plain
+reference over the same files and the comparison that decides ``correct``.
+The last line of stdout is the result, printed only on a TPU that
+``peaks.json`` knows.
 """
 
 import time
@@ -32,6 +33,7 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import random  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
@@ -113,6 +115,38 @@ def schedule(mix: list, order: str, seed: int):
         if order == "shuffled":
             rng.shuffle(round_)
         yield from round_
+
+
+def host_reading() -> dict:
+    """What the host's kernel counts, read at one end of the window: the
+    load averages, the jiffies of all CPUs by state (``/proc/stat``) and
+    this process's own CPU seconds and context switches."""
+    with open("/proc/loadavg") as f:
+        load = [float(x) for x in f.read().split()[:3]]
+    with open("/proc/stat") as f:
+        jiffies = [int(x) for x in f.readline().split()[1:]]
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    return {"loadavg": load, "jiffies": jiffies,
+            "cpu_s": usage.ru_utime + usage.ru_stime,
+            "nvcsw": usage.ru_nvcsw, "nivcsw": usage.ru_nivcsw}
+
+
+def host_of(before: dict, after: dict) -> dict:
+    """The result line's ``host`` object, from the readings at the window's
+    two ends: what else the machine was doing while the window ran."""
+    # /proc/stat: user nice system idle iowait irq softirq steal [guest ...]
+    spent = [b - a for a, b in zip(before["jiffies"], after["jiffies"])][:8]
+    spent += [0] * (8 - len(spent))
+    total = sum(spent) or 1
+    return {"cpu_count": os.cpu_count(),
+            "affinity": len(os.sched_getaffinity(0)),
+            "loadavg_before": before["loadavg"],
+            "loadavg_after": after["loadavg"],
+            "steal_share": spent[7] / total,
+            "busy_share": (total - spent[3] - spent[4]) / total,
+            "process_cpu_s": after["cpu_s"] - before["cpu_s"],
+            "nvcsw": after["nvcsw"] - before["nvcsw"],
+            "nivcsw": after["nivcsw"] - before["nivcsw"]}
 
 
 def counters_of(profile) -> dict:
@@ -224,6 +258,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     turns = schedule(cell["mix"], traffic.get("order", "round_robin"), seed)
     answers, done, latencies, counters, traced = [], [], [], {}, []
     attempted = failed = 0
+    host_before = host_reading()
     w0 = time.perf_counter()
     setup_s = w0 - T_START
     while True:
@@ -253,10 +288,11 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         if time.perf_counter() - w0 >= seconds:
             break
     w1 = t2
+    host = host_of(host_before, host_reading())
     if tracing:
         jax.profiler.stop_trace()
     say(f"setup_s {setup_s:.3f} latencies "
-        + " ".join(f"{x:.3f}" for x in latencies))
+        + " ".join(f"{x:.4f}" for x in latencies))
 
     device = jax.devices()[0]
     memory_peak = (device.memory_stats() or {}).get("peak_bytes_in_use", 0)
@@ -284,7 +320,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         "row_groups": row_groups, "setup_s": setup_s, "phases": phases,
         "attempted": attempted, "failed": failed,
         "completed": attempted - failed, "latencies": latencies,
-        "window_s": w1 - w0, "first": [e["query"] for e in cell["mix"]],
+        "window_s": w1 - w0, "host": host,
+        "first": [e["query"] for e in cell["mix"]],
         "done": done, "setup_counters": setup_counters,
         "counters": counters, "traced_queries": traced, "trace": reduced,
         "compile_events": [(t - w0, secs) for t, secs in compiles],
@@ -330,6 +367,7 @@ def result_line(run: dict, trace: bool, device: dict, peaks: dict) -> dict:
         device["window_s"] = run["trace"]["window_s"]
         line["breakdown"] = {"device_ops": run["trace"]["device_ops"],
                              "idle_gaps": run["trace"]["idle_gaps"]}
+    line["host"] = run["host"]
     line["scale"] = run["scale"]    # 1.0: the cell's own size
     line["compared"] = run["compared"]
     return line

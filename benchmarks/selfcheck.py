@@ -12,7 +12,9 @@ line.
 - an answer altered where it is produced (a float moved by 1e-6 of itself,
   a key changed, a row dropped) makes ``correct`` false, and so does a row
   group read on the host by a query of set-up;
-- the float32 control comes out as not correct;
+- the float32 control comes out as not correct, in every cell whose answer
+  holds a floating-point column (an answer of counts alone has nothing to
+  round: the altered key and the dropped row hold it);
 - a mix that gives a query its parameters is held to each entry's own
   reference;
 - ``trace_reduce`` gives the hand-checked numbers for the recorded trace
@@ -39,7 +41,9 @@ FAULTS = ("float", "key", "row", "setup_host_read")
 
 # cells that wait for a later PR (PERF.md, Open questions) but whose files
 # are here: held to the same checks, so that an entry is all they need
-WAITING = {"tpch_sf1_parquet.q3": ("tpch_sf1_parquet", "q3")}
+WAITING = {"tpch_sf1_cached.q3": ("tpch_sf1_cached", "q3"),
+           "tpch_sf1_parquet_writer_defaults.q1":
+               ("tpch_sf1_parquet_writer_defaults", "q1")}
 
 
 def cells():
@@ -161,6 +165,11 @@ def check_params() -> None:
 def check_control(name: str) -> None:
     correct, compared = control.control_reading(load_cell(name), seed=7,
                                                 scale=SCALE)
+    if correct is None:
+        print(f"selfcheck {name}: the float32 control finds no "
+              "floating-point column in the answer; its exact cells are "
+              "held by the faults 'key' and 'row'")
+        return
     if correct:
         raise AssertionError(f"{name}: the float32 control passed: {compared}")
     print(f"selfcheck {name}: float32 control -> correct false, {compared}")
