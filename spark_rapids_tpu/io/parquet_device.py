@@ -48,6 +48,7 @@ from __future__ import annotations
 import dataclasses
 import datetime as _dt
 import struct as _struct
+import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -278,6 +279,8 @@ class ColumnChunkPlan:
     text_src: Optional[np.ndarray] = None
     page_starts: Optional[List[int]] = None
     page_counts: Optional[List[int]] = None
+    # host nanoseconds spent parsing the definition levels
+    def_level_ns: int = 0
 
 
 def _decompress(codec: str, payload: bytes, uncompressed_size: int) -> bytes:
@@ -322,6 +325,7 @@ def plan_column_chunk(f, col_md, field: T.StructField,
     n_rows = 0
     dict_count = 0
     has_nulls = False
+    def_level_ns = 0
     uses_dict = False
     uses_plain = False
     while pos < len(chunk):
@@ -341,12 +345,14 @@ def plan_column_chunk(f, col_md, field: T.StructField,
         if max_def_level > 0:
             if ph.def_encoding != RLE:
                 raise NotImplementedError("non-RLE definition levels")
+            t0 = time.perf_counter_ns()
             (def_len,) = _struct.unpack_from("<I", payload, p)
             p += 4
             _parse_hybrid(payload, p, p + def_len, 1, ph.num_values,
                           def_runs, packed)
             p += def_len
             non_null = def_runs.non_null_count(page_def_start, packed)
+            def_level_ns += time.perf_counter_ns() - t0
         else:
             def_runs.kinds.append(1)
             def_runs.counts.append(ph.num_values)
@@ -390,7 +396,8 @@ def plan_column_chunk(f, col_md, field: T.StructField,
         idx_runs=idx_runs if uses_dict else None,
         idx_bit_width=idx_bw, packed=bytes(packed),
         plain_values=None, dict_count=dict_count, dict_values=None,
-        dict_rank=None, dict_offsets=None, dict_payload=None)
+        dict_rank=None, dict_offsets=None, dict_payload=None,
+        def_level_ns=def_level_ns)
 
     if text_plain:
         # the values' bytes as the pages hold them; finding each value in
@@ -558,14 +565,17 @@ def _count(counters: Optional[dict], name: str, value: int) -> None:
         counters[name] = counters.get(name, 0) + value
 
 
+def _nbytes(host) -> int:
+    return sum(a.nbytes for a in jax.tree_util.tree_leaves(host))
+
+
 def _count_chunk(counters: Optional[dict], plan: "ColumnChunkPlan",
                  kinds, host, upload_ns: int, launch_ns: int) -> None:
     """One decoded chunk into the scan's counters: the upload's and the
     launch's host nanoseconds, the bytes uploaded, and the chunk under
     each of ``kinds`` (what its pages hold)."""
     _count(counters, "scanUploadNs", upload_ns)
-    _count(counters, "uploadBytes",
-           sum(a.nbytes for a in jax.tree_util.tree_leaves(host)))
+    _count(counters, "uploadBytes", _nbytes(host))
     _count(counters, "scanLaunchNs", launch_ns)
     _count(counters, "scanColumnChunksDecoded", 1)
     for kind in kinds:
@@ -854,7 +864,6 @@ def _decode_text_chunk(plan: ColumnChunkPlan, capacity: int,
     rows program, then — the one wait of a chunk's decode, for two numbers
     — the text's bytes and the longest value back on the host, which size
     the payload's bucket and the column's ``max_bytes``, then the copy."""
-    import time
     has_idx = plan.idx_runs is not None
     kind, counter = (("dictplain", "scanChunksDictionaryThenPlain")
                      if has_idx else ("plain", "scanChunksPlain"))
@@ -884,7 +893,6 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int,
     ``counters`` (the scan's, one dict per row group) takes the upload's
     and the launch's host nanoseconds, the bytes uploaded and the chunk
     itself: three clock reads a chunk, nothing per row."""
-    import time
     if plan.text_src is not None:
         return _decode_text_chunk(plan, capacity, counters)
     dict_string = plan.dict_rank is not None
@@ -930,6 +938,8 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int,
         else (dev["plain"], out)
     t2 = time.perf_counter_ns()
     _count_chunk(counters, plan, (counter,), host, t1 - t0, t2 - t1)
+    if plan.has_nulls:
+        _count(counters, "scanNullableUploadBytes", _nbytes(host))
     if dict_string:
         max_bytes = 8
         if plan.dict_offsets is not None and len(plan.dict_offsets) > 1:
@@ -950,8 +960,9 @@ def decode_row_group(path: str, row_group: int, schema: T.Schema,
     ``(meta, pq_schema)`` to amortize the footer parse across a file's
     row groups (metadata objects hold no file descriptor). ``counters``
     is added to in place: ``scanParseNs`` here (file read, page headers,
-    decompression, run tables), the rest in :func:`decode_chunk`."""
-    import time
+    decompression, run tables) and, of a column nullable in the file, the
+    part of it that parsed definition levels (``scanDefLevelNs``), the
+    rest in :func:`decode_chunk`."""
     import pyarrow.parquet as pq
     if meta is None:
         if pf is None:
@@ -971,6 +982,8 @@ def decode_row_group(path: str, row_group: int, schema: T.Schema,
                 f, md.column(ci), field,
                 pq_schema.column(ci).max_definition_level)
             _count(counters, "scanParseNs", time.perf_counter_ns() - t0)
+            if pq_schema.column(ci).max_definition_level > 0:
+                _count(counters, "scanDefLevelNs", plan.def_level_ns)
             cols.append(decode_chunk(plan, capacity, counters))
     return ColumnarBatch(tuple(cols), jnp.asarray(n_rows, jnp.int32),
                          schema)

@@ -200,6 +200,18 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "definition-level table, the prefix sum and the gather through "
           "slots (parquet_decode_*_nn programs); a chunk with a null "
           "takes the nullable program of its kind."),
+    _spec("scanDefLevelNs", MetricKind.NANO_TIMING, ESSENTIAL,
+          "Device parquet scan: the part of scanParseNs spent parsing "
+          "the definition levels of column chunks whose column is "
+          "nullable in the file and counting their non-null values "
+          "(io/parquet_device.py plan_column_chunk). Thread-seconds "
+          "summed over the decode producers."),
+    _spec("scanNullableUploadBytes", MetricKind.SUM, ESSENTIAL,
+          "Device parquet scan: the part of uploadBytes that belongs to "
+          "fixed-width or dictionary-string column chunks in which a "
+          "page held a null, decoded by the nullable program of their "
+          "kind (parquet_decode_*_bw<n>_*: definition levels, prefix "
+          "sum to slots, the slot or dictionary gather)."),
     _spec("planRuns", MetricKind.SUM, ESSENTIAL,
           "Runs of the plan behind one collect()/cache(): 1, plus "
           "join-capacity re-runs and dispatch retries (TpuSession node; "
