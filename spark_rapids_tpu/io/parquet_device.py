@@ -11,14 +11,15 @@ TPU-native split here:
   are sliced into RUN TABLES — (kind, count, value | bit offset) per run —
   without expanding a single value.
 * DEVICE (data-sized work): one traced kernel expands the run tables —
-  ``searchsorted`` over run ends finds each output's run, RLE runs
-  broadcast their value, bit-packed runs gather+shift+mask straight from
-  the uploaded page bytes — then definition levels become the validity
-  mask and dictionary indices scatter into row order. Everything is
-  vectorized; no per-value host loop anywhere. A chunk in which no page
-  holds a null (the host sees that in the pages) skips the definition
-  levels, the row -> slot prefix sum and the slot gather: row i is value
-  i, and a PLAIN chunk is its uploaded buffer.
+  each run's step of (bit offset, width, RLE value) is scattered once to
+  its first row and a prefix sum carries it to the run's other rows; RLE
+  rows take their value, bit-packed rows two 32-bit words of the
+  uploaded page bytes, shifted and masked — then definition levels become
+  the validity mask and dictionary indices scatter into row order.
+  Everything is vectorized; no per-value host loop anywhere. A chunk in
+  which no page holds a null (the host sees that in the pages) skips the
+  definition levels, the row -> slot prefix sum and the slot gather: row
+  i is value i, and a PLAIN chunk is its uploaded buffer.
 
 Parquet dictionaries pair perfectly with this engine's dict-encoded string
 columns: the page dictionary IS the column dictionary. The host sorts the
@@ -452,36 +453,66 @@ def plan_column_chunk(f, col_md, field: T.StructField,
 
 # -- device expansion kernels -----------------------------------------------
 
+#: lanes a block in the first level of :func:`_prefix_sum`
+_SCAN_BLOCK = 1024
+
+
+def _prefix_sum(x):
+    """Inclusive prefix sum of an int32 vector (traced), in two levels:
+    within blocks of ``_SCAN_BLOCK`` lanes, then over the blocks' totals;
+    int32 adds wrap, so any order gives ``jnp.cumsum``'s values. The v5e
+    compiler takes ~18 s over a 1 Mi-row ``jnp.cumsum`` (one reduce window
+    as long as the vector) and under a second over the two levels."""
+    n = x.shape[0]
+    blocks = jnp.cumsum(
+        jnp.pad(x, (0, -n % _SCAN_BLOCK)).reshape(-1, _SCAN_BLOCK), axis=1)
+    totals = blocks[:, -1]
+    return (blocks + (jnp.cumsum(totals) - totals)[:, None]).reshape(-1)[:n]
+
 
 def _expand_hybrid(kinds, counts, values, bit_starts, widths, packed,
                    capacity):
     """Expand a hybrid run table to ``capacity`` int32 values (traced).
 
-    For output i: its run via searchsorted over cumulative counts; RLE runs
-    broadcast, bit-packed runs gather 4 bytes around the value's bit
-    position and shift/mask. Widths are per RUN (a dictionary's bit width
-    grows across pages as it fills; <= 24, so shift <= 7 + width <= 24
-    keeps every value inside the 4 gathered bytes)."""
+    Output ``i`` of run ``r`` is ``V[r]`` plus the ``w[r]`` bits of
+    ``packed`` at bit ``A[r] + i * w[r]``: an RLE run has ``w = A = 0``
+    and its value as ``V``; a bit-packed run ``V = 0``, its width and
+    ``A = bit_start - first_row * w``. Each run's step of ``(A, w, V)``
+    from the run before is scattered once to its first row and a prefix
+    sum carries it to the run's other rows — no search and no gather of
+    the run table a row. Runs of count 0 (padding among them) add up to
+    their successor's step, and a start at ``capacity`` is dropped. Widths
+    are per RUN (a dictionary's bit width grows across pages as it fills;
+    <= 24, so shift <= 31 + width keeps every value inside the two 32-bit
+    words gathered)."""
     with jax.named_scope("expand_hybrid"):
-        ends = jnp.cumsum(counts)
-        starts = ends - counts
-        i = jnp.arange(capacity, dtype=jnp.int32)
-        r = jnp.searchsorted(ends, i, side="right")
-        r = jnp.clip(r, 0, kinds.shape[0] - 1)
-        within = i - starts[r]
-        w = widths[r]
-        bit0 = bit_starts[r] + within * w
+        starts = jnp.cumsum(counts) - counts
+        rle = kinds == 1
+        zero = jnp.zeros((), jnp.int32)
+
+        def carried(per_run):
+            step = per_run - jnp.concatenate([zero[None], per_run[:-1]])
+            marks = jnp.zeros(capacity, jnp.int32).at[starts].add(
+                step, mode="drop", indices_are_sorted=True)
+            return _prefix_sum(marks)
+        a = carried(jnp.where(rle, zero, bit_starts - starts * widths))
+        w = carried(jnp.where(rle, zero, widths))
+        v = carried(jnp.where(rle, values, zero))
+        bit0 = a + jnp.arange(capacity, dtype=jnp.int32) * w
     with jax.named_scope("unpack"):
-        byte0 = bit0 >> 3
-        shift = (bit0 & 7).astype(jnp.uint32)
-        nb = packed.shape[0]
-        b = [packed[jnp.clip(byte0 + k, 0, nb - 1)].astype(jnp.uint32)
-             for k in range(4)]
-        word = b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24)
-        mask = (jnp.uint32(1) << jnp.clip(w, 0, 31).astype(jnp.uint32)) \
-            - jnp.uint32(1)
-        packed_val = ((word >> shift) & mask).astype(jnp.int32)
-        return jnp.where(kinds[r] == 1, values[r], packed_val)
+        # the buffer as little-endian 32-bit words: two gathers a value
+        words = jax.lax.bitcast_convert_type(
+            jnp.pad(packed, (0, -packed.shape[0] % 4)).reshape(-1, 4),
+            jnp.uint32)
+        word = bit0 >> 5
+        last = words.shape[0] - 1
+        shift = (bit0 & 31).astype(jnp.uint32)
+        low = words[jnp.clip(word, 0, last)] >> shift
+        high = jnp.where(shift == 0, jnp.uint32(0),
+                         words[jnp.clip(word + 1, 0, last)]
+                         << (jnp.uint32(32) - shift))
+        mask = (jnp.uint32(1) << w.astype(jnp.uint32)) - jnp.uint32(1)
+        return v + ((low | high) & mask).astype(jnp.int32)
 
 
 def _decode_chunk_device(def_table, idx_table, packed, plain, dict_table,
@@ -572,8 +603,9 @@ def _nbytes(host) -> int:
 def _count_chunk(counters: Optional[dict], plan: "ColumnChunkPlan",
                  kinds, host, upload_ns: int, launch_ns: int) -> None:
     """One decoded chunk into the scan's counters: the upload's and the
-    launch's host nanoseconds, the bytes uploaded, and the chunk under
-    each of ``kinds`` (what its pages hold)."""
+    launch's host nanoseconds, the bytes uploaded, the chunk under each
+    of ``kinds`` (what its pages hold), and the hybrid streams staged for
+    :func:`_expand_hybrid` with their real (unpadded) runs."""
     _count(counters, "scanUploadNs", upload_ns)
     _count(counters, "uploadBytes", _nbytes(host))
     _count(counters, "scanLaunchNs", launch_ns)
@@ -581,6 +613,11 @@ def _count_chunk(counters: Optional[dict], plan: "ColumnChunkPlan",
     for kind in kinds:
         _count(counters, kind, 1)
     _count(counters, "scanChunksNoNulls", int(not plan.has_nulls))
+    staged = [runs for key, runs in (("def", plan.def_runs),
+                                     ("idx", plan.idx_runs))
+              if host.get(key) is not None]
+    _count(counters, "scanHybridStreams", len(staged))
+    _count(counters, "scanHybridRuns", sum(len(r.kinds) for r in staged))
 
 
 def _live_rows(n_rows, capacity):

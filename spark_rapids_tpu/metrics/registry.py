@@ -212,6 +212,18 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "page held a null, decoded by the nullable program of their "
           "kind (parquet_decode_*_bw<n>_*: definition levels, prefix "
           "sum to slots, the slot or dictionary gather)."),
+    _spec("scanHybridStreams", MetricKind.SUM, ESSENTIAL,
+          "Device parquet scan: RLE/bit-packed hybrid streams expanded on "
+          "the device (io/parquet_device.py _expand_hybrid), counted as "
+          "each chunk's run tables are staged: one for a dictionary chunk "
+          "without nulls (its indices), two for a nullable dictionary "
+          "chunk (definition levels, indices), one for a nullable PLAIN "
+          "chunk, none for a PLAIN chunk without nulls."),
+    _spec("scanHybridRuns", MetricKind.SUM, ESSENTIAL,
+          "Device parquet scan: the runs of the streams scanHybridStreams "
+          "counts, as the host parsed them (the staged tables' padding "
+          "excluded): the updates of _expand_hybrid's scatter of run "
+          "steps."),
     _spec("planRuns", MetricKind.SUM, ESSENTIAL,
           "Runs of the plan behind one collect()/cache(): 1, plus "
           "join-capacity re-runs and dispatch retries (TpuSession node; "

@@ -512,10 +512,11 @@ def _decode_three_ways(tbl, path):
 def test_pure_chunks_keep_their_programs(tmp_path, monkeypatch):
     """A chunk WITH nulls asks ``cached_kernel`` for the key, the name and
     the operands it asked for before chunks without nulls got programs of
-    their own, and lowers to the same text (the digests are of the commit
-    before that change, from this table, on this backend; a JAX upgrade
-    that moves them moves all of them: re-pin from a checkout that still
-    passes): a file with nulls compiles and runs what it ran."""
+    their own, and lowers to a pinned text (the digests are of the run
+    expansion by a prefix sum, from this table, on this backend; a JAX
+    upgrade that moves them moves all of them: re-pin from a checkout that
+    still passes; a change to the decode's lowering re-pins them with it):
+    a file with nulls compiles and runs what was reviewed."""
     n = 5000
     holes = np.zeros(n, bool)
     holes[n - 1] = True         # one null a column, in its last page
@@ -524,31 +525,31 @@ def test_pure_chunks_keep_their_programs(tmp_path, monkeypatch):
     assert asked == [
         ("parquet_decode_double_bw0_plain",
          ("double", 8192, 0, False, False, True, 128), 6,
-         "f05d49efbe52a95e"),
+         "5e065979b9c1ec32"),
         ("parquet_decode_date_bw0_plain",
          ("date", 8192, 0, False, False, True, 128), 6,
-         "c2d7779a6efc2662"),
+         "f826a8af24711128"),
         ("parquet_decode_bigint_bw0_plain",
          ("bigint", 8192, 0, False, False, True, 128), 6,
-         "2964ad0b865f31d4"),
+         "6083207a0fb587b0"),
         ("parquet_decode_string_bw4_dictstr",
          ("string", 8192, 4, True, True, False, 128), 6,
-         "35219163f6826281"),
+         "180ea4e0d7234844"),
         ("parquet_decode_double_bw13_dict",
          ("double", 8192, 13, True, False, False, 128), 6,
-         "da0c047f25bc16ca"),
+         "d640bb5ad43c5e2a"),
         ("parquet_decode_date_bw12_dict",
          ("date", 8192, 12, True, False, False, 128), 6,
-         "22bd95769912ce1d"),
+         "f363a9852a7170e5"),
         ("parquet_decode_bigint_bw13_dict",
          ("bigint", 8192, 13, True, False, False, 128), 6,
-         "bad169d070ea0787"),
+         "ea765242cbd96467"),
         ("parquet_decode_string_bw4_dictstr",
          ("string", 8192, 4, True, True, False, 128), 6,
-         "35219163f6826281"),
+         "180ea4e0d7234844"),
         ("parquet_decode_double_bw10_dictplain",
          ("double", 8192, 10, True, False, True, 128), 7,
-         "cb1c44bfdc36d15d"),
+         "82e33b1eff7b4ba2"),
     ]
 
 
@@ -933,3 +934,237 @@ def test_string_dictionary_page_after_plain_pages_is_refused(tmp_path,
     with pytest.raises(NotImplementedError,
                        match="dictionary pages after PLAIN"):
         _decode_text_groups(path)
+
+
+# -- the run expansion against a plain numpy one ------------------------------
+
+
+def _bit_packed(values, width):
+    """Bytes of one bit-packed run as parquet lays them: groups of eight
+    values, LSB first, the last group zero-filled."""
+    vals = np.zeros(-(-len(values) // 8) * 8, np.int64)
+    vals[: len(values)] = values
+    bits = (vals[:, None] >> np.arange(width)) & 1
+    return np.packbits(bits.astype(np.uint8).ravel(), bitorder="little")
+
+
+def _runs_from_spec(spec, rng):
+    """(run table, packed bytes, the values it holds) of hand-written runs:
+    ``("rle", count, width, value)`` or ``("bp", count, width[, values])``,
+    values drawn below ``2 ** width`` where not given; a bit-packed run's
+    bytes follow the ones before it, as ``_parse_hybrid`` appends them."""
+    runs, packed, want = PD._HybridRuns(), bytearray(), []
+    for kind, count, width, *value in spec:
+        runs.counts.append(count)
+        runs.widths.append(width)
+        if kind == "rle":
+            runs.kinds.append(1)
+            runs.values.append(value[0])
+            runs.bit_starts.append(0)
+            want.append(np.full(count, value[0]))
+        else:
+            vals = value[0] if value else rng.integers(0, 1 << width, count)
+            runs.kinds.append(0)
+            runs.values.append(0)
+            runs.bit_starts.append(len(packed) * 8)
+            packed.extend(_bit_packed(vals, width).tobytes())
+            want.append(vals)
+    return runs, packed, np.concatenate(want)
+
+
+def _encoded(values, width):
+    """The stream a writer makes of ``values``: a group of eight equal
+    values starts or extends an RLE run, other groups gather into
+    bit-packed runs of at most 63 groups (504 values, as pyarrow closes
+    them); a short last group is bit-packed. The runs as
+    ``_runs_from_spec`` takes them."""
+    spec = []
+    for g in range(0, len(values), 8):
+        group = values[g: g + 8]
+        same = len(group) == 8 and (group == group[0]).all()
+        last = spec[-1] if spec else None
+        if same and last and last[0] == "rle" and last[3] == group[0]:
+            last[1] += 8
+        elif same:
+            spec.append(["rle", 8, width, int(group[0])])
+        elif last and last[0] == "bp" and last[1] < 504 and last[1] % 8 == 0:
+            last[1] += len(group)
+            last[3] = np.concatenate([last[3], group])
+        else:
+            spec.append(["bp", len(group), width, group])
+    return spec
+
+
+def _oracle(runs, packed):
+    """Run by run: an RLE run repeats its value, a bit-packed run reads
+    ``width`` bits a value from its bit offset on."""
+    bits = np.unpackbits(np.frombuffer(bytes(packed) or b"\0", np.uint8),
+                         bitorder="little").astype(np.int64)
+    out = []
+    for kind, count, value, start, width in zip(
+            runs.kinds, runs.counts, runs.values, runs.bit_starts,
+            runs.widths):
+        if kind == 1:
+            out.append(np.full(count, value, np.int64))
+        else:
+            at = start + np.arange(count)[:, None] * width \
+                + np.arange(width)
+            out.append((bits[at] << np.arange(width)).sum(axis=1))
+    return np.concatenate(out)
+
+
+def _expansion_case(name):
+    """(run table, packed bytes, the values, run-table bucket, capacity)
+    of one case of ``test_expand_hybrid_matches_numpy``."""
+    rng = np.random.default_rng(_EXPANSION_CASES.index(name))
+    if name in ("q43_def_levels", "tpch_2bit", "tpch_12bit"):
+        n, width = 1 << 16, {"q43_def_levels": 1, "tpch_2bit": 2,
+                             "tpch_12bit": 12}[name]
+        values = (rng.random(n) >= 0.04).astype(np.int64) \
+            if width == 1 else rng.integers(0, 1 << width, n)
+        runs, packed, _ = _runs_from_spec(_encoded(values, width), rng)
+        return runs, packed, values, None, n
+    spec = {
+        "widths_1_to_24": [
+            run for w in range(1, 25)
+            for run in (("bp", int(rng.integers(1, 41)), w),
+                        ("rle", int(rng.integers(1, 30)), w,
+                         int(rng.integers(0, 1 << w))))],
+        "rle_bp_interleaved": [
+            ("rle", 9, 3, 5), ("bp", 16, 3), ("rle", 1, 3, 7), ("bp", 3, 5),
+            ("bp", 24, 17), ("rle", 100, 20, 1 << 19), ("bp", 8, 1)],
+        "zero_count_mid": [
+            ("bp", 13, 6), ("bp", 0, 9), ("rle", 0, 4, 11), ("rle", 5, 4, 3),
+            ("rle", 0, 4, 2), ("bp", 0, 24), ("bp", 20, 11)],
+        "padding_runs": [("rle", 40, 2, 1), ("bp", 17, 7)],
+        "total_eq_capacity": [("bp", 504, 12), ("rle", 16, 12, 4095),
+                              ("bp", 504, 12)],
+        "total_below_capacity": [("bp", 504, 12), ("rle", 16, 12, 1),
+                                 ("bp", 480, 12)],
+        # 80 + 48 bytes: the last value's bits end the buffer's last word
+        "last_word": [("rle", 7, 8, 9), ("bp", 80, 8), ("bp", 16, 24)],
+    }[name]
+    runs, packed, values = _runs_from_spec(spec, rng)
+    pad = 256 if name == "padding_runs" else None
+    capacity = 1024 if name.startswith("total") \
+        else PD.bucket_capacity(len(values))
+    return runs, packed, values, pad, capacity
+
+
+_EXPANSION_CASES = ["widths_1_to_24", "rle_bp_interleaved", "zero_count_mid",
+                    "padding_runs", "total_eq_capacity",
+                    "total_below_capacity", "last_word", "q43_def_levels",
+                    "tpch_2bit", "tpch_12bit"]
+
+
+@pytest.mark.parametrize("name", _EXPANSION_CASES)
+def test_expand_hybrid_matches_numpy(name):
+    """``_expand_hybrid`` on hand-built run tables, as the decoder stages
+    them (run tables padded to their bucket, packed bytes to theirs),
+    exactly what a plain run-by-run numpy expansion reads on every live
+    row."""
+    import jax
+    runs, packed, values, pad, capacity = _expansion_case(name)
+    want = _oracle(runs, packed)
+    assert np.array_equal(want, values)       # the oracle reads the writer
+    total = len(values)
+    assert total <= capacity
+    if name == "total_eq_capacity":
+        assert total == capacity
+    if name == "last_word":
+        assert len(packed) == len(PD._pad_packed(bytes(packed)))
+    if name == "zero_count_mid":
+        assert 0 in runs.counts[1:-1]
+    pad = pad or PD._run_table_bucket(runs)
+    assert pad > len(runs.kinds) or name == "total_eq_capacity"
+    expand = jax.jit(PD._expand_hybrid, static_argnums=6)
+    got = np.asarray(expand(*PD._runs_arrays(runs, pad),
+                            PD._pad_packed(bytes(packed)), capacity))
+    assert got.dtype == np.int32
+    assert np.array_equal(got[:total], want)
+
+
+@pytest.mark.parametrize("n", [1, 1000, 1024, 3000, 1 << 16])
+def test_prefix_sum_is_cumsum(n):
+    """The two-level prefix sum reads what numpy's does, wrap included,
+    at lengths on and off its block."""
+    values = np.random.default_rng(n).integers(-(1 << 31), 1 << 31, n,
+                                               dtype=np.int64)
+    got = np.asarray(PD._prefix_sum(values.astype(np.int32)))
+    assert np.array_equal(got, np.cumsum(values).astype(np.int32))
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the programs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) \
+                    else (param,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_expand_hybrid_searches_nothing():
+    """At a 1 Mi-row capacity the expansion holds no loop and no sort (a
+    ``searchsorted`` lowers to a ``while``), and its only gathers are the
+    two of 32-bit words of the packed bytes: none of a run-table column."""
+    import jax
+    import jax.numpy as jnp
+    capacity, packed_bytes = 1 << 20, 1 << 22
+    table = jax.ShapeDtypeStruct((4096,), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda k, c, v, b, w, p: PD._expand_hybrid(k, c, v, b, w, p,
+                                                   capacity))(
+        *[table] * 5, jax.ShapeDtypeStruct((packed_bytes,), jnp.uint8))
+    eqns = list(_equations(jaxpr.jaxpr))
+    names = {eqn.primitive.name for eqn in eqns}
+    assert not names & {"while", "sort"}, names
+    assert not any("searchsorted" in str(eqn.params.get("name", ""))
+                   for eqn in eqns)
+    gathers = [eqn for eqn in eqns if eqn.primitive.name == "gather"]
+    assert [eqn.invars[0].aval.shape for eqn in gathers] == \
+        [(packed_bytes // 4,)] * 2
+    scatters = [eqn for eqn in eqns if eqn.primitive.name == "scatter-add"]
+    assert [eqn.invars[0].aval.shape for eqn in scatters] == [(capacity,)] * 3
+
+
+_STREAM_CASES = {
+    # writer options, whether a page holds a null, the streams staged
+    "dict_no_nulls": (dict(use_dictionary=True), False, ("idx",)),
+    "dict_nulls": (dict(use_dictionary=True), True, ("def", "idx")),
+    "plain_nulls": (dict(use_dictionary=False), True, ("def",)),
+    "plain_no_nulls": (dict(use_dictionary=False), False, ()),
+}
+
+
+@pytest.mark.parametrize("case", list(_STREAM_CASES))
+def test_hybrid_stream_counters(tmp_path, case):
+    """``scanHybridStreams`` counts the hybrid streams a chunk stages for
+    the device and ``scanHybridRuns`` their runs as the host parsed them,
+    not the padded tables."""
+    options, nulls, streams = _STREAM_CASES[case]
+    n = 3000
+    rng = np.random.default_rng(3)
+    mask = (rng.random(n) < 0.04) if nulls else None
+    tbl = pa.table({"v": pa.array(rng.integers(0, 40, n), pa.int64(),
+                                  mask=mask)})
+    path = str(tmp_path / "t.parquet")
+    pq.write_table(tbl, path, data_page_size=1024, write_batch_size=128,
+                   **options)
+    schema = T.schema_from_arrow(tbl.schema)
+    with open(path, "rb") as f:
+        plan = PD.plan_column_chunk(
+            f, pq.ParquetFile(path).metadata.row_group(0).column(0),
+            schema.fields[0], 1)
+    assert plan.has_nulls == nulls
+    tables = {"def": plan.def_runs, "idx": plan.idx_runs}
+    counters = {}
+    out = PD.decode_row_group(path, 0, schema, counters=counters).to_arrow()
+    assert out.column("v").to_pylist() == tbl.column("v").to_pylist()
+    assert counters["scanHybridStreams"] == len(streams)
+    assert counters["scanHybridRuns"] == sum(
+        len(tables[s].kinds) for s in streams)
+    if streams:
+        assert counters["scanHybridRuns"] > len(streams)
