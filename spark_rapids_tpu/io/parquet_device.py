@@ -46,6 +46,7 @@ nothing is compiled for a dictionary's length.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import datetime as _dt
 import struct as _struct
@@ -60,6 +61,7 @@ from .. import types as T
 from ..data.batch import ColumnarBatch
 from ..data.column import (DeviceColumn, bucket_byte_capacity,
                            bucket_capacity)
+from ..metrics.trace import span
 from ..utils.kernel_cache import cached_kernel
 
 # -- minimal thrift compact protocol reader ---------------------------------
@@ -280,8 +282,6 @@ class ColumnChunkPlan:
     text_src: Optional[np.ndarray] = None
     page_starts: Optional[List[int]] = None
     page_counts: Optional[List[int]] = None
-    # host nanoseconds spent parsing the definition levels
-    def_level_ns: int = 0
 
 
 def _decompress(codec: str, payload: bytes, uncompressed_size: int) -> bytes:
@@ -292,8 +292,24 @@ def _decompress(codec: str, payload: bytes, uncompressed_size: int) -> bytes:
         payload, decompressed_size=uncompressed_size).to_pybytes()
 
 
-def plan_column_chunk(f, col_md, field: T.StructField,
-                      max_def_level: int) -> ColumnChunkPlan:
+@contextlib.contextmanager
+def _stage(owner, counters: Optional[dict], name: str, counter: str):
+    """One stage of a column chunk's host work, over all of its pages:
+    one span ``name`` (under ``owner``, as for :func:`span`; nested in the
+    row group's ``parquet.device_decode``) and one pair of clock reads
+    counted under ``counter`` — never a clock read, span or count per
+    page, run or value."""
+    t0 = time.perf_counter_ns()
+    try:
+        with span(owner, name):
+            yield
+    finally:
+        _count(counters, counter, time.perf_counter_ns() - t0)
+
+
+def plan_column_chunk(f, col_md, field: T.StructField, max_def_level: int,
+                      counters: Optional[dict] = None,
+                      owner=None) -> ColumnChunkPlan:
     """Host phase for one column chunk: page headers -> run tables.
 
     ``f`` is an open file object; ``col_md`` a pyarrow ColumnChunkMetaData;
@@ -301,7 +317,16 @@ def plan_column_chunk(f, col_md, field: T.StructField,
     no definition-level stream regardless of what the engine schema says
     about nullability — trusting the engine schema here mis-frames the
     page payload). Raises NotImplementedError for shapes outside scope
-    (caller falls back to the host scan)."""
+    (caller falls back to the host scan).
+
+    The chunk is planned in stages, each over all of its pages: the read,
+    the page headers, the decompression, the definition levels, the index
+    streams, the values; what a stage finds in a page (where its index
+    stream or values start, its non-null values) is carried to the next.
+    Each stage but the headers' is a :func:`_stage` counted into
+    ``counters``: ``scanReadNs``, ``scanDecompressNs``, ``scanDefLevelNs``
+    (of a column nullable in the file only), ``scanIndexRunNs`` (zero
+    without dictionary-encoded pages), ``scanValuesNs``."""
     if max_def_level > 1:
         raise NotImplementedError("nested columns (max_def_level > 1)")
     phys = col_md.physical_type
@@ -310,144 +335,161 @@ def plan_column_chunk(f, col_md, field: T.StructField,
     start = col_md.data_page_offset
     if col_md.has_dictionary_page:
         start = min(start, col_md.dictionary_page_offset)
-    f.seek(start)
-    chunk = f.read(col_md.total_compressed_size)
-    codec = col_md.compression
+    with _stage(owner, counters, "parquet.read", "scanReadNs"):
+        f.seek(start)
+        chunk = f.read(col_md.total_compressed_size)
 
-    pos = 0
-    dict_vals_raw: Optional[bytes] = None
-    def_runs = _HybridRuns()
-    idx_runs = _HybridRuns()
-    packed = bytearray()
-    plain_parts: List[bytes] = []
-    plain_counts: List[int] = []
-    n_dict = 0
-    idx_bw = 0
-    n_rows = 0
-    dict_count = 0
-    has_nulls = False
-    def_level_ns = 0
+    headers: List[_PageHeader] = []
+    body: List[int] = []      # where each page's payload starts in chunk
     uses_dict = False
     uses_plain = False
+    pos = 0
     while pos < len(chunk):
         ph = _parse_page_header(chunk, pos)
         pos += ph.header_len
-        payload = _decompress(codec, chunk[pos: pos + ph.compressed_size],
-                              ph.uncompressed_size)
-        pos += ph.compressed_size
-        if ph.page_type == 2:  # dictionary page (PLAIN-encoded)
-            dict_vals_raw = payload
-            n_dict = ph.num_values
-            continue
-        if ph.page_type != 0:
-            raise NotImplementedError(f"page type {ph.page_type} (v2?)")
-        p = 0
-        page_def_start = len(def_runs.kinds)
-        if max_def_level > 0:
-            if ph.def_encoding != RLE:
+        if ph.page_type == 0:
+            if max_def_level > 0 and ph.def_encoding != RLE:
                 raise NotImplementedError("non-RLE definition levels")
-            t0 = time.perf_counter_ns()
-            (def_len,) = _struct.unpack_from("<I", payload, p)
-            p += 4
-            _parse_hybrid(payload, p, p + def_len, 1, ph.num_values,
-                          def_runs, packed)
-            p += def_len
-            non_null = def_runs.non_null_count(page_def_start, packed)
-            def_level_ns += time.perf_counter_ns() - t0
+            if ph.encoding in (PLAIN_DICTIONARY, RLE_DICTIONARY):
+                if uses_plain:
+                    # the writers' fallback is one-way: a slot's source is
+                    # told by its position alone only in that order
+                    raise NotImplementedError(
+                        "dictionary pages after PLAIN pages")
+                uses_dict = True
+            elif ph.encoding == PLAIN:
+                uses_plain = True
+            else:
+                raise NotImplementedError(f"encoding {ph.encoding}")
+        elif ph.page_type != 2:  # 2: the dictionary page (PLAIN-encoded)
+            raise NotImplementedError(f"page type {ph.page_type} (v2?)")
+        headers.append(ph)
+        body.append(pos)
+        pos += ph.compressed_size
+
+    codec = col_md.compression
+    with _stage(owner, counters, "parquet.decompress", "scanDecompressNs"):
+        payloads = [_decompress(codec, chunk[p: p + ph.compressed_size],
+                                ph.uncompressed_size)
+                    for ph, p in zip(headers, body)]
+    dict_vals_raw: Optional[bytes] = None
+    n_dict = 0
+    pages = []                # (header, payload) of each data page
+    for ph, payload in zip(headers, payloads):
+        if ph.page_type == 2:
+            dict_vals_raw, n_dict = payload, ph.num_values
         else:
+            pages.append((ph, payload))
+
+    # from here on, per data page: where its index stream or values start
+    # in its payload, and how many of its values are not null
+    at = [0] * len(pages)
+    non_null = [ph.num_values for ph, _ in pages]
+    def_runs = _HybridRuns()
+    packed = bytearray()
+    if max_def_level > 0:
+        with _stage(owner, counters, "parquet.def_levels", "scanDefLevelNs"):
+            for i, (ph, payload) in enumerate(pages):
+                first = len(def_runs.kinds)
+                (def_len,) = _struct.unpack_from("<I", payload, 0)
+                _parse_hybrid(payload, 4, 4 + def_len, 1, ph.num_values,
+                              def_runs, packed)
+                at[i] = 4 + def_len
+                non_null[i] = def_runs.non_null_count(first, packed)
+    else:
+        for ph, _ in pages:
             def_runs.kinds.append(1)
             def_runs.counts.append(ph.num_values)
             def_runs.values.append(1)
             def_runs.bit_starts.append(0)
             def_runs.widths.append(1)
-            non_null = ph.num_values
-        has_nulls = has_nulls or non_null < ph.num_values
-        if ph.encoding in (PLAIN_DICTIONARY, RLE_DICTIONARY):
-            if uses_plain:
-                # the writers' fallback is one-way: a slot's source is
-                # told by its position alone only in that order
-                raise NotImplementedError(
-                    "dictionary pages after PLAIN pages")
-            uses_dict = True
-            dict_count += non_null
-            bw = payload[p]
-            p += 1
-            if bw > 24:
-                raise NotImplementedError(f"dictionary bit width {bw}")
-            idx_bw = max(idx_bw, bw)
-            # The page stores exactly non_null indices (indices exist only
-            # for non-null slots; the def mask scatters them into row order
-            # on device). Capping at the EXACT count keeps multi-page run
-            # tables positionally aligned; per-run widths let later pages
-            # use wider codes as the dictionary fills.
-            _parse_hybrid(payload, p, len(payload), bw, non_null,
-                          idx_runs, packed)
-        elif ph.encoding == PLAIN:
-            uses_plain = True
-            plain_parts.append(payload[p:])
-            plain_counts.append(non_null)
-        else:
-            raise NotImplementedError(f"encoding {ph.encoding}")
-        n_rows += ph.num_values
-    text_plain = phys == "BYTE_ARRAY" and (uses_plain or not uses_dict)
+
+    idx_runs = _HybridRuns()
+    idx_bw = 0
+    dict_count = 0
+    if uses_dict:
+        with _stage(owner, counters, "parquet.index_runs", "scanIndexRunNs"):
+            for i, (ph, payload) in enumerate(pages):
+                if ph.encoding == PLAIN:
+                    continue
+                dict_count += non_null[i]
+                bw = payload[at[i]]
+                if bw > 24:
+                    raise NotImplementedError(f"dictionary bit width {bw}")
+                idx_bw = max(idx_bw, bw)
+                # The page stores exactly non_null indices (indices exist
+                # only for non-null slots; the def mask scatters them into
+                # row order on device). Capping at the EXACT count keeps
+                # multi-page run tables positionally aligned; per-run
+                # widths let later pages use wider codes as the dictionary
+                # fills.
+                _parse_hybrid(payload, at[i] + 1, len(payload), bw,
+                              non_null[i], idx_runs, packed)
+    else:
+        _count(counters, "scanIndexRunNs", 0)
 
     plan = ColumnChunkPlan(
-        dtype=field.data_type, n_rows=n_rows, nullable=field.nullable,
-        has_nulls=has_nulls, def_runs=def_runs,
-        idx_runs=idx_runs if uses_dict else None,
+        dtype=field.data_type, n_rows=sum(ph.num_values for ph, _ in pages),
+        nullable=field.nullable,
+        has_nulls=any(n < ph.num_values
+                      for n, (ph, _) in zip(non_null, pages)),
+        def_runs=def_runs, idx_runs=idx_runs if uses_dict else None,
         idx_bit_width=idx_bw, packed=bytes(packed),
         plain_values=None, dict_count=dict_count, dict_values=None,
-        dict_rank=None, dict_offsets=None, dict_payload=None,
-        def_level_ns=def_level_ns)
-
-    if text_plain:
-        # the values' bytes as the pages hold them; finding each value in
-        # them is the device's work (no per-value host loop)
-        parts = ([dict_vals_raw] if uses_dict else []) + plain_parts
-        plan.page_counts = ([n_dict] if uses_dict else []) + plain_counts
-        ends = np.cumsum([len(part) for part in parts], dtype=np.int64)
-        plan.page_starts = [int(end) - len(part)
-                            for end, part in zip(ends, parts)]
-        plan.text_src = np.zeros(bucket_byte_capacity(
-            max(int(ends[-1]) if parts else 0, 4)), np.uint8)
-        for start, part in zip(plan.page_starts, parts):
-            plan.text_src[start: start + len(part)] = np.frombuffer(
-                part, np.uint8)
-        return plan
-    if uses_plain:
-        raw = b"".join(plain_parts)
-        if phys == "BOOLEAN":
-            raise NotImplementedError("PLAIN boolean pages")
-        plan.plain_values = np.frombuffer(
-            raw, dtype=_PHYS_NP[phys]).astype(
-                field.data_type.np_dtype, copy=False)
-    if uses_dict:
-        assert dict_vals_raw is not None, "dict pages missing"
-        if phys == "BYTE_ARRAY":
-            # PLAIN byte-array dictionary: [u32 len][bytes]... Host-parse
-            # (dictionary-sized, small), sort, build the rank remap so the
-            # device column lands dict_sorted.
-            vals: List[bytes] = []
-            q = 0
-            while q < len(dict_vals_raw):
-                (ln,) = _struct.unpack_from("<I", dict_vals_raw, q)
-                q += 4
-                vals.append(dict_vals_raw[q: q + ln])
-                q += ln
-            order = np.argsort(np.asarray(vals, dtype=object), kind="stable")
-            rank = np.empty(len(vals), dtype=np.int32)
-            rank[order] = np.arange(len(vals), dtype=np.int32)
-            sorted_vals = [vals[i] for i in order] or [b""]
-            lens = np.asarray([len(b) for b in sorted_vals], np.int32)
-            plan.dict_offsets = np.concatenate(
-                [[0], np.cumsum(lens)]).astype(np.int32)
-            plan.dict_payload = np.frombuffer(
-                b"".join(sorted_vals) or b"\0", dtype=np.uint8)
-            plan.dict_rank = rank
-        else:
-            plan.dict_values = np.frombuffer(
-                dict_vals_raw, dtype=_PHYS_NP[phys]).astype(
+        dict_rank=None, dict_offsets=None, dict_payload=None)
+    with _stage(owner, counters, "parquet.values", "scanValuesNs"):
+        plain = [i for i, (ph, _) in enumerate(pages) if ph.encoding == PLAIN]
+        plain_parts = [pages[i][1][at[i]:] for i in plain]
+        if phys == "BYTE_ARRAY" and (uses_plain or not uses_dict):
+            # the values' bytes as the pages hold them; finding each value
+            # in them is the device's work (no per-value host loop)
+            parts = ([dict_vals_raw] if uses_dict else []) + plain_parts
+            plan.page_counts = ([n_dict] if uses_dict else []) \
+                + [non_null[i] for i in plain]
+            ends = np.cumsum([len(part) for part in parts], dtype=np.int64)
+            plan.page_starts = [int(end) - len(part)
+                                for end, part in zip(ends, parts)]
+            plan.text_src = np.zeros(bucket_byte_capacity(
+                max(int(ends[-1]) if parts else 0, 4)), np.uint8)
+            for start, part in zip(plan.page_starts, parts):
+                plan.text_src[start: start + len(part)] = np.frombuffer(
+                    part, np.uint8)
+            return plan
+        if uses_plain:
+            raw = b"".join(plain_parts)
+            if phys == "BOOLEAN":
+                raise NotImplementedError("PLAIN boolean pages")
+            plan.plain_values = np.frombuffer(
+                raw, dtype=_PHYS_NP[phys]).astype(
                     field.data_type.np_dtype, copy=False)
+        if uses_dict:
+            assert dict_vals_raw is not None, "dict pages missing"
+            if phys == "BYTE_ARRAY":
+                # PLAIN byte-array dictionary: [u32 len][bytes]...
+                # Host-parse (dictionary-sized, small), sort, build the
+                # rank remap so the device column lands dict_sorted.
+                vals: List[bytes] = []
+                q = 0
+                while q < len(dict_vals_raw):
+                    (ln,) = _struct.unpack_from("<I", dict_vals_raw, q)
+                    q += 4
+                    vals.append(dict_vals_raw[q: q + ln])
+                    q += ln
+                order = np.argsort(np.asarray(vals, dtype=object),
+                                   kind="stable")
+                rank = np.empty(len(vals), dtype=np.int32)
+                rank[order] = np.arange(len(vals), dtype=np.int32)
+                sorted_vals = [vals[i] for i in order] or [b""]
+                lens = np.asarray([len(b) for b in sorted_vals], np.int32)
+                plan.dict_offsets = np.concatenate(
+                    [[0], np.cumsum(lens)]).astype(np.int32)
+                plan.dict_payload = np.frombuffer(
+                    b"".join(sorted_vals) or b"\0", dtype=np.uint8)
+                plan.dict_rank = rank
+            else:
+                plan.dict_values = np.frombuffer(
+                    dict_vals_raw, dtype=_PHYS_NP[phys]).astype(
+                        field.data_type.np_dtype, copy=False)
     return plan
 
 
@@ -601,23 +643,20 @@ def _nbytes(host) -> int:
 
 
 def _count_chunk(counters: Optional[dict], plan: "ColumnChunkPlan",
-                 kinds, host, upload_ns: int, launch_ns: int) -> None:
-    """One decoded chunk into the scan's counters: the upload's and the
-    launch's host nanoseconds, the bytes uploaded, the chunk under each
-    of ``kinds`` (what its pages hold), and the hybrid streams staged for
-    :func:`_expand_hybrid` with their real (unpadded) runs."""
-    _count(counters, "scanUploadNs", upload_ns)
+                 kinds, host) -> None:
+    """One decoded chunk into the scan's counters: the bytes uploaded, the
+    chunk under each of ``kinds`` (what its pages hold), and the runs of
+    the hybrid streams staged for :func:`_expand_hybrid`, as parsed
+    (unpadded)."""
     _count(counters, "uploadBytes", _nbytes(host))
-    _count(counters, "scanLaunchNs", launch_ns)
     _count(counters, "scanColumnChunksDecoded", 1)
     for kind in kinds:
         _count(counters, kind, 1)
     _count(counters, "scanChunksNoNulls", int(not plan.has_nulls))
-    staged = [runs for key, runs in (("def", plan.def_runs),
-                                     ("idx", plan.idx_runs))
-              if host.get(key) is not None]
-    _count(counters, "scanHybridStreams", len(staged))
-    _count(counters, "scanHybridRuns", sum(len(r.kinds) for r in staged))
+    _count(counters, "scanHybridRuns", sum(
+        len(runs.kinds) for key, runs in (("def", plan.def_runs),
+                                          ("idx", plan.idx_runs))
+        if host.get(key) is not None))
 
 
 def _live_rows(n_rows, capacity):
@@ -896,85 +935,87 @@ def _text_programs(plan: ColumnChunkPlan, capacity: int, kind: str):
 
 
 def _decode_text_chunk(plan: ColumnChunkPlan, capacity: int,
-                       counters: Optional[dict]) -> DeviceColumn:
+                       counters: Optional[dict], owner) -> DeviceColumn:
     """A BYTE_ARRAY chunk with PLAIN values to a flat string column: the
     rows program, then — the one wait of a chunk's decode, for two numbers
     — the text's bytes and the longest value back on the host, which size
     the payload's bucket and the column's ``max_bytes``, then the copy."""
-    has_idx = plan.idx_runs is not None
-    kind, counter = (("dictplain", "scanChunksDictionaryThenPlain")
-                     if has_idx else ("plain", "scanChunksPlain"))
-    kern, host, order = _text_programs(plan, capacity, kind)
-    t0 = time.perf_counter_ns()
-    dev = jax.tree_util.tree_map(jnp.asarray, host)
-    t1 = time.perf_counter_ns()
-    row_start, row_len, validity, stats = kern(*[dev[n] for n in order])
-    total, longest = (int(x) for x in jax.device_get(stats))
-    out_cap = bucket_byte_capacity(max(total, 1))
-    place = cached_kernel(
-        "parquet_decode", ("string_plain_place", out_cap),
-        lambda: lambda src, start, length: _place_text(
-            src, start, length, out_cap),
-        suffix="string_plain_place")
-    payload, offsets = place(dev["src"], row_start, row_len)
-    t2 = time.perf_counter_ns()
-    _count_chunk(counters, plan, (counter, "scanChunksByteArrayPlain"),
-                 host, t1 - t0, t2 - t1)
+    with _stage(owner, counters, "parquet.stage", "scanStageNs"):
+        has_idx = plan.idx_runs is not None
+        kind, counter = (("dictplain", "scanChunksDictionaryThenPlain")
+                         if has_idx else ("plain", "scanChunksPlain"))
+        kern, host, order = _text_programs(plan, capacity, kind)
+    with _stage(owner, counters, "parquet.upload", "scanUploadNs"):
+        dev = jax.tree_util.tree_map(jnp.asarray, host)
+    with _stage(owner, counters, "parquet.launch", "scanLaunchNs"):
+        row_start, row_len, validity, stats = kern(*[dev[n] for n in order])
+        total, longest = (int(x) for x in jax.device_get(stats))
+        out_cap = bucket_byte_capacity(max(total, 1))
+        place = cached_kernel(
+            "parquet_decode", ("string_plain_place", out_cap),
+            lambda: lambda src, start, length: _place_text(
+                src, start, length, out_cap),
+            suffix="string_plain_place")
+        payload, offsets = place(dev["src"], row_start, row_len)
+    _count_chunk(counters, plan, (counter, "scanChunksByteArrayPlain"), host)
     return DeviceColumn(data=payload, validity=validity, dtype=T.STRING,
                         offsets=offsets, max_bytes=_width_bucket(longest))
 
 
 def decode_chunk(plan: ColumnChunkPlan, capacity: int,
-                 counters: Optional[dict] = None) -> DeviceColumn:
+                 counters: Optional[dict] = None, owner=None) -> DeviceColumn:
     """Upload one chunk's page bytes + run tables and decode on device.
-    ``counters`` (the scan's, one dict per row group) takes the upload's
-    and the launch's host nanoseconds, the bytes uploaded and the chunk
-    itself: three clock reads a chunk, nothing per row."""
+    ``counters`` (the scan's, one dict per row group) takes the bytes
+    uploaded, the chunk itself and three stages' host nanoseconds, each a
+    :func:`_stage` under ``owner``: the program's host operands staged at
+    their bucketed shapes (``scanStageNs``), their upload
+    (``scanUploadNs``), the launch (``scanLaunchNs``); nothing per row."""
     if plan.text_src is not None:
-        return _decode_text_chunk(plan, capacity, counters)
+        return _decode_text_chunk(plan, capacity, counters, owner)
     dict_string = plan.dict_rank is not None
     has_idx = plan.idx_runs is not None
     has_plain = plan.plain_values is not None
 
-    # what the chunk's pages hold, read from them: the program's kind and
-    # the counter it is counted under
-    kind, counter = (
-        ("dictstr", "scanChunksDictionary") if dict_string
-        else ("dictplain", "scanChunksDictionaryThenPlain")
-        if has_idx and has_plain
-        else ("dict", "scanChunksDictionary") if has_idx
-        else ("plain", "scanChunksPlain"))
-    kern, host, order = (_nullable_program if plan.has_nulls
-                         else _no_nulls_program)(plan, capacity, kind)
-
-    # Host staging (pad to the bucketed shapes), then every host->device
-    # copy of the chunk in one timed stretch.
-    host["n_rows"] = np.asarray(plan.n_rows, np.int32)
-    if dict_string:
-        host["dict"] = _bucketed(plan.dict_rank, np.int32)
-        byte_cap = bucket_byte_capacity(max(int(plan.dict_offsets[-1]), 1))
-        payload = np.zeros(byte_cap, np.uint8)
-        payload[: len(plan.dict_payload)] = plan.dict_payload
-        host["payload"] = payload
-        host["offsets"] = plan.dict_offsets
-    elif plan.dict_values is not None:
-        host["dict"] = _bucketed(plan.dict_values, plan.dict_values.dtype)
-    if has_plain:
-        buf = np.zeros(capacity, plan.plain_values.dtype)
-        buf[: len(plan.plain_values)] = plan.plain_values
-        host["plain"] = buf
-    if kind == "dictplain":
-        # where the chunk fell back is data: an operand, in no shape or key
-        host["dict_count"] = np.asarray(plan.dict_count, np.int32)
-    t0 = time.perf_counter_ns()
-    dev = jax.tree_util.tree_map(jnp.asarray, host)
-    t1 = time.perf_counter_ns()
-    out = kern(*[dev.get(name) for name in order])
-    # a PLAIN chunk without nulls is the uploaded buffer as it is
-    data, validity = out if has_idx or plan.has_nulls \
-        else (dev["plain"], out)
-    t2 = time.perf_counter_ns()
-    _count_chunk(counters, plan, (counter,), host, t1 - t0, t2 - t1)
+    with _stage(owner, counters, "parquet.stage", "scanStageNs"):
+        # what the chunk's pages hold, read from them: the program's kind
+        # and the counter it is counted under
+        kind, counter = (
+            ("dictstr", "scanChunksDictionary") if dict_string
+            else ("dictplain", "scanChunksDictionaryThenPlain")
+            if has_idx and has_plain
+            else ("dict", "scanChunksDictionary") if has_idx
+            else ("plain", "scanChunksPlain"))
+        kern, host, order = (_nullable_program if plan.has_nulls
+                             else _no_nulls_program)(plan, capacity, kind)
+        host["n_rows"] = np.asarray(plan.n_rows, np.int32)
+        if dict_string:
+            host["dict"] = _bucketed(plan.dict_rank, np.int32)
+            byte_cap = bucket_byte_capacity(
+                max(int(plan.dict_offsets[-1]), 1))
+            payload = np.zeros(byte_cap, np.uint8)
+            payload[: len(plan.dict_payload)] = plan.dict_payload
+            host["payload"] = payload
+            host["offsets"] = plan.dict_offsets
+        elif plan.dict_values is not None:
+            host["dict"] = _bucketed(plan.dict_values,
+                                     plan.dict_values.dtype)
+        if has_plain:
+            buf = np.zeros(capacity, plan.plain_values.dtype)
+            buf[: len(plan.plain_values)] = plan.plain_values
+            host["plain"] = buf
+        if kind == "dictplain":
+            # where the chunk fell back is data: an operand, in no shape
+            # or key
+            host["dict_count"] = np.asarray(plan.dict_count, np.int32)
+    # every host->device copy of the chunk in one stretch
+    with _stage(owner, counters, "parquet.upload", "scanUploadNs"):
+        dev = jax.tree_util.tree_map(jnp.asarray, host)
+    with _stage(owner, counters, "parquet.launch", "scanLaunchNs"):
+        out = kern(*[dev.get(name) for name in order])
+        # a PLAIN chunk without nulls is the uploaded buffer as it is
+        data, validity = out if has_idx or plan.has_nulls \
+            else (dev["plain"], out)
+    _count_chunk(counters, plan, (counter,), host)
     if plan.has_nulls:
         _count(counters, "scanNullableUploadBytes", _nbytes(host))
     if dict_string:
@@ -991,15 +1032,17 @@ def decode_chunk(plan: ColumnChunkPlan, capacity: int,
 
 def decode_row_group(path: str, row_group: int, schema: T.Schema,
                      pf=None, meta=None, pq_schema=None,
-                     counters: Optional[dict] = None) -> ColumnarBatch:
+                     counters: Optional[dict] = None,
+                     owner=None) -> ColumnarBatch:
     """Decode one row group of a parquet file into a device batch.
     Pass either an open ``pyarrow.parquet.ParquetFile`` or its parsed
     ``(meta, pq_schema)`` to amortize the footer parse across a file's
     row groups (metadata objects hold no file descriptor). ``counters``
-    is added to in place: ``scanParseNs`` here (file read, page headers,
-    decompression, run tables) and, of a column nullable in the file, the
-    part of it that parsed definition levels (``scanDefLevelNs``), the
-    rest in :func:`decode_chunk`."""
+    is added to in place: ``scanParseNs`` here, around
+    :func:`plan_column_chunk` (file read, page headers, decompression,
+    run tables, values), the stages' own in it and in
+    :func:`decode_chunk`. ``owner`` (the query's tracer, or None) takes
+    the stages' spans, as for :func:`span`."""
     import pyarrow.parquet as pq
     if meta is None:
         if pf is None:
@@ -1015,13 +1058,14 @@ def decode_row_group(path: str, row_group: int, schema: T.Schema,
         for field in schema:
             ci = name_to_idx[field.name]
             t0 = time.perf_counter_ns()
-            plan = plan_column_chunk(
-                f, md.column(ci), field,
-                pq_schema.column(ci).max_definition_level)
-            _count(counters, "scanParseNs", time.perf_counter_ns() - t0)
-            if pq_schema.column(ci).max_definition_level > 0:
-                _count(counters, "scanDefLevelNs", plan.def_level_ns)
-            cols.append(decode_chunk(plan, capacity, counters))
+            try:
+                plan = plan_column_chunk(
+                    f, md.column(ci), field,
+                    pq_schema.column(ci).max_definition_level,
+                    counters, owner)
+            finally:
+                _count(counters, "scanParseNs", time.perf_counter_ns() - t0)
+            cols.append(decode_chunk(plan, capacity, counters, owner))
     return ColumnarBatch(tuple(cols), jnp.asarray(n_rows, jnp.int32),
                          schema)
 
@@ -1156,7 +1200,6 @@ class TpuParquetScanExec:
 
         def read_unit(unit):
             path, meta, pq_schema, rg = unit
-            from ..metrics.trace import span
             from ..utils.fault_injection import maybe_inject
             n_rows = meta.row_group(rg).num_rows
             io: Dict[str, int] = {}
@@ -1167,7 +1210,7 @@ class TpuParquetScanExec:
                                         owner=ctx.trace):
                     batch = decode_row_group(path, rg, self._schema,
                                              meta=meta, pq_schema=pq_schema,
-                                             counters=io)
+                                             counters=io, owner=ctx.trace)
                 ctx.metric(name, "deviceDecodedRowGroups", 1)
             # ANY decode failure (unsupported shape, decompression codec
             # mismatch, corrupt/truncated page metadata) degrades to the

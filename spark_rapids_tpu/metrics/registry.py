@@ -152,18 +152,21 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "raises instead."),
     _spec("scanParseNs", MetricKind.NANO_TIMING, ESSENTIAL,
           "Device parquet scan, host side of a column chunk: file read, "
-          "page headers, decompression, run tables "
-          "(io/parquet_device.py plan_column_chunk). Thread-seconds "
-          "summed over the decode producers."),
+          "page headers, decompression, run tables, values "
+          "(io/parquet_device.py plan_column_chunk). Its stages are "
+          "scanReadNs, scanDecompressNs, scanDefLevelNs, scanIndexRunNs "
+          "and scanValuesNs; the rest is the page-header walk. "
+          "Thread-seconds summed over the decode producers."),
     _spec("scanUploadNs", MetricKind.NANO_TIMING, ESSENTIAL,
           "Device parquet scan: the host->device copies of a column "
           "chunk's packed bytes, run tables and dictionaries (the "
-          "jnp.asarray calls of decode_chunk), summed over the "
-          "producers; the bytes are in uploadBytes."),
+          "jnp.asarray calls of decode_chunk; span parquet.upload), "
+          "summed over the producers; the bytes are in uploadBytes."),
     _spec("scanLaunchNs", MetricKind.NANO_TIMING, ESSENTIAL,
           "Device parquet scan: the call that enqueues a column chunk's "
-          "decode program (trace and compile on its first visit). Grows "
-          "when a producer stands behind the device's queue."),
+          "decode program (trace and compile on its first visit; span "
+          "parquet.launch). Grows when a producer stands behind the "
+          "device's queue."),
     _spec("scanColumnChunksDecoded", MetricKind.SUM, ESSENTIAL,
           "Column chunks a file scan decoded, per run of the plan: row "
           "groups (parquet), stripes (ORC), file slices (CSV) or record "
@@ -212,18 +215,46 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "page held a null, decoded by the nullable program of their "
           "kind (parquet_decode_*_bw<n>_*: definition levels, prefix "
           "sum to slots, the slot or dictionary gather)."),
-    _spec("scanHybridStreams", MetricKind.SUM, ESSENTIAL,
-          "Device parquet scan: RLE/bit-packed hybrid streams expanded on "
-          "the device (io/parquet_device.py _expand_hybrid), counted as "
-          "each chunk's run tables are staged: one for a dictionary chunk "
-          "without nulls (its indices), two for a nullable dictionary "
-          "chunk (definition levels, indices), one for a nullable PLAIN "
-          "chunk, none for a PLAIN chunk without nulls."),
+    _spec("scanReadNs", MetricKind.NANO_TIMING, ESSENTIAL,
+          "Device parquet scan: the part of scanParseNs spent reading a "
+          "column chunk's bytes from its file (f.seek and f.read in "
+          "io/parquet_device.py plan_column_chunk; span parquet.read). "
+          "Thread-seconds summed over the decode producers."),
+    _spec("scanDecompressNs", MetricKind.NANO_TIMING, ESSENTIAL,
+          "Device parquet scan: the part of scanParseNs spent "
+          "decompressing every page of a column chunk, each page's copy "
+          "out of the codec's buffer (to_pybytes) included (_decompress; "
+          "span parquet.decompress). Thread-seconds summed over the "
+          "decode producers."),
+    _spec("scanIndexRunNs", MetricKind.NANO_TIMING, ESSENTIAL,
+          "Device parquet scan: the part of scanParseNs spent slicing the "
+          "dictionary index streams of a column chunk's pages into run "
+          "tables (_parse_hybrid; span parquet.index_runs): the "
+          "counterpart of scanDefLevelNs, zero for a chunk without "
+          "dictionary-encoded pages. Thread-seconds summed over the "
+          "decode producers."),
+    _spec("scanValuesNs", MetricKind.NANO_TIMING, ESSENTIAL,
+          "Device parquet scan: the part of scanParseNs spent turning a "
+          "column chunk's PLAIN values and dictionary page into the "
+          "arrays its decode takes: np.frombuffer, the concatenations, a "
+          "string dictionary's ranks and offsets, a byte-array chunk's "
+          "text buffer (span parquet.values). Thread-seconds summed over "
+          "the decode producers."),
+    _spec("scanStageNs", MetricKind.NANO_TIMING, ESSENTIAL,
+          "Device parquet scan: a column chunk's host operands staged "
+          "before their upload — the decode program's lookup, the run "
+          "tables and dictionaries padded to their buckets, the "
+          "zero-padded copies (io/parquet_device.py decode_chunk and "
+          "_decode_text_chunk; span parquet.stage). Not a part of "
+          "scanParseNs. Thread-seconds summed over the decode "
+          "producers."),
     _spec("scanHybridRuns", MetricKind.SUM, ESSENTIAL,
-          "Device parquet scan: the runs of the streams scanHybridStreams "
-          "counts, as the host parsed them (the staged tables' padding "
-          "excluded): the updates of _expand_hybrid's scatter of run "
-          "steps."),
+          "Device parquet scan: the runs of the RLE/bit-packed hybrid "
+          "streams a column chunk stages for the device's expansion "
+          "(io/parquet_device.py _expand_hybrid: a dictionary chunk's "
+          "indices, a chunk with nulls' definition levels), as the host "
+          "parsed them (the staged tables' padding excluded): the "
+          "updates of _expand_hybrid's scatter of run steps."),
     _spec("planRuns", MetricKind.SUM, ESSENTIAL,
           "Runs of the plan behind one collect()/cache(): 1, plus "
           "join-capacity re-runs and dispatch retries (TpuSession node; "

@@ -24,6 +24,11 @@ READERS = {
     "scan_launch_s_per_query": ("scanLaunchNs", "counters"),
     "cold_programs_in_setup": ("persistentCacheMisses", "setup_counters"),
     "scan_nonnull_chunks_per_query": ("scanChunksNoNulls", "counters"),
+    "scan_read_s_per_query": ("scanReadNs", "counters"),
+    "scan_decompress_s_per_query": ("scanDecompressNs", "counters"),
+    "scan_index_run_parse_s_per_query": ("scanIndexRunNs", "counters"),
+    "scan_values_s_per_query": ("scanValuesNs", "counters"),
+    "scan_stage_s_per_query": ("scanStageNs", "counters"),
 }
 
 
@@ -103,6 +108,13 @@ def test_readings_at_a_ninetieth(runs):
     spent = sum(_reader(f"scan_{part}_s_per_query")(q6)
                 for part in ("parse", "upload", "launch"))
     assert 0 < spent
+    # the parse's stages are parts of it; Q6's chunks hold PLAIN pages
+    # alone, so no index stream is parsed
+    parts = [_reader(f"scan_{part}_s_per_query")(q6)
+             for part in ("read", "decompress", "values")]
+    assert all(p > 0 for p in parts)
+    assert _reader("scan_index_run_parse_s_per_query")(q6) == 0.0
+    assert sum(parts) <= _reader("scan_parse_s_per_query")(q6)
     assert _reader("cold_programs_in_setup")(q6) >= 0
 
 

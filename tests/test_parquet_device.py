@@ -1141,9 +1141,9 @@ _STREAM_CASES = {
 
 @pytest.mark.parametrize("case", list(_STREAM_CASES))
 def test_hybrid_stream_counters(tmp_path, case):
-    """``scanHybridStreams`` counts the hybrid streams a chunk stages for
-    the device and ``scanHybridRuns`` their runs as the host parsed them,
-    not the padded tables."""
+    """``scanHybridRuns`` counts the runs of the hybrid streams a chunk
+    stages for the device as the host parsed them, not the padded
+    tables."""
     options, nulls, streams = _STREAM_CASES[case]
     n = 3000
     rng = np.random.default_rng(3)
@@ -1163,8 +1163,133 @@ def test_hybrid_stream_counters(tmp_path, case):
     counters = {}
     out = PD.decode_row_group(path, 0, schema, counters=counters).to_arrow()
     assert out.column("v").to_pylist() == tbl.column("v").to_pylist()
-    assert counters["scanHybridStreams"] == len(streams)
     assert counters["scanHybridRuns"] == sum(
         len(tables[s].kinds) for s in streams)
     if streams:
         assert counters["scanHybridRuns"] > len(streams)
+
+
+# ---------------------------------------------------------------------------
+# The scan's host side, stage by stage
+# ---------------------------------------------------------------------------
+
+#: the stages of a column chunk's host work: (span, counter)
+_STAGES = (("parquet.read", "scanReadNs"),
+           ("parquet.decompress", "scanDecompressNs"),
+           ("parquet.def_levels", "scanDefLevelNs"),
+           ("parquet.index_runs", "scanIndexRunNs"),
+           ("parquet.values", "scanValuesNs"),
+           ("parquet.stage", "scanStageNs"),
+           ("parquet.upload", "scanUploadNs"),
+           ("parquet.launch", "scanLaunchNs"))
+#: the stages that are parts of scanParseNs
+_PARSE_PARTS = ("scanReadNs", "scanDecompressNs", "scanDefLevelNs",
+                "scanIndexRunNs", "scanValuesNs")
+
+
+def test_every_stage_of_a_chunk_is_counted(tmp_path):
+    """A file in which every stage occurs (snappy pages, definition
+    levels, index streams, PLAIN values, dictionaries of numbers and
+    strings): each stage's counter reads time, the parse's five parts add
+    up to no more than ``scanParseNs``, and the staging is counted apart
+    from the parse, the upload and the launch. A PLAIN file without nulls
+    holds no index stream (zero) and a REQUIRED column no definition
+    levels (not counted)."""
+    import time
+    tbl = _table(n=6000)
+    path = str(tmp_path / "t.parquet")
+    pq.write_table(tbl, path, use_dictionary=["i", "s"], data_page_size=2048)
+    schema = T.schema_from_arrow(tbl.schema)
+    PD.decode_row_group(path, 0, schema)           # compiles
+    counters = {}
+    t0 = time.perf_counter_ns()
+    out = PD.decode_row_group(path, 0, schema, counters=counters)
+    wall = time.perf_counter_ns() - t0
+    for name in tbl.column_names:
+        assert out.to_arrow().column(name).to_pylist() == \
+            tbl.column(name).to_pylist(), name
+    for name in _PARSE_PARTS + ("scanStageNs",):
+        assert counters[name] > 0, name
+    assert sum(counters[name] for name in _PARSE_PARTS) \
+        <= counters["scanParseNs"]
+    assert counters["scanParseNs"] + counters["scanStageNs"] \
+        + counters["scanUploadNs"] + counters["scanLaunchNs"] <= wall
+
+    plain = pa.table({"p": pa.array(np.arange(3000, dtype=np.float64))},
+                     schema=pa.schema([pa.field("p", pa.float64(),
+                                                nullable=False)]))
+    path = str(tmp_path / "plain.parquet")
+    pq.write_table(plain, path, use_dictionary=False)
+    counters = {}
+    PD.decode_row_group(path, 0, T.schema_from_arrow(plain.schema),
+                        counters=counters)
+    assert counters["scanIndexRunNs"] == 0
+    assert "scanDefLevelNs" not in counters
+    assert counters["scanValuesNs"] > 0
+
+
+def _counted_decode(monkeypatch, path, schema):
+    """(clock reads, annotations) of one decode of the file's first row
+    group, its programs compiled before."""
+    import time
+    from spark_rapids_tpu.metrics import trace as TR
+    PD.decode_row_group(path, 0, schema)
+    calls = {"clock": 0, "annotation": 0}
+    clock = time.perf_counter_ns
+
+    def counted_clock():
+        calls["clock"] += 1
+        return clock()
+
+    class CountedAnnotation(TR.TraceAnnotation):
+        def __init__(self, *args, **kwargs):
+            calls["annotation"] += 1
+            super().__init__(*args, **kwargs)
+    with monkeypatch.context() as m:
+        m.setattr(time, "perf_counter_ns", counted_clock)
+        m.setattr(TR, "TraceAnnotation", CountedAnnotation)
+        PD.decode_row_group(path, 0, schema, counters={})
+    return calls["clock"], calls["annotation"]
+
+
+def _dictionary_file(path, values, pages):
+    tbl = pa.table({"v": pa.array(values, pa.int64())})
+    pq.write_table(tbl, path, data_page_size=1,
+                   write_batch_size=-(-len(values) // pages))
+    schema = T.schema_from_arrow(tbl.schema)
+    with open(path, "rb") as f:
+        plan = PD.plan_column_chunk(
+            f, pq.ParquetFile(path).metadata.row_group(0).column(0),
+            schema.fields[0], 1)
+    return schema, plan
+
+
+@pytest.mark.parametrize("pair", ["runs", "pages"])
+def test_stage_clocks_do_not_grow_with_runs_or_pages(tmp_path, monkeypatch,
+                                                     pair):
+    """The instrumentation's budget: one span and one pair of clock reads
+    a stage and column chunk, nothing per page, run or value. A nullable
+    dictionary chunk of a few runs reads the clock and opens annotations
+    as often as one of 40,000 runs (same rows, same pages), and one of a
+    page as often as one of 50: 2 clock reads around the parse and 2 for
+    each of the eight stages, one annotation a stage."""
+    if pair == "runs":
+        n = 40_000 * 8
+        cases = [(np.repeat(np.arange(10), n // 10), 16),
+                 (np.repeat(np.arange(n // 8) % 40, 8), 16)]
+    else:
+        n = 20_000
+        cases = [(np.arange(n) % 300, 1), (np.arange(n) % 300, 50)]
+    counts, plans = [], []
+    for k, (values, pages) in enumerate(cases):
+        path = str(tmp_path / f"{k}.parquet")
+        schema, plan = _dictionary_file(path, values, pages)
+        plans.append(plan)
+        counts.append(_counted_decode(monkeypatch, path, schema))
+    pages = [len(p.def_runs.kinds) for p in plans]   # one run a page here
+    runs = [len(p.idx_runs.kinds) for p in plans]
+    if pair == "runs":
+        assert pages[0] == pages[1] and runs[0] < 30 and runs[1] == 40_000
+    else:
+        assert pages == [1, 50]
+    assert counts[0] == counts[1] == (2 + 2 * len(_STAGES), len(_STAGES))

@@ -379,3 +379,141 @@ def test_totals_counts_a_shared_node_name_once():
         engine={"compile": {"xlaCompiles": 3}})
     assert profile.totals() == {"deviceDecodedRowGroups": 9, "opTime": 8,
                                 "planRuns": 1}
+
+
+# ---------------------------------------------------------------------------
+# The scan's host side, stage by stage
+# ---------------------------------------------------------------------------
+
+#: a column chunk's stages: span -> the counter that times it
+STAGES = {"parquet.read": "scanReadNs",
+          "parquet.decompress": "scanDecompressNs",
+          "parquet.def_levels": "scanDefLevelNs",
+          "parquet.index_runs": "scanIndexRunNs",
+          "parquet.values": "scanValuesNs",
+          "parquet.stage": "scanStageNs",
+          "parquet.upload": "scanUploadNs",
+          "parquet.launch": "scanLaunchNs"}
+#: the benchmark's readers of the stages' counters
+STAGE_READERS = {"scan_read_s_per_query": "scanReadNs",
+                 "scan_decompress_s_per_query": "scanDecompressNs",
+                 "scan_index_run_parse_s_per_query": "scanIndexRunNs",
+                 "scan_values_s_per_query": "scanValuesNs",
+                 "scan_stage_s_per_query": "scanStageNs"}
+
+
+def _metric(name):
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location("metric_" + name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.read
+
+
+def test_scan_stages_are_children_of_the_row_groups_span(tmp_path):
+    """Under ``trace.enabled`` each stage of a column chunk is one tree
+    span, a child of its row group's ``parquet.device_decode`` on the
+    producer thread that decoded it, and lands on its annotation twin in
+    the profiler's trace."""
+    import jax
+    rng = np.random.default_rng(5)
+    rows = 4000
+    table = pa.table({
+        "k": pa.array(rng.integers(0, 50, rows), pa.int64(),
+                      mask=rng.random(rows) < 0.05),
+        "x": rng.random(rows),
+        "s": pa.array(rng.choice(["a", "b", "c"], rows))})
+    path = str(tmp_path / "stages.parquet")
+    pq.write_table(table, path, row_group_size=2000,
+                   use_dictionary=["k", "s"])
+    session = TpuSession(dict(DEVICE, **{
+        "spark.rapids.tpu.trace.enabled": True,
+        "spark.rapids.tpu.trace.dir": str(tmp_path / "chrome")}))
+    query = session.read.parquet(path).where(col("x") >= lit(0.0))
+    query.collect()                       # compile outside the trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path / "xplane"),
+                             profiler_options=options)
+    try:
+        assert query.collect().num_rows == rows
+    finally:
+        jax.profiler.stop_trace()
+    tracer = session.last_trace()
+    by_id = {s["id"]: s for s in tracer.spans}
+    decodes = [s for s in tracer.spans
+               if s["name"] == "parquet.device_decode"]
+    assert len(decodes) == 2                   # one a row group
+    stages = [s for s in tracer.spans if s["name"] in STAGES]
+    # one span a stage and chunk: 3 columns (each nullable in the file, two
+    # of them dictionary-encoded) in each of 2 row groups
+    want = {name: 6 for name in STAGES}
+    want["parquet.index_runs"] = 4
+    assert {name: sum(s["name"] == name for s in stages)
+            for name in STAGES} == want
+    for s in stages:
+        parent = by_id[s["parent"]]
+        assert parent["name"] == "parquet.device_decode", s
+        assert parent["tid"] == s["tid"]
+        assert parent["t0_ns"] <= s["t0_ns"] <= s["t1_ns"] \
+            <= parent["t1_ns"]
+
+    found = glob.glob(str(tmp_path / "xplane" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    profile = jax.profiler.ProfileData.from_file(found[0])
+    start = next(int(dict(p.stats)["profile_start_time"])
+                 for p in profile.planes
+                 if "profile_start_time" in dict(p.stats))
+    twins = {}
+    for plane in profile.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for event in line.events:
+                    if event.name in STAGES:
+                        twins.setdefault(event.name, []).append(
+                            start + event.start_ns)
+    for s in stages:
+        unix_ns = tracer.unix_ns + s["t0_ns"] - tracer.t0_ns
+        gap = min(abs(unix_ns - t) for t in twins[s["name"]])
+        assert gap < 1e6, f"{s['name']}: {gap} ns off its twin"
+
+
+@pytest.mark.parametrize("name", sorted(STAGE_READERS))
+def test_stage_reader_reads_its_counter(name):
+    """Each stage's reader: its counter's thread-seconds a completed
+    query, nothing without the counter or a completed query."""
+    read = _metric(name)
+    counter = STAGE_READERS[name]
+    run = {"counters": {counter: 3_000_000_000, "scanParseNs": 7},
+           "completed": 2}
+    assert read(run) == 1.5
+    assert read(dict(run, counters={"scanParseNs": 7})) is None
+    assert read(dict(run, completed=0)) is None
+    # the benchmark's file-scanning cells list it, the cached cell not
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"]
+                     if m["name"] == name)
+    assert entry["source"] == "program_counter"
+    assert "tpch_sf1_cached.q1" not in entry["workloads"]
+    assert len(entry["workloads"]) == 7
+
+
+def test_scan_host_idle_reads_the_scan_spans_of_the_reduced_trace():
+    """The idle seconds a traced query that the reduced trace puts under a
+    ``parquet.*`` span or ``pipeline.wait``; no other span's, and none
+    from the pooled short gaps."""
+    read = _metric("scan_host_idle_s_per_query")
+    trace = {"queries": 4, "window_s": 10.0, "busy_s": 3.0, "idle_gaps": [
+        ["pipeline.wait", 2.5], ["parquet.decompress", 1.75],
+        ["(shorter gaps)", 0.6], ["parquet.device_decode", 0.5],
+        ["parquet.index_runs", 0.25], ["session.dispatch", 0.2],
+        ["DevicePutWithSharding", 0.1], ["parquet.stage", 0.05]]}
+    assert read({"trace": trace}) == pytest.approx(
+        (2.5 + 1.75 + 0.5 + 0.25 + 0.05) / 4)
+    assert read({"trace": dict(trace, idle_gaps=[
+        ["(shorter gaps)", 5.0]])}) == 0.0
+    assert read({"trace": None}) is None
