@@ -51,6 +51,8 @@ import dataclasses
 import datetime as _dt
 import struct as _struct
 import time
+from itertools import compress, repeat
+from operator import and_
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -183,64 +185,101 @@ class _HybridRuns:
         self.bit_starts, self.widths = [], []
 
     def non_null_count(self, start_run: int, packed: bytearray) -> int:
-        """Popcount of a bit-width-1 (definition level) run suffix: the
-        number of NON-NULL values — which is exactly how many entries the
-        page's index stream stores."""
-        total = 0
-        for i in range(start_run, len(self.kinds)):
-            if self.kinds[i] == 1:
-                total += self.counts[i] * (self.values[i] & 1)
-            else:
-                b0 = self.bit_starts[i]
-                count = self.counts[i]
-                chunk = np.frombuffer(
-                    packed, np.uint8,
-                    count=(b0 % 8 + count + 7) // 8, offset=b0 // 8)
-                bits = np.unpackbits(chunk, bitorder="little")
-                total += int(bits[b0 % 8: b0 % 8 + count].sum())
-        return total
+        """Popcount of a bit-width-1 (definition level) page: the number
+        of NON-NULL values — which is exactly how many entries the page's
+        index stream stores. The runs from ``start_run`` on are those one
+        :func:`_parse_hybrid` call sliced, so their bit-packed bytes lie
+        back to back in ``packed`` and only the last of them can end in a
+        capped group. The RLE runs' ones are summed in Python (a
+        bit-packed run holds value 0); the bit-packed runs' are one
+        popcount over their bits, up to the last run's count, so a capped
+        group's padding bits count no value."""
+        kinds = self.kinds[start_run:]
+        total = sum(compress(self.counts[start_run:],
+                             map(and_, self.values[start_run:], repeat(1))))
+        if 0 not in kinds:
+            return total
+        first = start_run + kinds.index(0)
+        last = len(self.kinds) - 1 - kinds[::-1].index(0)
+        bit0 = self.bit_starts[first]
+        end = self.bit_starts[last] + self.counts[last]
+        if end > len(packed) * 8:
+            raise ValueError("bit-packed definition levels cut short")
+        bits = int.from_bytes(packed[bit0 // 8: (end + 7) // 8],
+                              "little") >> bit0 % 8
+        return total + (bits & ((1 << (end - bit0)) - 1)).bit_count()
 
 
 def _parse_hybrid(buf: bytes, pos: int, end: int, bit_width: int,
                   n_values: int, runs: _HybridRuns, packed: bytearray,
-                  pad_tail: bool = True) -> None:
+                  pad_tail: bool = True) -> int:
     """Slice one hybrid stream into runs; bit-packed payloads append to
-    ``packed``. Never expands values. Counts CAP at the page's n_values so
-    the (multiple-of-8 padded) last bit-packed group never leaks phantom
-    positions into the next page's runs."""
+    ``packed``, joined once a stream. Never expands values. Counts CAP at
+    the page's n_values so the (multiple-of-8 padded) last bit-packed
+    group never leaks phantom positions into the next page's runs.
+    Returns the bit-packed runs sliced. A run costs no call but the
+    tables' appends: a header of one byte is read inline (the varint loop
+    runs for a header of 64 values or more), a value of one byte is
+    ``buf[pos]``."""
     produced = 0
-    t = _Thrift(buf, pos)
     byte_w = (bit_width + 7) // 8
-    while produced < n_values and t.pos < end:
-        header = t.varint()
+    add_kind, add_count, add_value, add_bit_start, add_width = (
+        runs.kinds.append, runs.counts.append, runs.values.append,
+        runs.bit_starts.append, runs.widths.append)
+    parts = []                # the bit-packed payloads, in order
+    add_part = parts.append
+    bit0 = len(packed) * 8
+    n_buf = len(buf)
+    while produced < n_values and pos < end:
+        header = buf[pos]
+        pos += 1
+        if header & 0x80:
+            header &= 0x7F
+            shift = 7
+            while True:
+                b = buf[pos]
+                pos += 1
+                header |= (b & 0x7F) << shift
+                if not b & 0x80:
+                    break
+                shift += 7
         if header & 1:  # bit-packed: (header>>1) groups of 8 values
             groups = header >> 1
-            count = min(groups * 8, n_values - produced)
+            count = groups * 8
             nbytes = groups * bit_width  # groups * 8 * bw / 8
-            runs.kinds.append(0)
-            runs.counts.append(count)
-            runs.values.append(0)
-            runs.bit_starts.append(len(packed) * 8)
-            runs.widths.append(bit_width)
-            packed.extend(buf[t.pos: t.pos + nbytes])
-            t.pos += nbytes
+            add_kind(0)
+            add_value(0)
+            add_bit_start(bit0)
+            add_part(buf[pos: pos + nbytes])
+            pos += nbytes
+            # a payload cut short by the buffer's end ends the walk: no
+            # run of this stream starts after it
+            bit0 += nbytes * 8
         else:
-            count = min(header >> 1, n_values - produced)
-            raw = buf[t.pos: t.pos + byte_w]
-            t.pos += byte_w
-            runs.kinds.append(1)
-            runs.counts.append(count)
-            runs.values.append(int.from_bytes(raw, "little"))
-            runs.bit_starts.append(0)
-            runs.widths.append(bit_width)
+            count = header >> 1
+            if byte_w == 1:
+                add_value(buf[pos] if pos < n_buf else 0)
+            else:
+                add_value(int.from_bytes(buf[pos: pos + byte_w], "little"))
+            pos += byte_w
+            add_kind(1)
+            add_bit_start(0)
         produced += count
+        if produced > n_values:
+            count -= produced - n_values
+            produced = n_values
+        add_count(count)
+        add_width(bit_width)
+    if parts:
+        packed += b"".join(parts)
     if pad_tail and produced < n_values:
         # Implicit trailing zeros (writers may omit the final RLE run).
-        runs.kinds.append(1)
-        runs.counts.append(n_values - produced)
-        runs.values.append(0)
-        runs.bit_starts.append(0)
-        runs.widths.append(bit_width)
+        add_kind(1)
+        add_count(n_values - produced)
+        add_value(0)
+        add_bit_start(0)
+        add_width(bit_width)
+    return len(parts)
 
 
 _PHYS_NP = {"INT32": np.int32, "INT64": np.int64, "FLOAT": np.float32,
@@ -326,7 +365,9 @@ def plan_column_chunk(f, col_md, field: T.StructField, max_def_level: int,
     Each stage but the headers' is a :func:`_stage` counted into
     ``counters``: ``scanReadNs``, ``scanDecompressNs``, ``scanDefLevelNs``
     (of a column nullable in the file only), ``scanIndexRunNs`` (zero
-    without dictionary-encoded pages), ``scanValuesNs``."""
+    without dictionary-encoded pages), ``scanValuesNs``; and
+    ``scanDefLevelPopcountPages``, the data pages whose non-null count
+    came from a popcount of their bit-packed definition levels."""
     if max_def_level > 1:
         raise NotImplementedError("nested columns (max_def_level > 1)")
     phys = col_md.physical_type
@@ -388,14 +429,17 @@ def plan_column_chunk(f, col_md, field: T.StructField, max_def_level: int,
     def_runs = _HybridRuns()
     packed = bytearray()
     if max_def_level > 0:
+        popcounted = 0        # pages with a bit-packed definition run
         with _stage(owner, counters, "parquet.def_levels", "scanDefLevelNs"):
             for i, (ph, payload) in enumerate(pages):
                 first = len(def_runs.kinds)
                 (def_len,) = _struct.unpack_from("<I", payload, 0)
-                _parse_hybrid(payload, 4, 4 + def_len, 1, ph.num_values,
-                              def_runs, packed)
+                popcounted += _parse_hybrid(
+                    payload, 4, 4 + def_len, 1, ph.num_values, def_runs,
+                    packed) > 0
                 at[i] = 4 + def_len
                 non_null[i] = def_runs.non_null_count(first, packed)
+        _count(counters, "scanDefLevelPopcountPages", popcounted)
     else:
         for ph, _ in pages:
             def_runs.kinds.append(1)

@@ -204,11 +204,21 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
           "slots (parquet_decode_*_nn programs); a chunk with a null "
           "takes the nullable program of its kind."),
     _spec("scanDefLevelNs", MetricKind.NANO_TIMING, ESSENTIAL,
-          "Device parquet scan: the part of scanParseNs spent parsing "
+          "Device parquet scan: the part of scanParseNs spent slicing "
           "the definition levels of column chunks whose column is "
-          "nullable in the file and counting their non-null values "
-          "(io/parquet_device.py plan_column_chunk). Thread-seconds "
-          "summed over the decode producers."),
+          "nullable in the file into run tables (_parse_hybrid) and "
+          "counting each page's non-null values: a sum over its RLE "
+          "runs, and one popcount a page over its bit-packed runs' bytes "
+          "(io/parquet_device.py plan_column_chunk; span "
+          "parquet.def_levels). Thread-seconds summed over the decode "
+          "producers."),
+    _spec("scanDefLevelPopcountPages", MetricKind.SUM, ESSENTIAL,
+          "Device parquet scan: data pages whose definition levels hold "
+          "a bit-packed run, so that their non-null count came from one "
+          "popcount over the page's bit-packed bytes (part of "
+          "scanDefLevelNs). Zero for a chunk whose pages are all RLE, as "
+          "a column without nulls, or with nulls in long runs, writes "
+          "them."),
     _spec("scanNullableUploadBytes", MetricKind.SUM, ESSENTIAL,
           "Device parquet scan: the part of uploadBytes that belongs to "
           "fixed-width or dictionary-string column chunks in which a "
@@ -229,7 +239,8 @@ TAXONOMY: Dict[str, MetricSpec] = {s.name: s for s in [
     _spec("scanIndexRunNs", MetricKind.NANO_TIMING, ESSENTIAL,
           "Device parquet scan: the part of scanParseNs spent slicing the "
           "dictionary index streams of a column chunk's pages into run "
-          "tables (_parse_hybrid; span parquet.index_runs): the "
+          "tables (_parse_hybrid, the walker of the definition levels "
+          "too; span parquet.index_runs): the "
           "counterpart of scanDefLevelNs, zero for a chunk without "
           "dictionary-encoded pages. Thread-seconds summed over the "
           "decode producers."),

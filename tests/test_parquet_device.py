@@ -1228,9 +1228,9 @@ def test_every_stage_of_a_chunk_is_counted(tmp_path):
     assert counters["scanValuesNs"] > 0
 
 
-def _counted_decode(monkeypatch, path, schema):
+def _counted_decode(monkeypatch, path, schema, counters=None):
     """(clock reads, annotations) of one decode of the file's first row
-    group, its programs compiled before."""
+    group, its programs compiled before; its counters into ``counters``."""
     import time
     from spark_rapids_tpu.metrics import trace as TR
     PD.decode_row_group(path, 0, schema)
@@ -1248,12 +1248,13 @@ def _counted_decode(monkeypatch, path, schema):
     with monkeypatch.context() as m:
         m.setattr(time, "perf_counter_ns", counted_clock)
         m.setattr(TR, "TraceAnnotation", CountedAnnotation)
-        PD.decode_row_group(path, 0, schema, counters={})
+        PD.decode_row_group(path, 0, schema,
+                            counters={} if counters is None else counters)
     return calls["clock"], calls["annotation"]
 
 
-def _dictionary_file(path, values, pages):
-    tbl = pa.table({"v": pa.array(values, pa.int64())})
+def _dictionary_file(path, values, pages, mask=None):
+    tbl = pa.table({"v": pa.array(values, pa.int64(), mask=mask)})
     pq.write_table(tbl, path, data_page_size=1,
                    write_batch_size=-(-len(values) // pages))
     schema = T.schema_from_arrow(tbl.schema)
@@ -1264,15 +1265,18 @@ def _dictionary_file(path, values, pages):
     return schema, plan
 
 
-@pytest.mark.parametrize("pair", ["runs", "pages"])
+@pytest.mark.parametrize("pair", ["runs", "pages", "null_runs"])
 def test_stage_clocks_do_not_grow_with_runs_or_pages(tmp_path, monkeypatch,
                                                      pair):
     """The instrumentation's budget: one span and one pair of clock reads
     a stage and column chunk, nothing per page, run or value. A nullable
     dictionary chunk of a few runs reads the clock and opens annotations
-    as often as one of 40,000 runs (same rows, same pages), and one of a
-    page as often as one of 50: 2 clock reads around the parse and 2 for
-    each of the eight stages, one annotation a stage."""
+    as often as one of 40,000 runs (same rows, same pages), one of a page
+    as often as one of 50, and one without nulls as often as one whose 50
+    pages each hold bit-packed definition levels, counted by a popcount a
+    page: 2 clock reads around the parse and 2 for each of the eight
+    stages, one annotation a stage."""
+    masks = [None, None]
     if pair == "runs":
         n = 40_000 * 8
         cases = [(np.repeat(np.arange(10), n // 10), 16),
@@ -1280,16 +1284,300 @@ def test_stage_clocks_do_not_grow_with_runs_or_pages(tmp_path, monkeypatch,
     else:
         n = 20_000
         cases = [(np.arange(n) % 300, 1), (np.arange(n) % 300, 50)]
-    counts, plans = [], []
-    for k, (values, pages) in enumerate(cases):
+    if pair == "null_runs":
+        cases[0] = cases[1]
+        masks[1] = np.random.default_rng(4).random(n) < 0.04
+    counts, plans, popcounted = [], [], []
+    for k, ((values, pages), mask) in enumerate(zip(cases, masks)):
         path = str(tmp_path / f"{k}.parquet")
-        schema, plan = _dictionary_file(path, values, pages)
+        schema, plan = _dictionary_file(path, values, pages, mask)
         plans.append(plan)
-        counts.append(_counted_decode(monkeypatch, path, schema))
-    pages = [len(p.def_runs.kinds) for p in plans]   # one run a page here
+        counters = {}
+        counts.append(_counted_decode(monkeypatch, path, schema, counters))
+        popcounted.append(counters["scanDefLevelPopcountPages"])
+    # one definition-level run a page without nulls
+    pages = [len(p.def_runs.kinds) for p in plans]
     runs = [len(p.idx_runs.kinds) for p in plans]
     if pair == "runs":
         assert pages[0] == pages[1] and runs[0] < 30 and runs[1] == 40_000
-    else:
+    elif pair == "pages":
         assert pages == [1, 50]
+    else:
+        assert pages[0] == 50 and pages[1] > 50 * 10
+        assert popcounted == [0, 50]
     assert counts[0] == counts[1] == (2 + 2 * len(_STAGES), len(_STAGES))
+
+
+# -- the run walk against the walk it replaced --------------------------------
+
+def _reference_parse_hybrid(buf, pos, end, bit_width, n_values, runs, packed,
+                            pad_tail=True):
+    """The run walk as it read before the walk without a call a run: a
+    ``_Thrift.varint`` a header, ``packed.extend`` a bit-packed run."""
+    produced = 0
+    t = PD._Thrift(buf, pos)
+    byte_w = (bit_width + 7) // 8
+    while produced < n_values and t.pos < end:
+        header = t.varint()
+        if header & 1:  # bit-packed: (header>>1) groups of 8 values
+            groups = header >> 1
+            count = min(groups * 8, n_values - produced)
+            nbytes = groups * bit_width  # groups * 8 * bw / 8
+            runs.kinds.append(0)
+            runs.counts.append(count)
+            runs.values.append(0)
+            runs.bit_starts.append(len(packed) * 8)
+            runs.widths.append(bit_width)
+            packed.extend(buf[t.pos: t.pos + nbytes])
+            t.pos += nbytes
+        else:
+            count = min(header >> 1, n_values - produced)
+            raw = buf[t.pos: t.pos + byte_w]
+            t.pos += byte_w
+            runs.kinds.append(1)
+            runs.counts.append(count)
+            runs.values.append(int.from_bytes(raw, "little"))
+            runs.bit_starts.append(0)
+            runs.widths.append(bit_width)
+        produced += count
+    if pad_tail and produced < n_values:
+        # Implicit trailing zeros (writers may omit the final RLE run).
+        runs.kinds.append(1)
+        runs.counts.append(n_values - produced)
+        runs.values.append(0)
+        runs.bit_starts.append(0)
+        runs.widths.append(bit_width)
+
+
+def _reference_non_null_count(runs, start_run, packed):
+    """The count as it read before the popcount a page: three or four
+    numpy calls a bit-packed run."""
+    total = 0
+    for i in range(start_run, len(runs.kinds)):
+        if runs.kinds[i] == 1:
+            total += runs.counts[i] * (runs.values[i] & 1)
+        else:
+            b0 = runs.bit_starts[i]
+            count = runs.counts[i]
+            chunk = np.frombuffer(
+                packed, np.uint8,
+                count=(b0 % 8 + count + 7) // 8, offset=b0 // 8)
+            bits = np.unpackbits(chunk, bitorder="little")
+            total += int(bits[b0 % 8: b0 % 8 + count].sum())
+    return total
+
+
+def _varint(n):
+    out = bytearray()
+    while True:
+        out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            return bytes(out)
+
+
+def _hybrid_stream(spec, width):
+    """The bytes of a hybrid stream: ``("rle", count, value)`` or
+    ``("bp", values)``, the latter's groups of eight filled with the
+    values given (so padding bits past a capped count may be set)."""
+    out = bytearray()
+    for kind, *rest in spec:
+        if kind == "rle":
+            count, value = rest
+            out += _varint(count << 1)
+            out += int(value).to_bytes((width + 7) // 8, "little")
+        else:
+            values = np.asarray(rest[0], np.int64)
+            out += _varint((-(-len(values) // 8)) << 1 | 1)
+            out += _bit_packed(values, width).tobytes()
+    return bytes(out)
+
+
+def _writer_spec(values):
+    """``_encoded``'s runs as ``_hybrid_stream`` takes them."""
+    return [("rle", run[1], run[3]) if run[0] == "rle" else ("bp", run[3])
+            for run in _encoded(np.asarray(values, np.int64), 1)]
+
+
+def _file_def_pages(tmp_path, values, mask):
+    """(definition-level stream, its end, values, width 1) of each data
+    page of a nullable double column pyarrow writes with its defaults."""
+    path = str(tmp_path / "levels.parquet")
+    pq.write_table(pa.table({"v": pa.array(values, pa.float64(),
+                                           mask=mask)}), path,
+                   data_page_size=4096)
+    md = pq.ParquetFile(path).metadata.row_group(0).column(0)
+    with open(path, "rb") as f:
+        f.seek(min(md.data_page_offset, md.dictionary_page_offset
+                   or md.data_page_offset))
+        chunk = f.read(md.total_compressed_size)
+    pages, pos = [], 0
+    while pos < len(chunk):
+        ph = PD._parse_page_header(chunk, pos)
+        pos += ph.header_len
+        payload = PD._decompress(md.compression,
+                                 chunk[pos: pos + ph.compressed_size],
+                                 ph.uncompressed_size)
+        pos += ph.compressed_size
+        if ph.page_type == 0:
+            (n,) = PD._struct.unpack_from("<I", payload, 0)
+            pages.append((payload, 4, 4 + n, ph.num_values, 1, True))
+    return pages
+
+
+def _hybrid_pages(name, tmp_path):
+    """The pages of one case of ``test_parse_hybrid_matches_the_old_walk``:
+    (buffer, start, end, values, bit width, pad_tail), parsed in turn into
+    one table and one packed buffer, as a column chunk's pages are."""
+    rng = np.random.default_rng(_HYBRID_CASES.index(name))
+
+    def page(spec, width, n_values=None, pad_tail=True, prefix=b"",
+             suffix=b""):
+        stream = _hybrid_stream(spec, width)
+        if n_values is None:
+            n_values = sum(run[1] if run[0] == "rle" else len(run[1])
+                           for run in spec)
+        buf = prefix + stream + suffix
+        return (buf, len(prefix), len(prefix) + len(stream), n_values, width,
+                pad_tail)
+
+    def mixed(width, runs):
+        spec = []
+        for _ in range(runs):
+            if rng.random() < 0.5:
+                spec.append(("rle", int(rng.integers(1, 40)),
+                             int(rng.integers(0, 1 << width))))
+            else:
+                spec.append(("bp", rng.integers(
+                    0, 1 << width, 8 * int(rng.integers(1, 6)))))
+        return spec
+
+    if name == "widths_1_to_24":
+        # one chunk whose dictionary width grows page by page: 1..3-byte
+        # RLE values, index streams that start after a width byte
+        return [page(mixed(w, 12), w, prefix=bytes([w])) for w in
+                range(1, 25)]
+    if name == "long_runs":
+        # headers of two and three varint bytes
+        return [page([("rle", 64, 3), ("bp", rng.integers(0, 32, 64 * 8)),
+                      ("rle", 20_000, 17), ("bp", rng.integers(
+                          0, 32, 200 * 8)), ("rle", 70_000, 0)], 5),
+                page([("bp", rng.integers(0, 2, 9000 * 8)),
+                      ("rle", 100_000, 1), ("rle", 63, 0)], 1)]
+    if name == "capped_padding":
+        # the last group's padding bits set, in definition levels and in
+        # indices; the next page's runs start after them
+        return [page([("rle", 8, 1), ("bp", np.ones(16, np.int64))], 1,
+                     n_values=11),
+                page([("bp", np.r_[rng.integers(0, 2, 5), np.ones(3)])], 1,
+                     n_values=5, suffix=b"\xff" * 8),
+                page([("bp", rng.integers(0, 1 << 9, 24))], 9, n_values=17)]
+    if name == "pad_tail":
+        # streams that end before their values: the implicit zero run, and
+        # without it (an index stream parsed with pad_tail False)
+        return [page([("rle", 10, 1), ("bp", rng.integers(0, 2, 8))], 1,
+                     n_values=40),
+                page([("rle", 5, 1)], 1, n_values=9, suffix=b"\x07\x07"),
+                page([("bp", rng.integers(0, 8, 16))], 3, n_values=30,
+                     pad_tail=False)]
+    if name == "cut_short":
+        # a buffer that ends inside a run: after an RLE header, and
+        # inside a bit-packed payload of indices and of definition levels
+        # (whose count cannot be read: both walks raise)
+        rle = _hybrid_stream([("rle", 8, 1)], 1) + _varint(6 << 1)
+        bp = _hybrid_stream([("rle", 8, 5)], 3) + _varint(4 << 1 | 1) \
+            + b"\x55\x55"
+        levels = _hybrid_stream([("rle", 8, 1)], 1) + _varint(4 << 1 | 1) \
+            + b"\x55\x55"
+        return [(rle, 0, len(rle), 20, 1, True),
+                (bp, 0, len(bp), 40, 3, True),
+                (levels, 0, len(levels), 40, 1, True)]
+    n = 20_000
+    if name == "no_nulls":
+        return [page([("rle", n, 1)], 1)]
+    if name == "all_nulls":
+        return [page([("rle", n, 0)], 1)]
+    if name == "nulls_4pct":
+        levels = (rng.random(n) >= 0.04).astype(np.int64)
+        return [page(_writer_spec(levels[i: i + 2500]), 1)
+                for i in range(0, n, 2500)]
+    mask = rng.random(n) < {"file_4pct": 0.04, "file_no_nulls": 0.0,
+                            "file_all_nulls": 1.0}[name]
+    return _file_def_pages(tmp_path, rng.random(n), mask)
+
+
+_HYBRID_CASES = ["widths_1_to_24", "long_runs", "capped_padding", "pad_tail",
+                 "cut_short", "no_nulls", "all_nulls", "nulls_4pct",
+                 "file_4pct", "file_no_nulls", "file_all_nulls"]
+
+
+@pytest.mark.parametrize("name", _HYBRID_CASES)
+def test_parse_hybrid_matches_the_old_walk(name, tmp_path):
+    """The walk without a call a run gives the run tables, the packed
+    bytes and, for definition levels (width 1), the non-null count of each
+    page that the run-by-run walk and count gave (or, where a page's
+    bit-packed levels are cut short, a ``ValueError`` as it did); it
+    returns the bit-packed runs it sliced."""
+    pages = _hybrid_pages(name, tmp_path)
+    old, new = PD._HybridRuns(), PD._HybridRuns()
+    old_packed, new_packed = bytearray(), bytearray()
+    for buf, pos, end, n_values, width, pad_tail in pages:
+        first = len(new.kinds)
+        _reference_parse_hybrid(buf, pos, end, width, n_values, old,
+                                old_packed, pad_tail)
+        sliced = PD._parse_hybrid(buf, pos, end, width, n_values, new,
+                                  new_packed, pad_tail)
+        assert (new.kinds, new.counts, new.values, new.bit_starts,
+                new.widths) == (old.kinds, old.counts, old.values,
+                                old.bit_starts, old.widths)
+        assert bytes(new_packed) == bytes(old_packed)
+        assert sliced == new.kinds[first:].count(0)
+        if width == 1:
+            try:
+                want = _reference_non_null_count(old, first, old_packed)
+            except ValueError:
+                assert name == "cut_short"
+                with pytest.raises(ValueError):
+                    new.non_null_count(first, new_packed)
+                continue
+            assert new.non_null_count(first, new_packed) == want
+    kinds = set(new.kinds)
+    if name in ("long_runs", "capped_padding", "nulls_4pct", "file_4pct",
+                "widths_1_to_24"):
+        assert kinds == {0, 1}
+    if name.endswith("no_nulls") or name.endswith("all_nulls"):
+        assert kinds == {1}
+    if name == "widths_1_to_24":
+        assert max(new.values) >= 1 << 16
+    if name == "long_runs":
+        assert max(new.counts) >= 64 * 8 * 8 and 200 * 8 in new.counts
+
+
+@pytest.mark.parametrize("nulls", ["none", "blocks", "random"])
+def test_popcount_pages_are_the_pages_with_bit_packed_levels(tmp_path, nulls):
+    """``scanDefLevelPopcountPages`` counts the data pages whose definition
+    levels hold a bit-packed run: none where every page's levels are RLE
+    (no nulls, or nulls in aligned blocks of 1,024), every page where
+    nulls fall at random; the decode reads what pyarrow reads."""
+    n = 16_384
+    rng = np.random.default_rng(5)
+    mask = {"none": None,
+            "blocks": (np.arange(n) // 1024) % 3 == 1,
+            "random": rng.random(n) < 0.04}[nulls]
+    pages = _file_def_pages(tmp_path, rng.random(n), mask)
+    path = str(tmp_path / "levels.parquet")
+    bit_packed = 0
+    for buf, pos, end, n_values, width, pad_tail in pages:
+        runs = PD._HybridRuns()
+        _reference_parse_hybrid(buf, pos, end, width, n_values, runs,
+                                bytearray(), pad_tail)
+        bit_packed += 0 in runs.kinds
+    schema = T.schema_from_arrow(pq.read_schema(path))
+    counters = {}
+    out = PD.decode_row_group(path, 0, schema, counters=counters)
+    assert out.to_arrow().column("v").to_pylist() == \
+        pq.read_table(path).column("v").to_pylist()
+    assert len(pages) > 1
+    assert counters["scanDefLevelPopcountPages"] == bit_packed
+    assert bit_packed == {"none": 0, "blocks": 0,
+                          "random": len(pages)}[nulls]
