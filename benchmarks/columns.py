@@ -29,11 +29,18 @@ def write_parquet(table: pa.Table, path: str, storage: dict) -> None:
 
 def to_numpy(column) -> np.ndarray:
     """A pyarrow column as numpy: dates as int32 days, strings as numpy
-    unicode (through their dictionary, so 6 M flags cost no 6 M objects)."""
+    unicode (through their dictionary, so 6 M flags cost no 6 M objects).
+    A null is NaN in a column of numbers or dates (which then comes as
+    float64) and None in a column of strings (which then comes as an
+    object array)."""
     arr = column.combine_chunks() if isinstance(column, pa.ChunkedArray) \
         else column
     if pa.types.is_string(arr.type) or pa.types.is_large_string(arr.type):
         enc = arr.dictionary_encode()
+        if arr.null_count:
+            words = np.array(enc.dictionary.to_pylist() + [None], object)
+            return words[enc.indices.fill_null(len(words) - 1)
+                         .to_numpy(zero_copy_only=False)]
         words = np.asarray(enc.dictionary.to_pylist(), dtype=np.str_)
         return words[enc.indices.to_numpy(zero_copy_only=False)]
     if pa.types.is_date32(arr.type):

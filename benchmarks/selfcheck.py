@@ -10,8 +10,8 @@ line.
 
 - every cell runs to ``correct`` true;
 - an answer altered where it is produced (a float moved by 1e-6 of itself,
-  a key changed, a row dropped) makes ``correct`` false, and so does a row
-  group read on the host by a query of set-up;
+  a key changed, a row dropped, a value made null) makes ``correct``
+  false, and so does a row group read on the host by a query of set-up;
 - the float32 control comes out as not correct, in every cell whose answer
   holds a floating-point column (an answer of counts alone has nothing to
   round: the altered key and the dropped row hold it);
@@ -36,14 +36,13 @@ import run  # noqa: E402
 import trace_reduce  # noqa: E402
 
 SCALE = 1 / 90
-FAULTS = ("float", "key", "row", "setup_host_read")
+FAULTS = ("float", "key", "row", "null", "setup_host_read")
 
 
 # cells that wait for a later PR (PERF.md, Open questions) but whose files
-# are here: held to the same checks, so that an entry is all they need
-WAITING = {"tpch_sf1_cached.q3": ("tpch_sf1_cached", "q3"),
-           "tpch_sf1_parquet_writer_defaults.q1":
-               ("tpch_sf1_parquet_writer_defaults", "q1")}
+# are here, {name: (config, traffic)}: held to the same checks, so that an
+# entry is all they need
+WAITING = {}
 
 
 def cells():
@@ -63,7 +62,8 @@ def altered_answers(fault: str):
     """Break the engine underneath the harness: every collect() returns its
     answer with one fault planted, or the first profile of set-up counts a
     row group read on the host. Yields the count of faults planted (a query
-    with no exact-typed column has no key to change)."""
+    with no exact-typed column has no key to change; ``null`` makes the
+    first value that is not null in the answer's first row null)."""
     import datetime
 
     import pyarrow as pa
@@ -90,11 +90,16 @@ def altered_answers(fault: str):
             planted[0] += 1
             return table.slice(0, table.num_rows - 1)
         for i, field in enumerate(table.schema):
-            if pa.types.is_floating(field.type) != (fault == "float"):
+            if fault != "null" and \
+                    pa.types.is_floating(field.type) != (fault == "float"):
                 continue
             values = table.column(i).to_pylist()
-            first = values[0]
-            if isinstance(first, float):
+            first = values[0] if values else None
+            if fault == "null":
+                if first is None:
+                    continue
+                values[0] = None
+            elif isinstance(first, float):
                 values[0] = first * (1 + 1e-6)
             elif isinstance(first, str):
                 values[0] = first + "x"
